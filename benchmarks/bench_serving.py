@@ -48,6 +48,7 @@ import numpy as np
 from benchmarks.common import emit
 from repro.configs.registry import get_reduced
 from repro.models import transformer as T
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serving import SamplingParams, ServingEngine
 
 
@@ -717,6 +718,7 @@ def main() -> None:
                     "pair ratio exceeds R (acceptance: 1.0 — the pipelined "
                     "step must be at or under the two-call path)")
     args = ap.parse_args()
+    enable_compile_cache()
     print("name,us_per_call,derived")
     run(smoke=args.smoke)
     from benchmarks.common import ROWS

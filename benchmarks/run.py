@@ -11,9 +11,11 @@ from __future__ import annotations
 
 from benchmarks import (bench_attention, bench_gptq, bench_kernels,
                         bench_paging, bench_serving)
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for mod in (bench_attention, bench_paging, bench_gptq, bench_kernels,
                 bench_serving):

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.registry import get_reduced
+from repro.launch.mesh import auto_mesh
 from repro.models import transformer as T
 from repro.models.moe import moe_apply, moe_init
 from repro.optim.adamw import AdamWConfig, init_opt_state
@@ -35,7 +36,7 @@ def check(name, a, b, tol=3e-2):
 
 
 def main():
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = auto_mesh((4, 2), ("data", "model"))
     ctx = make_ctx(mesh)
     key = jax.random.PRNGKey(0)
 

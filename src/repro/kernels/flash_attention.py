@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from repro.kernels.paged_attention import group_slopes
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -57,8 +57,8 @@ def _fa_kernel(slopes_ref, q_ref, k_ref, v_ref, o_ref,
                                 preferred_element_type=jnp.float32) * scale
         # s: [G, Tq, Tk]
         if use_alibi:
-            slopes = slopes_ref[0].astype(jnp.float32)     # [G]
-            s = s - slopes[:, None, None] * jnp.maximum(dist, 0)[None].astype(jnp.float32)
+            s = s - slopes_ref[0] \
+                * jnp.maximum(dist, 0)[None].astype(jnp.float32)
         mask = k_pos < seq_len_k
         if causal:
             mask &= dist >= 0
@@ -102,7 +102,7 @@ def _fa_kernel(slopes_ref, q_ref, k_ref, v_ref, o_ref,
 def _fa_chunk_kernel(block_table_ref, info_ref,      # scalar prefetch (SMEM)
                      slopes_ref, q_ref, *refs,
                      block_q: int, block_size: int, num_pool_blocks: int,
-                     num_raw_blocks: int, use_alibi: bool,
+                     num_raw_blocks: int, num_kv_heads: int, use_alibi: bool,
                      sliding_window: int, quantized: bool):
     """Dynamic-offset chunk-prefill flash body (one sequence).
 
@@ -115,20 +115,25 @@ def _fa_chunk_kernel(block_table_ref, info_ref,      # scalar prefetch (SMEM)
     attends its own tokens unquantized / un-roundtripped, matching the
     whole-prompt prefill semantics (and keeping int8 parity).
 
+    Every tile holds all KV heads (``[BS, KV, D]``, the pool's own
+    layout); a static loop over heads contracts each head's slice with
+    its G query heads, as the decode kernel does.
+
     ``info_ref`` holds the two *traced* scalars ``[q_offset, total_len]``
     — the causal mask, ALiBi distances and the live-page clamp are all
     computed from them, so every chunk of every prompt runs from one
     compiled executable.  ``quantized`` reuses the in-register dequant
     of ``paged_attention_quant.py``: pool tiles are int8 with one f32
-    scale per (page, kv head); raw tiles are always full precision.
+    scale per (page, kv head) in SMEM; raw tiles are always full
+    precision.
     """
     if quantized:
         (kp_ref, ks_ref, vp_ref, vs_ref, kr_ref, vr_ref,
          o_ref, acc_ref, m_ref, l_ref) = refs
     else:
         kp_ref, vp_ref, kr_ref, vr_ref, o_ref, acc_ref, m_ref, l_ref = refs
-    iq = pl.program_id(1)
-    ik = pl.program_id(2)
+    iq = pl.program_id(0)
+    ik = pl.program_id(1)
     q_off = info_ref[0]
     tlen = info_ref[1]
 
@@ -141,43 +146,43 @@ def _fa_chunk_kernel(block_table_ref, info_ref,      # scalar prefetch (SMEM)
     q_pos = q_off + iq * block_q + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_size), 0)
 
-    def _accum(k, v, k_pos, mask):
-        q = q_ref[0].astype(jnp.float32)                   # [G, Tq, D]
+    def _accum(h, k, v, k_pos, mask):
+        q = q_ref[h].astype(jnp.float32)                   # [G, Tq, D]
         scale = q.shape[-1] ** -0.5
         s = jax.lax.dot_general(q, k, (((2,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         dist = q_pos - k_pos                               # [Tq, Tk]
         if use_alibi:
-            slopes = slopes_ref[0].astype(jnp.float32)     # [G]
-            s = s - slopes[:, None, None] \
+            s = s - slopes_ref[h] \
                 * jnp.maximum(dist, 0)[None].astype(jnp.float32)
         if sliding_window > 0:
             mask &= dist < sliding_window
         s = jnp.where(mask[None], s, NEG_INF)
-        m_prev = m_ref[...]                                # [G, Tq]
-        l_prev = l_ref[...]
+        m_prev = m_ref[h]                                  # [G, Tq]
+        l_prev = l_ref[h]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new[..., None])                  # [G, Tq, Tk]
-        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1)
-        m_ref[...] = m_new
+        l_ref[h] = l_prev * alpha + jnp.sum(p, axis=-1)
+        m_ref[h] = m_new
         pv = jax.lax.dot_general(p, v, (((2,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * alpha[..., None] + pv
+        acc_ref[h] = acc_ref[h] * alpha[..., None] + pv
 
     # ---- pool pages: the prefix [0, q_offset). Pages past the prefix
     # are skipped (their DMA re-resolved to the last live page, compute
     # gated off) — the HBM walk is ceil(q_offset / block_size), never
     # the static table capacity.
     def _pool():
-        k = kp_ref[0, :, 0, :].astype(jnp.float32)         # [BS, D]
-        v = vp_ref[0, :, 0, :].astype(jnp.float32)
-        if quantized:
-            k = k * ks_ref[0, 0]
-            v = v * vs_ref[0, 0]
         k_pos = ik * block_size + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_size), 1)
-        _accum(k, v, k_pos, k_pos < q_off)
+        for h in range(num_kv_heads):
+            k = kp_ref[0, :, h, :].astype(jnp.float32)     # [BS, D]
+            v = vp_ref[0, :, h, :].astype(jnp.float32)
+            if quantized:
+                k = k * ks_ref[0, 0, h]
+                v = v * vs_ref[0, 0, h]
+            _accum(h, k, v, k_pos, k_pos < q_off)
 
     pool_live = jnp.logical_and(ik < num_pool_blocks,
                                 ik * block_size < q_off)
@@ -192,11 +197,13 @@ def _fa_chunk_kernel(block_table_ref, info_ref,      # scalar prefetch (SMEM)
     # within the chunk; padded tail positions masked by total_len.
     def _raw():
         j = ik - num_pool_blocks
-        k = kr_ref[0, 0].astype(jnp.float32)               # [BS, D]
-        v = vr_ref[0, 0].astype(jnp.float32)
         k_pos = q_off + j * block_size + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_size), 1)
-        _accum(k, v, k_pos, (k_pos < tlen) & (q_pos - k_pos >= 0))
+        mask = (k_pos < tlen) & (q_pos - k_pos >= 0)
+        for h in range(num_kv_heads):
+            k = kr_ref[0, :, h, :].astype(jnp.float32)     # [BS, D]
+            v = vr_ref[0, :, h, :].astype(jnp.float32)
+            _accum(h, k, v, k_pos, mask)
 
     j = ik - num_pool_blocks
     raw_live = jnp.logical_and(
@@ -212,7 +219,7 @@ def _fa_chunk_kernel(block_table_ref, info_ref,      # scalar prefetch (SMEM)
     @pl.when(ik == num_pool_blocks + num_raw_blocks - 1)
     def _final():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l[..., None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / l[..., None]).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -233,7 +240,7 @@ def flash_attention_chunk(
     v_scales: Optional[jnp.ndarray] = None,
     sliding_window: int = 0,
     block_q: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Chunk-prefill attention straight over the paged pool (TPU serving).
 
@@ -254,9 +261,7 @@ def flash_attention_chunk(
     G = H // KV
     MB = block_table.shape[1]
     quantized = k_scales is not None
-    use_alibi = alibi_slopes is not None
-    slopes = (alibi_slopes.reshape(KV, G) if use_alibi
-              else jnp.zeros((KV, G), jnp.float32))
+    slopes = group_slopes(alibi_slopes, KV, G)[..., None]    # [KV, G, 1, 1]
 
     bq = min(block_q, W)
     pq = (-W) % bq
@@ -265,65 +270,66 @@ def flash_attention_chunk(
     nr = (W + pr) // BS                              # raw chunk K tiles
     qg = jnp.pad(q, ((0, 0), (0, pq), (0, 0), (0, 0)))[0] \
         .reshape(W + pq, KV, G, D).transpose(1, 2, 0, 3)   # [KV, G, Wq, D]
-    kr = jnp.pad(k_raw, ((0, 0), (0, pr), (0, 0), (0, 0)))[0] \
-        .transpose(1, 0, 2).reshape(KV, nr, BS, D)
-    vr = jnp.pad(v_raw, ((0, 0), (0, pr), (0, 0), (0, 0)))[0] \
-        .transpose(1, 0, 2).reshape(KV, nr, BS, D)
+    # raw tiles keep the pool's page layout: [nr, BS, KV, D]
+    kr = jnp.pad(k_raw, ((0, 0), (0, pr), (0, 0), (0, 0))) \
+        .reshape(nr, BS, KV, D)
+    vr = jnp.pad(v_raw, ((0, 0), (0, pr), (0, 0), (0, 0))) \
+        .reshape(nr, BS, KV, D)
     info = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                       jnp.asarray(total_len, jnp.int32)])
 
     kernel = functools.partial(
         _fa_chunk_kernel, block_q=bq, block_size=BS, num_pool_blocks=MB,
-        num_raw_blocks=nr, use_alibi=use_alibi,
+        num_raw_blocks=nr, num_kv_heads=KV, use_alibi=alibi_slopes is not None,
         sliding_window=sliding_window, quantized=quantized)
 
-    def page_map(h, iq, ik, bt, info):
+    def page_map(iq, ik, bt, info):
         # pages past the live prefix re-resolve to its last live page
         # (Pallas skips the DMA when consecutive steps map to the same
         # block), so the walk is bounded by ceil(q_offset / BS).
-        return (bt[0, _chunk_clamp(ik, info[0], BS, MB)], 0, h, 0)
+        return (bt[0, _chunk_clamp(ik, info[0], BS, MB)], 0, 0, 0)
 
-    def scale_map(h, iq, ik, bt, info):
-        return (bt[0, _chunk_clamp(ik, info[0], BS, MB)], h)
+    def scale_map(iq, ik, bt, info):
+        return (bt[0, _chunk_clamp(ik, info[0], BS, MB)], 0, 0)
 
-    def raw_map(h, iq, ik, bt, info):
-        return (h, jnp.clip(ik - MB, 0, nr - 1), 0, 0)
+    def raw_map(iq, ik, bt, info):
+        return (jnp.clip(ik - MB, 0, nr - 1), 0, 0, 0)
 
+    page = pl.BlockSpec((1, BS, KV, D), page_map)
     in_specs = [
-        pl.BlockSpec((1, G), lambda h, iq, ik, bt, info: (h, 0)),
-        pl.BlockSpec((1, G, bq, D), lambda h, iq, ik, bt, info: (h, 0, iq, 0)),
-        pl.BlockSpec((1, BS, 1, D), page_map),
+        pl.BlockSpec((KV, G, 1, 1), lambda iq, ik, bt, info: (0, 0, 0, 0)),
+        pl.BlockSpec((KV, G, bq, D), lambda iq, ik, bt, info: (0, 0, iq, 0)),
     ]
-    args = [k_pool]
     if quantized:
-        in_specs.append(pl.BlockSpec((1, 1), scale_map))
-        args.append(k_scales)
-    in_specs.append(pl.BlockSpec((1, BS, 1, D), page_map))
-    args.append(v_pool)
-    if quantized:
-        in_specs.append(pl.BlockSpec((1, 1), scale_map))
-        args.append(v_scales)
-    in_specs += [pl.BlockSpec((1, 1, BS, D), raw_map),
-                 pl.BlockSpec((1, 1, BS, D), raw_map)]
+        # one f32 per (page, head) in SMEM; see paged_decode_call
+        scale = pl.BlockSpec((1, 1, KV), scale_map, memory_space=pltpu.SMEM)
+        in_specs += [page, scale, page, scale]
+        args = [k_pool, k_scales.reshape(NB, 1, KV),
+                v_pool, v_scales.reshape(NB, 1, KV)]
+    else:
+        in_specs += [page, page]
+        args = [k_pool, v_pool]
+    in_specs += [pl.BlockSpec((1, BS, KV, D), raw_map),
+                 pl.BlockSpec((1, BS, KV, D), raw_map)]
     args += [kr, vr]
 
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,                 # block_table, [off, len]
-            grid=(KV, nq, MB + nr),
+            grid=(nq, MB + nr),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, G, bq, D),
-                                   lambda h, iq, ik, bt, info: (h, 0, iq, 0)),
+            out_specs=pl.BlockSpec((KV, G, bq, D),
+                                   lambda iq, ik, bt, info: (0, 0, iq, 0)),
             scratch_shapes=[
-                pltpu.VMEM((G, bq, D), jnp.float32),
-                pltpu.VMEM((G, bq), jnp.float32),
-                pltpu.VMEM((G, bq), jnp.float32),
+                pltpu.VMEM((KV, G, bq, D), jnp.float32),
+                pltpu.VMEM((KV, G, bq), jnp.float32),
+                pltpu.VMEM((KV, G, bq), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((KV, G, W + pq, D), q.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(block_table, info, slopes, qg, *args)
 
@@ -353,7 +359,7 @@ def flash_attention(
     q_offset: int = 0,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     B, Sq, H, D = q.shape
     Sk, KV = k.shape[1], k.shape[2]
@@ -371,8 +377,7 @@ def flash_attention(
     kg = kp.transpose(0, 2, 1, 3)                                    # [B,KV,S,D]
     vg = vp.transpose(0, 2, 1, 3)
     use_alibi = alibi_slopes is not None
-    slopes = (alibi_slopes.reshape(KV, G) if use_alibi
-              else jnp.zeros((KV, G), jnp.float32))
+    slopes = group_slopes(alibi_slopes, KV, G)[..., None]    # [KV, G, 1, 1]
 
     nq = (Sq + pq) // block_q
     nk = (Sk + pk) // block_k
@@ -389,7 +394,7 @@ def flash_attention(
             num_scalar_prefetch=0,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, G), lambda b, h, iq, ik: (h, 0)),
+                pl.BlockSpec((1, G, 1, 1), lambda b, h, iq, ik: (h, 0, 0, 0)),
                 pl.BlockSpec((1, 1, G, block_q, D),
                              lambda b, h, iq, ik: (b, h, 0, iq, 0)),
                 pl.BlockSpec((1, 1, block_k, D),
@@ -406,7 +411,7 @@ def flash_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, KV, G, Sq + pq, D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

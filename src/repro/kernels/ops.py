@@ -1,7 +1,10 @@
 """Public kernel entry points with automatic Pallas / XLA-reference dispatch.
 
-``use_pallas=None`` (default) picks Pallas on TPU, interpret-mode Pallas is
-available for CPU validation, and the pure-XLA reference otherwise.
+``use_pallas=None`` (default) picks Pallas on TPU and the pure-XLA
+reference elsewhere.  ``interpret=None`` resolves to "not on TPU": the
+compiled kernel on the chip, the Pallas interpreter for CPU validation.
+The kernels themselves take ``interpret`` with no default, so nothing
+that bypasses this module can run the interpreter on a chip by accident.
 The dry-run always lowers the reference path (Pallas cannot lower on the
 CPU backend of the 512-device compile-only mesh).
 """
@@ -26,6 +29,10 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _interpret(interpret: Optional[bool]) -> bool:
+    return (not _on_tpu()) if interpret is None else interpret
+
+
 def flash_attention(q, k, v, alibi_slopes=None, *, causal=True,
                     sliding_window=0, q_offset=0,
                     use_pallas: Optional[bool] = None,
@@ -35,7 +42,7 @@ def flash_attention(q, k, v, alibi_slopes=None, *, causal=True,
     if use_pallas:
         return _flash_pallas(q, k, v, alibi_slopes, causal=causal,
                              sliding_window=sliding_window, q_offset=q_offset,
-                             interpret=(not _on_tpu()) if interpret is None else interpret)
+                             interpret=_interpret(interpret))
     if q.shape[1] > 512 and isinstance(q_offset, int):
         # flash-structured XLA lowering: no [S,S] materialization
         from repro.core.gqa import grouped_attention_chunked
@@ -77,7 +84,7 @@ def chunk_prefill_attention(q, k_pool, v_pool, k_scales, v_scales, layer,
             k_scales=k_scales[layer] if quant else None,
             v_scales=v_scales[layer] if quant else None,
             sliding_window=sliding_window,
-            interpret=(not _on_tpu()) if interpret is None else interpret)
+            interpret=_interpret(interpret))
     return _ref.chunk_prefill_attention_ref(
         q, k_pool, v_pool, k_scales, v_scales, layer, block_table,
         q_offset, total_len, k_raw, v_raw, alibi_slopes=alibi_slopes,
@@ -93,7 +100,7 @@ def paged_attention(q, k_pool, v_pool, block_table, seq_lens,
     if use_pallas:
         return _paged_pallas(q, k_pool, v_pool, block_table, seq_lens,
                              alibi_slopes, sliding_window=sliding_window,
-                             interpret=(not _on_tpu()) if interpret is None else interpret)
+                             interpret=_interpret(interpret))
     return _ref.paged_attention_ref(q, k_pool, v_pool, block_table, seq_lens,
                                     alibi_slopes=alibi_slopes,
                                     sliding_window=sliding_window)
@@ -112,7 +119,7 @@ def paged_attention_quant(q, k_values, k_scales, v_values, v_scales,
         return _paged_quant_pallas(
             q, k_values, k_scales, v_values, v_scales, block_table, seq_lens,
             alibi_slopes, sliding_window=sliding_window,
-            interpret=(not _on_tpu()) if interpret is None else interpret)
+            interpret=_interpret(interpret))
     return _ref.paged_attention_quant_ref(
         q, k_values, k_scales, v_values, v_scales, block_table, seq_lens,
         alibi_slopes=alibi_slopes, sliding_window=sliding_window)
@@ -144,7 +151,7 @@ def quant_matmul(x: jnp.ndarray, params: Dict[str, jnp.ndarray], *,
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
     y = _gptq_pallas(x2, params["qweight"], params["scales"], params["zeros"],
-                     interpret=(not _on_tpu()) if interpret is None else interpret)
+                     interpret=_interpret(interpret))
     if "bias" in params:
         y = y + params["bias"].astype(y.dtype)
     return y.reshape(*lead, -1)

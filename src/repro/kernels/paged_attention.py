@@ -6,9 +6,13 @@ The TPU form of the paper's custom DCU decode kernel:
   scalar-prefetch operand (SMEM) so the BlockSpec ``index_map`` itself
   resolves the per-sequence physical block id — the DMA engine walks the
   page list, which is exactly "paging" on TPU.
-* One grid step = (sequence, kv_head, page): the page's K/V tile is pulled
-  into VMEM once and contracted with *all* G grouped query heads (shared
-  K/V -> batched matmul, the Opt-GQA insight).
+* One grid step = (sequence, page): the page's K/V tile for *all* KV
+  heads ``[BS, KV, D]`` is pulled into VMEM once (its last two block dims
+  equal the pool's, which is what the TPU's (8, 128) tiling rule asks of
+  a block that is not tile-aligned), and each KV head's slice is
+  contracted with all G grouped query heads (shared K/V -> batched
+  matmul, the Opt-GQA insight).  The head loop is static: Mosaic slices
+  a static sublane index of a bf16/int8 tile but not a dynamic one.
 * ALiBi bias from iota in-tile; positions past ``seq_len`` masked; online
   softmax accumulated in VMEM scratch across pages.
 """
@@ -21,8 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels._compat import CompilerParams
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -39,21 +41,22 @@ def _clamp_live(i, seq_len, block_size):
 
 def _pa_kernel(block_tables_ref, seq_lens_ref,       # scalar prefetch (SMEM)
                slopes_ref, q_ref, *refs,
-               block_size: int, num_pages: int, use_alibi: bool,
-               sliding_window: int, quantized: bool = False):
+               block_size: int, num_pages: int, num_kv_heads: int,
+               use_alibi: bool, sliding_window: int, quantized: bool = False):
     """Shared online-softmax body for the bf16 and int8 decode kernels.
 
     ``refs`` is (k, v, o, acc, m, l) in the dense mode and
     (k, k_scale, v, v_scale, o, acc, m, l) when ``quantized`` — the int8
     wrapper (``paged_attention_quant.py``) reuses this body so the
-    softmax loop can never diverge between the two pool formats.
+    softmax loop can never diverge between the two pool formats.  The
+    scale refs are SMEM ``[1, 1, KV]`` rows: one f32 per (page, head).
     """
     if quantized:
         k_ref, ks_ref, v_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = refs
     else:
         k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
     b = pl.program_id(0)
-    i = pl.program_id(2)
+    i = pl.program_id(1)
 
     @pl.when(i == 0)
     def _init():
@@ -66,41 +69,116 @@ def _pa_kernel(block_tables_ref, seq_lens_ref,       # scalar prefetch (SMEM)
 
     @pl.when(k_lo < seq_len)                          # skip pages past the end
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)           # [G, D]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)     # [BS, D]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)     # [BS, D]
-        if quantized:
-            # in-register dequant: int8 tile * the page's per-head scale
-            k = k * ks_ref[0, 0]
-            v = v * vs_ref[0, 0]
-        scale = q.shape[-1] ** -0.5
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        # s: [G, BS]
-        k_pos = k_lo + jax.lax.broadcasted_iota(jnp.int32, (1, block_size), 1)[0]
+        k_pos = k_lo + jax.lax.broadcasted_iota(jnp.int32, (1, block_size), 1)
         q_pos = seq_len - 1
-        if use_alibi:
-            slopes = slopes_ref[0].astype(jnp.float32)                 # [G]
-            s = s - slopes[:, None] * jnp.maximum(q_pos - k_pos, 0)[None]
         mask = k_pos < seq_len
         if sliding_window > 0:
             mask &= k_pos > q_pos - sliding_window
-        s = jnp.where(mask[None], s, NEG_INF)
+        for h in range(num_kv_heads):
+            q = q_ref[0, h].astype(jnp.float32)       # [G, D]
+            k = k_ref[0, :, h, :].astype(jnp.float32)  # [BS, D]
+            v = v_ref[0, :, h, :].astype(jnp.float32)  # [BS, D]
+            if quantized:
+                # in-register dequant: int8 tile * the page's per-head scale
+                k = k * ks_ref[0, 0, h]
+                v = v * vs_ref[0, 0, h]
+            scale = q.shape[-1] ** -0.5
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32) * scale
+            # s: [G, BS]
+            if use_alibi:
+                s = s - slopes_ref[h] * jnp.maximum(
+                    q_pos - k_pos, 0).astype(jnp.float32)
+            s = jnp.where(mask, s, NEG_INF)
 
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1)
-        m_ref[...] = m_new
-        pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + pv
+            m_prev, l_prev = m_ref[h], l_ref[h]       # [G, 1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_ref[h] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            m_ref[h] = m_new
+            pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            acc_ref[h] = acc_ref[h] * alpha + pv
 
     @pl.when(i == num_pages - 1)
     def _final():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
+def group_slopes(alibi_slopes, KV, G):
+    """[H] ALiBi slopes -> the kernels' ``[KV, G, 1]`` operand (zeros
+    when the model has none; the kernel then never reads them)."""
+    if alibi_slopes is None:
+        return jnp.zeros((KV, G, 1), jnp.float32)
+    return alibi_slopes.astype(jnp.float32).reshape(KV, G, 1)
+
+
+def paged_decode_call(q, k_pool, v_pool, k_scales, v_scales, block_table,
+                      seq_lens, alibi_slopes, *, sliding_window: int,
+                      interpret: bool) -> jnp.ndarray:
+    """The decode ``pallas_call`` for both pool formats (int8 when
+    ``k_scales`` is given, with ``[NB, KV]`` f32 scales)."""
+    B, H, D = q.shape
+    NB, BS, KV, _ = k_pool.shape
+    G = H // KV
+    MB = block_table.shape[1]
+    quantized = k_scales is not None
+    slopes = group_slopes(alibi_slopes, KV, G)
+    qg = q.reshape(B, KV, G, D)
+
+    kernel = functools.partial(
+        _pa_kernel, block_size=BS, num_pages=MB, num_kv_heads=KV,
+        use_alibi=alibi_slopes is not None, sliding_window=sliding_window,
+        quantized=quantized)
+
+    # the paging step: the physical page id comes from the prefetched
+    # block table inside the index_map. Pages past the sequence's live
+    # page count re-resolve to its last live page: Pallas skips the DMA
+    # when consecutive grid steps map to the same block, so the HBM walk
+    # is bounded by ceil(seq_len/BS), not the static MB (compute for
+    # those steps is skipped too).
+    def page_map(b, i, bt, sl):
+        return (bt[b, _clamp_live(i, sl[b], BS)], 0, 0, 0)
+
+    def scale_map(b, i, bt, sl):
+        return (bt[b, _clamp_live(i, sl[b], BS)], 0, 0)
+
+    page = pl.BlockSpec((1, BS, KV, D), page_map)
+    in_specs = [pl.BlockSpec((KV, G, 1), lambda b, i, bt, sl: (0, 0, 0)),
+                pl.BlockSpec((1, KV, G, D), lambda b, i, bt, sl: (b, 0, 0, 0))]
+    if quantized:
+        # [NB, KV] -> [NB, 1, KV]: a (1, 1, KV) block equals the array's
+        # last two dims, which a (1, KV) block of [NB, KV] does not.
+        scale = pl.BlockSpec((1, 1, KV), scale_map, memory_space=pltpu.SMEM)
+        in_specs += [page, scale, page, scale]
+        args = [k_pool, k_scales.reshape(NB, 1, KV),
+                v_pool, v_scales.reshape(NB, 1, KV)]
+    else:
+        in_specs += [page, page]
+        args = [k_pool, v_pool]
+
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,                     # block_table, seq_lens
+            grid=(B, MB),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, KV, G, D),
+                                   lambda b, i, bt, sl: (b, 0, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((KV, G, D), jnp.float32),
+                pltpu.VMEM((KV, G, 1), jnp.float32),
+                pltpu.VMEM((KV, G, 1), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(block_table, seq_lens, slopes, qg, *args)
+    return out.reshape(B, H, D)
 
 
 @functools.partial(jax.jit, static_argnames=("sliding_window", "interpret"))
@@ -113,54 +191,9 @@ def paged_attention(
     alibi_slopes: Optional[jnp.ndarray] = None,
     *,
     sliding_window: int = 0,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
-    B, H, D = q.shape
-    NB, BS, KV, _ = k_pool.shape
-    G = H // KV
-    MB = block_table.shape[1]
-    use_alibi = alibi_slopes is not None
-    slopes = (alibi_slopes.reshape(KV, G) if use_alibi
-              else jnp.zeros((KV, G), jnp.float32))
-    qg = q.reshape(B, KV, G, D)
-
-    kernel = functools.partial(
-        _pa_kernel, block_size=BS, num_pages=MB, use_alibi=use_alibi,
-        sliding_window=sliding_window)
-
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,                     # block_table, seq_lens
-            grid=(B, KV, MB),
-            in_specs=[
-                pl.BlockSpec((1, G), lambda b, h, i, bt, sl: (h, 0)),
-                pl.BlockSpec((1, 1, G, D), lambda b, h, i, bt, sl: (b, h, 0, 0)),
-                # the paging step: physical page id comes from the prefetched
-                # block table inside the index_map. Pages past the sequence's
-                # live page count re-resolve to its last live page: Pallas
-                # skips the DMA when consecutive grid steps map to the same
-                # block, so the HBM walk is bounded by ceil(seq_len/BS), not
-                # the static MB (compute for those steps is skipped too).
-                pl.BlockSpec((1, BS, 1, D),
-                             lambda b, h, i, bt, sl: (
-                                 bt[b, _clamp_live(i, sl[b], BS)], 0, h, 0)),
-                pl.BlockSpec((1, BS, 1, D),
-                             lambda b, h, i, bt, sl: (
-                                 bt[b, _clamp_live(i, sl[b], BS)], 0, h, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, G, D),
-                                   lambda b, h, i, bt, sl: (b, h, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((G, D), jnp.float32),
-                pltpu.VMEM((G,), jnp.float32),
-                pltpu.VMEM((G,), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(block_table, seq_lens, slopes, qg, k_pool, v_pool)
-
-    return out.reshape(B, H, D)
+    return paged_decode_call(q, k_pool, v_pool, None, None, block_table,
+                             seq_lens, alibi_slopes,
+                             sliding_window=sliding_window,
+                             interpret=interpret)

@@ -1,16 +1,17 @@
 """Pallas TPU paged-attention decode kernel over the int8 KV pool.
 
 Same grid / scalar-prefetch structure as ``paged_attention.py`` — one
-grid step = (sequence, kv_head, page); the block table resolves physical
-page ids inside the BlockSpec ``index_map``; online softmax across pages
-in VMEM scratch; Opt-GQA shared-KV contraction of all G grouped query
-heads per tile.  The kernel body IS ``_pa_kernel`` (``quantized=True``):
-the K/V tiles DMA'd into VMEM are **int8** with one f32 scale per
-(page, kv head), dequantized in-register right before the contraction.
-The quantized cache is never materialized in HBM at full precision:
-attention consumes it directly (the TurboAttention observation, arXiv
-2412.08585), so the kernel moves ~1/2 (bf16) to ~1/4 (f32) of the
-baseline's KV bytes per decode step.
+grid step = (sequence, page) covering every KV head; the block table
+resolves physical page ids inside the BlockSpec ``index_map``; online
+softmax across pages in VMEM scratch; Opt-GQA shared-KV contraction of
+all G grouped query heads per head tile.  The ``pallas_call`` IS
+``paged_decode_call`` with scales: the K/V tiles DMA'd into VMEM are
+**int8** with one f32 scale per (page, kv head), read from SMEM and
+applied in-register right before the contraction.  The quantized cache
+is never materialized in HBM at full precision: attention consumes it
+directly (the TurboAttention observation, arXiv 2412.08585), so the
+kernel moves ~1/2 (bf16) to ~1/4 (f32) of the baseline's KV bytes per
+decode step.
 """
 from __future__ import annotations
 
@@ -19,11 +20,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-from repro.kernels.paged_attention import _clamp_live, _pa_kernel
+from repro.kernels.paged_attention import paged_decode_call
 
 
 @functools.partial(jax.jit, static_argnames=("sliding_window", "interpret"))
@@ -38,56 +36,9 @@ def paged_attention_quant(
     alibi_slopes: Optional[jnp.ndarray] = None,
     *,
     sliding_window: int = 0,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
-    B, H, D = q.shape
-    NB, BS, KV, _ = k_values.shape
-    G = H // KV
-    MB = block_table.shape[1]
-    use_alibi = alibi_slopes is not None
-    slopes = (alibi_slopes.reshape(KV, G) if use_alibi
-              else jnp.zeros((KV, G), jnp.float32))
-    qg = q.reshape(B, KV, G, D)
-
-    kernel = functools.partial(
-        _pa_kernel, block_size=BS, num_pages=MB, use_alibi=use_alibi,
-        sliding_window=sliding_window, quantized=True)
-
-    def page_map(b, h, i, bt, sl):
-        return (bt[b, _clamp_live(i, sl[b], BS)], 0, h, 0)
-
-    def scale_map(b, h, i, bt, sl):
-        return (bt[b, _clamp_live(i, sl[b], BS)], h)
-
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,                     # block_table, seq_lens
-            grid=(B, KV, MB),
-            in_specs=[
-                pl.BlockSpec((1, G), lambda b, h, i, bt, sl: (h, 0)),
-                pl.BlockSpec((1, 1, G, D), lambda b, h, i, bt, sl: (b, h, 0, 0)),
-                # paging exactly as the bf16 kernel: the prefetched block
-                # table picks the physical page; dead pages re-resolve to
-                # the last live one so their DMA + compute are skipped.
-                pl.BlockSpec((1, BS, 1, D), page_map),
-                pl.BlockSpec((1, 1), scale_map),
-                pl.BlockSpec((1, BS, 1, D), page_map),
-                pl.BlockSpec((1, 1), scale_map),
-            ],
-            out_specs=pl.BlockSpec((1, 1, G, D),
-                                   lambda b, h, i, bt, sl: (b, h, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((G, D), jnp.float32),
-                pltpu.VMEM((G,), jnp.float32),
-                pltpu.VMEM((G,), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(block_table, seq_lens, slopes, qg, k_values, k_scales,
-      v_values, v_scales)
-
-    return out.reshape(B, H, D)
+    return paged_decode_call(q, k_values, v_values, k_scales, v_scales,
+                             block_table, seq_lens, alibi_slopes,
+                             sliding_window=sliding_window,
+                             interpret=interpret)

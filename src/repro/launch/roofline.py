@@ -215,8 +215,6 @@ class RooflineTerms:
 
 def terms_from_compiled(compiled) -> RooflineTerms:
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):   # jax 0.4.3x: one dict per computation
-        ca = ca[0] if ca else {}
     text = compiled.as_text()
     cb = collective_bytes(text)
     return RooflineTerms(

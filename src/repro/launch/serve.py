@@ -35,6 +35,7 @@ import json
 import numpy as np
 
 from repro.configs.base import PagingConfig
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serving import LLM, SamplingParams
 
 
@@ -124,6 +125,7 @@ def main() -> None:
                          "this directory (adds TraceAnnotation labels to "
                          "every device dispatch)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     overrides = {}
     if args.mha_baseline:

@@ -62,25 +62,13 @@ def _div(n: int, size: int) -> bool:
 
 def shard_map(f, mesh, in_specs, out_specs, axis_names=None,
               check_vma=True):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax exposes it at the top level with ``axis_names`` (the axes
-    made Manual) and ``check_vma``; 0.4.x has only
-    ``jax.experimental.shard_map.shard_map`` where the same intent is the
-    complementary ``auto`` set and ``check_rep``.  Model code calls this
-    shim so the 512-device dry-run lowers on the pinned CPU jax too.
-    ``check_vma`` defaults to True like upstream — the island call sites
-    that opt out of replication checking say so explicitly.
+    """``jax.shard_map`` with ``axis_names`` (the axes made Manual)
+    optional.  ``check_vma`` defaults to True like upstream — the island
+    call sites that opt out of replication checking say so explicitly.
     """
-    if hasattr(jax, "shard_map"):
-        kw = {} if axis_names is None else {"axis_names": axis_names}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-    auto = (frozenset(mesh.axis_names) - frozenset(axis_names)
-            if axis_names is not None else frozenset())
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=bool(check_vma), auto=auto)
+    kw = {} if axis_names is None else {"axis_names": axis_names}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kw)
 
 
 def shard(ctx: Optional[ParallelCtx], x: jnp.ndarray, spec: P) -> jnp.ndarray:

@@ -7,6 +7,7 @@ from repro.checkpoint.checkpointer import AsyncCheckpointer, Checkpointer
 from repro.configs.base import ShapeConfig
 from repro.configs.registry import get_reduced
 from repro.data.pipeline import SyntheticLM
+from repro.launch.mesh import auto_mesh
 from repro.models import transformer as T
 from repro.optim.adamw import AdamWConfig, init_opt_state
 from repro.runtime.fault import (PreemptionError, StragglerDetector,
@@ -104,7 +105,7 @@ def test_elastic_restore_different_topology(tmp_path):
     ck = Checkpointer(str(tmp_path))
     ck.save(1, {"params": params})
     from repro.runtime.sharding import make_ctx, param_shardings
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
     ctx = make_ctx(mesh)
     sh = param_shardings(ctx, params, cfg)
     trees, _ = ck.restore(1, {"params": params}, shardings={"params": sh})

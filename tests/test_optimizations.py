@@ -7,6 +7,7 @@ import pytest
 
 from repro.configs.base import ShapeConfig
 from repro.configs.registry import get_reduced
+from repro.launch.mesh import auto_mesh
 from repro.models import transformer as T
 from repro.models.registry import decode_geometry
 
@@ -64,7 +65,7 @@ def test_fp8_kv_cache_decode_close():
 def test_dp_only_policy_matches_2d():
     """Same math under both parallelism policies (8 virtual... 1 device)."""
     from repro.runtime.sharding import make_ctx
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
     cfg = get_reduced("qwen2-1.5b", num_layers=2)
     params = T.init_params(cfg, KEY)
     b = {"tokens": jax.random.randint(KEY, (2, 17), 0, cfg.vocab_size)}

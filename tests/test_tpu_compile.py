@@ -1,0 +1,161 @@
+"""Compile the serving path's Pallas kernels for a described TPU v5e.
+
+Nothing runs: each test lowers and compiles for a v5e chip that is
+described, not attached, which is where Mosaic refuses a block shape the
+TPU's (8, 128) tiling rule forbids or more VMEM than a kernel may use —
+faults that interpret mode cannot see.  Shapes are qwen1.5-0.5b's
+published widths (16 x 64 heads, KV 16 or the Opt-GQA grouping 2, 16-token
+pages, a 256-token chunk, the 1024 x 2816 MLP).
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU library, and xdist workers import every file.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs.registry import get_config
+from repro.core.alibi import alibi_slopes
+from repro.kernels.flash_attention import flash_attention, flash_attention_chunk
+from repro.kernels.gptq_matmul import gptq_matmul
+from repro.kernels.paged_attention import paged_attention
+from repro.kernels.paged_attention_quant import paged_attention_quant
+
+H, D, BS, NB, MB, W, SLOTS = 16, 64, 16, 4096, 64, 256, 8
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """A shape factory placed on one described v5e chip, with the
+    persistent compile cache off (a described-chip compile is written to
+    it but can never be read back without a chip)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def shape(s, dtype):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one)
+    yield shape
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _hlo(fn, *args):
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _decode(chip, kv, dtype, alibi):
+    q = chip((SLOTS, H, D), jnp.bfloat16)
+    pool = chip((NB, BS, kv, D), dtype)
+    bt = chip((SLOTS, MB), jnp.int32)
+    sl = chip((SLOTS,), jnp.int32)
+    slopes = alibi_slopes(H) if alibi else None
+    if dtype == jnp.int8:
+        sc = chip((NB, kv), jnp.float32)
+        return _hlo(lambda q, k, ks, v, vs, bt, sl: paged_attention_quant(
+            q, k, ks, v, vs, bt, sl, slopes, interpret=False),
+            q, pool, sc, pool, sc, bt, sl)
+    return _hlo(lambda q, k, v, bt, sl: paged_attention(
+        q, k, v, bt, sl, slopes, interpret=False), q, pool, pool, bt, sl)
+
+
+def _chunk(chip, kv, dtype, alibi):
+    q = chip((1, W, H, D), jnp.bfloat16)
+    raw = chip((1, W, kv, D), jnp.bfloat16)
+    pool = chip((NB, BS, kv, D), dtype)
+    bt = chip((1, MB), jnp.int32)
+    off = chip((), jnp.int32)
+    slopes = alibi_slopes(H) if alibi else None
+    sc = chip((NB, kv), jnp.float32) if dtype == jnp.int8 else None
+
+    def f(q, k, v, bt, off, tl, kr, vr, ks, vs):
+        return flash_attention_chunk(q, k, v, bt, off, tl, kr, vr, slopes,
+                                     k_scales=ks, v_scales=vs,
+                                     interpret=False)
+    return _hlo(f, q, pool, pool, bt, off, off, raw, raw, sc, sc)
+
+
+def _prefill(chip, kv, dtype, alibi):
+    q = chip((1, W, H, D), dtype)
+    k = chip((1, W, kv, D), dtype)
+    slopes = alibi_slopes(H) if alibi else None
+    return _hlo(lambda q, k, v: flash_attention(
+        q, k, v, slopes, causal=True, interpret=False), q, k, k)
+
+
+def _gptq(chip, group_size, m):
+    K, N = 1024, 2816                          # the MLP's up projection
+    x = chip((m, K), jnp.bfloat16)
+    qw = chip((K // 8, N), jnp.int32)
+    s = chip((K // group_size, N), jnp.float32)
+    return _hlo(lambda x, qw, s, z: gptq_matmul(x, qw, s, z, interpret=False),
+                x, qw, s, s)
+
+
+@pytest.mark.parametrize("build,args", [
+    (_decode, (16, jnp.bfloat16, False)),
+    (_decode, (2, jnp.bfloat16, True)),
+    (_decode, (16, jnp.float32, False)),
+    (_decode, (2, jnp.int8, False)),
+    (_decode, (16, jnp.int8, True)),
+    (_chunk, (16, jnp.bfloat16, False)),
+    (_chunk, (2, jnp.bfloat16, True)),
+    (_chunk, (2, jnp.int8, False)),
+    (_chunk, (16, jnp.int8, False)),
+    (_prefill, (2, jnp.bfloat16, True)),
+    (_prefill, (16, jnp.bfloat16, False)),
+    (_gptq, (32, SLOTS)),
+    (_gptq, (128, SLOTS)),
+    (_gptq, (32, W)),
+], ids=["decode-kv16", "decode-kv2-alibi", "decode-kv16-f32",
+        "decode-int8-kv2", "decode-int8-kv16-alibi", "chunk-kv16",
+        "chunk-kv2-alibi", "chunk-int8-kv2", "chunk-int8-kv16",
+        "prefill-kv2-alibi", "prefill-kv16", "gptq-g32", "gptq-g128",
+        "gptq-g32-chunk"])
+def test_kernel_compiles_for_v5e(chip, build, args):
+    assert "tpu_custom_call" in build(chip, *args)
+
+
+@pytest.mark.parametrize("kv,kv_dtype,quant", [
+    (16, "bf16", None), (2, "int8", "rtn-int4")],
+    ids=["kv16-bf16", "kv2-int8-w4a16"])
+def test_unified_step_compiles_with_kernels(chip, kv, kv_dtype, quant):
+    """The serving engine's one-dispatch step, two layers deep at the
+    published widths, compiles for the chip with every kernel in it."""
+    from repro.models import transformer as T
+    from repro.models.quantize import quantize_params_rtn
+    cfg = get_config("qwen1.5-0.5b").replace(num_layers=2, num_kv_heads=kv)
+    rt = {"use_pallas": True, "interpret": False}
+
+    def params_fn():
+        p = T.init_params(cfg, jax.random.PRNGKey(0))
+        return quantize_params_rtn(p, cfg, group_size=32) if quant else p
+    shapes = jax.tree.map(lambda a: chip(a.shape, a.dtype),
+                          (jax.eval_shape(params_fn),
+                           jax.eval_shape(lambda: T.make_decode_state(
+                               cfg, SLOTS, 512, MB, dtype=jnp.float32,
+                               kv_cache_dtype=kv_dtype))))
+    n = SLOTS + 1
+    sampling = {"keys": chip((n, 2), jnp.uint32),
+                "counts": chip((n,), jnp.int32),
+                "temps": chip((n,), jnp.float32),
+                "top_ks": chip((n,), jnp.int32),
+                "top_ps": chip((n,), jnp.float32)}
+    i32 = chip((), jnp.int32)
+    hlo = _hlo(lambda p, s, t, sp, a, c, cbt, off, tl: T.unified_step(
+        cfg, p, s, t, sp, a, c, cbt, off, tl, None, rt),
+        *shapes, chip((SLOTS,), jnp.int32), sampling, chip((SLOTS,), jnp.bool_),
+        chip((1, W), jnp.int32), chip((1, MB), jnp.int32), i32, i32)
+    assert "tpu_custom_call" in hlo
