@@ -331,6 +331,7 @@ def flash_attention_chunk(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_attention_chunk",
     )(block_table, info, slopes, qg, *args)
 
     return out.transpose(2, 0, 1, 3).reshape(1, W + pq, H, D)[:, :W]
@@ -415,6 +416,7 @@ def flash_attention(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_attention",
     )(slopes, qg, kg, vg)
 
     out = out.transpose(0, 3, 1, 2, 4).reshape(B, Sq + pq, H, D)

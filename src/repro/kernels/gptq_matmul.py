@@ -105,5 +105,6 @@ def gptq_matmul(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="gptq_matmul",
     )(x, qweight, scales, zeros)
     return out[:M, :N]

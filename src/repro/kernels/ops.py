@@ -78,11 +78,13 @@ def chunk_prefill_attention(q, k_pool, v_pool, k_scales, v_scales, layer,
         use_pallas = _on_tpu()
     if use_pallas:
         quant = k_scales is not None
+        with jax.named_scope("pool_slice"):
+            kl, vl = k_pool[layer], v_pool[layer]
+            ks = k_scales[layer] if quant else None
+            vs = v_scales[layer] if quant else None
         return _flash_chunk_pallas(
-            q, k_pool[layer], v_pool[layer], block_table, q_offset,
-            total_len, k_raw, v_raw, alibi_slopes,
-            k_scales=k_scales[layer] if quant else None,
-            v_scales=v_scales[layer] if quant else None,
+            q, kl, vl, block_table, q_offset, total_len, k_raw, v_raw,
+            alibi_slopes, k_scales=ks, v_scales=vs,
             sliding_window=sliding_window,
             interpret=_interpret(interpret))
     return _ref.chunk_prefill_attention_ref(

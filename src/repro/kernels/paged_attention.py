@@ -177,6 +177,7 @@ def paged_decode_call(q, k_pool, v_pool, k_scales, v_scales, block_table,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_attention_quant" if quantized else "paged_attention",
     )(block_table, seq_lens, slopes, qg, *args)
     return out.reshape(B, H, D)
 

@@ -24,8 +24,9 @@ serves ``/metrics`` (Prometheus), ``/health`` (JSON) and ``/trace``
 ``--trace-out f.json`` writes the span timeline at exit (open in
 Perfetto); ``--metrics-out f.json`` dumps the registry snapshot;
 ``--profile-dir d/`` wraps the run in a ``jax.profiler`` capture with
-per-dispatch TraceAnnotation labels; ``--no-enable-telemetry`` turns
-the span tracer off (the metrics registry is always on).
+every engine span mirrored as a TraceAnnotation (this keeps the span
+tracer on); ``--no-enable-telemetry`` turns the span tracer off (the
+metrics registry is always on).
 """
 from __future__ import annotations
 
@@ -122,8 +123,8 @@ def main() -> None:
                     help="write the metrics-registry JSON snapshot at exit")
     ap.add_argument("--profile-dir", default=None,
                     help="capture a jax.profiler trace of the run into "
-                         "this directory (adds TraceAnnotation labels to "
-                         "every device dispatch)")
+                         "this directory (mirrors every engine span as a "
+                         "TraceAnnotation)")
     args = ap.parse_args()
     enable_compile_cache()
 

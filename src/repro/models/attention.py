@@ -198,19 +198,24 @@ def _decode_cache_attend(cfg, q, k, v, cache: KVCache, block_table,
         else:
             o = _ring_attention(q, kc, vc, valid)
     else:
-        cache = kv_write_decode(cache, layer, k, v, block_table, seq_lens - 1)
+        with jax.named_scope("kv_write"):
+            cache = kv_write_decode(cache, layer, k, v, block_table,
+                                    seq_lens - 1)
         if rt.get("skip_mixer_core"):
             o = q * (1 + 1e-30 * seq_lens.sum())
         elif cache.quantized:
+            with jax.named_scope("pool_slice"):
+                kl, ks = cache.k[layer], cache.k_scale[layer]
+                vl, vs = cache.v[layer], cache.v_scale[layer]
             o = ops.paged_attention_quant(
-                q, cache.k[layer], cache.k_scale[layer],
-                cache.v[layer], cache.v_scale[layer],
-                block_table, seq_lens, _slopes(cfg),
+                q, kl, ks, vl, vs, block_table, seq_lens, _slopes(cfg),
                 use_pallas=rt.get("use_pallas"),
                 interpret=rt.get("interpret"))
         else:
-            o = ops.paged_attention(q, cache.k[layer], cache.v[layer],
-                                    block_table, seq_lens, _slopes(cfg),
+            with jax.named_scope("pool_slice"):
+                kl, vl = cache.k[layer], cache.v[layer]
+            o = ops.paged_attention(q, kl, vl, block_table, seq_lens,
+                                    _slopes(cfg),
                                     use_pallas=rt.get("use_pallas"),
                                     interpret=rt.get("interpret"))
     return o, cache

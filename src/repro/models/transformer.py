@@ -265,7 +265,8 @@ def decode_step(cfg: ModelConfig, params: Params,
     already count the new token. Returns (logits [B, V], new state).
     """
     rt = rt or {}
-    x = params["embed"][tokens].astype(jnp.dtype(cfg.dtype))      # [B, d]
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens].astype(jnp.dtype(cfg.dtype))  # [B, d]
     state = dict(state)
     seq_lens = state["seq_lens"]
     L = cfg.num_layers
@@ -295,19 +296,23 @@ def decode_step(cfg: ModelConfig, params: Params,
         def body(carry, inp):
             h, cache = carry
             lp, li = inp
-            hn = apply_norm(lp["attn_norm"], h, cfg.norm, cfg.norm_eps)
-            mix, cache = attn_decode(
-                cfg, lp["attn"], hn, ctx, kind=kind0, cache=cache,
-                layer=li, block_table=state["block_table"],
-                seq_lens=seq_lens, rt=rt)
-            cache = _pin_cache(cache)
-            h = h + mix
-            hn = apply_norm(lp["mlp_norm"], h, cfg.norm, cfg.norm_eps)
-            if cfg.num_experts:
-                y = moe_apply(cfg, lp["moe"], hn[:, None, :], ctx, rt)[:, 0]
-            else:
-                y = mlp_apply(lp["mlp"], hn, cfg.act, rt)
-            return (h + y, cache), None
+            with jax.named_scope("attention"):
+                hn = apply_norm(lp["attn_norm"], h, cfg.norm, cfg.norm_eps)
+                mix, cache = attn_decode(
+                    cfg, lp["attn"], hn, ctx, kind=kind0, cache=cache,
+                    layer=li, block_table=state["block_table"],
+                    seq_lens=seq_lens, rt=rt)
+                cache = _pin_cache(cache)
+                h = h + mix
+            with jax.named_scope("mlp"):
+                hn = apply_norm(lp["mlp_norm"], h, cfg.norm, cfg.norm_eps)
+                if cfg.num_experts:
+                    y = moe_apply(cfg, lp["moe"], hn[:, None, :], ctx,
+                                  rt)[:, 0]
+                else:
+                    y = mlp_apply(lp["mlp"], hn, cfg.act, rt)
+                h = h + y
+            return (h, cache), None
 
         (x, cache), _ = jax.lax.scan(
             body, (x, cache_from_state(state)),
@@ -369,9 +374,11 @@ def decode_step(cfg: ModelConfig, params: Params,
                 y = mlp_apply(lp["mlp"], hn, cfg.act, rt)
             x = x + y
 
-    x = apply_norm(params["final_norm"], x, cfg.norm, cfg.norm_eps)
-    logits = unembed(x, params["embed"], params.get("head"))
-    return logits.astype(jnp.float32), state
+    with jax.named_scope("lm_head"):
+        x = apply_norm(params["final_norm"], x, cfg.norm, cfg.norm_eps)
+        logits = unembed(x, params["embed"], params.get("head"))
+        logits = logits.astype(jnp.float32)
+    return logits, state
 
 
 def decode_megastep(cfg: ModelConfig, params: Params,
@@ -419,10 +426,11 @@ def decode_megastep(cfg: ModelConfig, params: Params,
     def body(t, carry):
         state, toks, out = carry
         logits, state = decode_step(cfg, params, state, toks, ctx, rt)
-        nxt = sample_from_logits(logits, sampling["keys"],
-                                 sampling["counts"] + t, sampling["temps"],
-                                 sampling["top_ks"], sampling["top_ps"],
-                                 poison=sampling.get("poison"), guard=guard)
+        with jax.named_scope("sample"):
+            nxt = sample_from_logits(
+                logits, sampling["keys"], sampling["counts"] + t,
+                sampling["temps"], sampling["top_ks"], sampling["top_ps"],
+                poison=sampling.get("poison"), guard=guard)
         nxt = jnp.where(active, nxt, toks)
         state = dict(state)
         state["seq_lens"] = state["seq_lens"] + active_i
@@ -692,7 +700,8 @@ def prefill_chunk(cfg: ModelConfig, params: Params, cache,
     rt = rt or {}
     assert supports_chunked_prefill(cfg), cfg.name
     W = tokens.shape[1]
-    x = params["embed"][tokens].astype(jnp.dtype(cfg.dtype))   # [1, W, d]
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens].astype(jnp.dtype(cfg.dtype))  # [1,W,d]
     positions = pos_offset + jnp.arange(W)
     total_len = jnp.asarray(total_len, jnp.int32)
     ctx_lens = total_len[None] if total_len.ndim == 0 else total_len
@@ -702,30 +711,35 @@ def prefill_chunk(cfg: ModelConfig, params: Params, cache,
     def body(carry, inp):
         h, cache = carry
         lp, li = inp
-        hn = apply_norm(lp["attn_norm"], h, cfg.norm, cfg.norm_eps)
-        q, k, v = _qkv(cfg, lp["attn"], hn, positions, ctx, rt)
-        cache = kv_write_prefill(cache, li, k, v, block_table, ctx_lens,
-                                 pos_offset=pos_offset)
-        if rt.get("skip_mixer_core"):
-            o = q * (1 + 1e-30 * (k.sum() + v.sum()))
-        else:
-            # the chunk attends its OWN tokens raw (exactly like whole-
-            # prompt prefill), never pool-roundtripped, so int8
-            # quantization noise only enters for *earlier* chunks'
-            # positions; the traced q_offset drives the causal mask,
-            # which also hides every not-yet-written pool position.
-            o = kops.chunk_prefill_attention(
-                q, cache.k, cache.v, cache.k_scale, cache.v_scale, li,
-                block_table, pos_offset, total_len, k, v, slopes,
-                use_pallas=rt.get("use_pallas"),
-                interpret=rt.get("interpret"))
-        h = h + linear(o.reshape(*o.shape[:2], -1), lp["attn"]["wo"], rt)
-        hn = apply_norm(lp["mlp_norm"], h, cfg.norm, cfg.norm_eps)
-        if cfg.num_experts:
-            y = moe_apply(cfg, lp["moe"], hn, ctx, rt)
-        else:
-            y = mlp_apply(lp["mlp"], hn, cfg.act, rt)
-        return (h + y, cache), None
+        with jax.named_scope("attention"):
+            hn = apply_norm(lp["attn_norm"], h, cfg.norm, cfg.norm_eps)
+            q, k, v = _qkv(cfg, lp["attn"], hn, positions, ctx, rt)
+            with jax.named_scope("kv_write"):
+                cache = kv_write_prefill(cache, li, k, v, block_table,
+                                         ctx_lens, pos_offset=pos_offset)
+            if rt.get("skip_mixer_core"):
+                o = q * (1 + 1e-30 * (k.sum() + v.sum()))
+            else:
+                # the chunk attends its OWN tokens raw (exactly like
+                # whole-prompt prefill), never pool-roundtripped, so int8
+                # quantization noise only enters for *earlier* chunks'
+                # positions; the traced q_offset drives the causal mask,
+                # which also hides every not-yet-written pool position.
+                o = kops.chunk_prefill_attention(
+                    q, cache.k, cache.v, cache.k_scale, cache.v_scale, li,
+                    block_table, pos_offset, total_len, k, v, slopes,
+                    use_pallas=rt.get("use_pallas"),
+                    interpret=rt.get("interpret"))
+            h = h + linear(o.reshape(*o.shape[:2], -1), lp["attn"]["wo"],
+                           rt)
+        with jax.named_scope("mlp"):
+            hn = apply_norm(lp["mlp_norm"], h, cfg.norm, cfg.norm_eps)
+            if cfg.num_experts:
+                y = moe_apply(cfg, lp["moe"], hn, ctx, rt)
+            else:
+                y = mlp_apply(lp["mlp"], hn, cfg.act, rt)
+            h = h + y
+        return (h, cache), None
 
     if rt.get("scan_layers", True):
         (x, cache), _ = jax.lax.scan(
@@ -737,11 +751,13 @@ def prefill_chunk(cfg: ModelConfig, params: Params, cache,
             carry, _ = body(carry, (lp, jnp.int32(li)))
         x, cache = carry
 
-    x = apply_norm(params["final_norm"], x, cfg.norm, cfg.norm_eps)
-    last_i = jnp.clip(total_len - pos_offset - 1, 0, W - 1)
-    last = jnp.take_along_axis(x, last_i[None, None, None], axis=1)[:, 0]
-    logits = unembed(last, params["embed"], params.get("head"))
-    return logits.astype(jnp.float32), cache
+    with jax.named_scope("lm_head"):
+        x = apply_norm(params["final_norm"], x, cfg.norm, cfg.norm_eps)
+        last_i = jnp.clip(total_len - pos_offset - 1, 0, W - 1)
+        last = jnp.take_along_axis(x, last_i[None, None, None], axis=1)[:, 0]
+        logits = unembed(last, params["embed"], params.get("head"))
+        logits = logits.astype(jnp.float32)
+    return logits, cache
 
 
 def unified_step(cfg: ModelConfig, params: Params,
@@ -792,12 +808,13 @@ def unified_step(cfg: ModelConfig, params: Params,
         cfg, params, cache, chunk_tokens, chunk_block_table, pos_offset,
         total_len, ctx, rt)
     state.update(cache_to_state(cache))
-    logits = jnp.concatenate([logits_dec, logits_chunk], axis=0)
-    nxt = sample_from_logits(logits, sampling["keys"], sampling["counts"],
-                             sampling["temps"], sampling["top_ks"],
-                             sampling["top_ps"],
-                             poison=sampling.get("poison"),
-                             guard=bool((rt or {}).get("sampling_guard")))
+    with jax.named_scope("sample"):
+        logits = jnp.concatenate([logits_dec, logits_chunk], axis=0)
+        nxt = sample_from_logits(
+            logits, sampling["keys"], sampling["counts"], sampling["temps"],
+            sampling["top_ks"], sampling["top_ps"],
+            poison=sampling.get("poison"),
+            guard=bool((rt or {}).get("sampling_guard")))
     return nxt, state
 
 
@@ -833,8 +850,9 @@ def unified_step_chained(cfg: ModelConfig, params: Params,
     non-donated state copy is the price of the overlap — ~2 MB on the
     reduced serving configs, well under one step of host time.
     """
-    fed = jnp.where(use_prev,
-                    jnp.clip(prev_tokens[chain_idx], 0, None), tokens)
+    with jax.named_scope("chain_gather"):
+        fed = jnp.where(use_prev,
+                        jnp.clip(prev_tokens[chain_idx], 0, None), tokens)
     return unified_step(cfg, params, state, fed, sampling, active,
                         chunk_tokens, chunk_block_table, pos_offset,
                         total_len, ctx, rt)

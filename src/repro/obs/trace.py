@@ -2,40 +2,46 @@
 
 The tracer answers the question the aggregate counters cannot: *where*
 does a steady-state engine step spend its milliseconds?  Every section
-of interest — plan, each device dispatch, the token-readback sync
-boundary, detokenization — is wrapped in a :class:`SpanTracer.span`
-context manager; completed spans land in a bounded ring buffer of
-``(name, cat, ts_ns, dur_ns, depth, args)`` records and can be exported
-as Chrome-trace-event JSON (``chrome://tracing`` / Perfetto's
-``ui.perfetto.dev`` open it directly).
+of interest — plan, input building, table sync, each device dispatch,
+the token-readback sync boundary, absorbing tokens, detokenization — is
+wrapped in a :class:`SpanTracer.span` context manager; completed spans
+land in a bounded ring buffer of ``(name, cat, ts_ns, dur_ns, depth,
+args)`` records and can be exported as Chrome-trace-event JSON
+(``chrome://tracing`` / Perfetto's ``ui.perfetto.dev`` open it
+directly).
+
+With an annotation factory (``annotate``, e.g.
+``jax.profiler.TraceAnnotation``) every span and instant is also opened
+as a profiler annotation of the same extent, under the span's ``label``
+(its name unless given), so a device trace carries the program's own
+spans on its own clock: the program names its host regions in a
+profiler capture through this path alone.
 
 Hot-path contract (enforced by the R1 rule in ``repro.analysis``):
 
 * **no jax imports** — this module must be loadable and zero-cost in
   processes that never touch a device, and nothing here may ever block
-  on a device stream;
+  on a device stream (the annotation factory is handed in);
 * **no host syncs** — span bodies only read ``time.perf_counter_ns``
   (one monotonic clock call on enter, one on exit) and append one
   record to a ``deque``; span ``args`` must be plain host values
   (ints / floats / strings), never device arrays;
 * **zero work when disabled** — ``span()`` returns a preallocated
   no-op singleton and ``instant()`` returns immediately, so a
-  telemetry-off engine traces nothing and allocates nothing per step
-  (``table_telemetry`` in ``benchmarks/bench_serving.py`` gates the
-  telemetry-ON overhead at <= 2%; off is free by construction).
+  telemetry-off engine traces nothing and allocates nothing per step.
 
 ``attribute_steps`` post-processes the ring into the per-step
 host-vs-device wall-time split (``engine.attribution()``): device time
-is the sum of ``cat="device"`` spans inside each step span — dispatch
-issue plus the readback sync — and host time is the remainder (plan,
-absorb, detokenize, bookkeeping).
+is the sum of top-level ``cat="device"`` spans inside each step span —
+dispatch issue plus the readback sync — and host time is the remainder
+(plan, inputs, table sync, absorb, detokenize, bookkeeping).
 """
 from __future__ import annotations
 
 import json
 from collections import deque
 from time import perf_counter_ns
-from typing import Dict, Iterable, List, Optional
+from typing import Any, Callable, Dict, Iterable, List, Optional
 
 __all__ = ["Span", "SpanTracer", "NULL_TRACER", "attribute_steps",
            "validate_chrome_trace"]
@@ -99,6 +105,29 @@ class _SpanCtx:
                              tr._depth, self.args))
 
 
+class _MirroredSpanCtx(_SpanCtx):
+    """A span that is also a profiler annotation of the same extent,
+    under ``label``: the annotation opens just after the span's first
+    clock read and closes just before its last, so the annotation's own
+    cost stays inside the span it names."""
+    __slots__ = ("label", "_ann")
+
+    def __init__(self, tracer: "SpanTracer", name: str, cat: str,
+                 args: Optional[dict], label: str):
+        super().__init__(tracer, name, cat, args)
+        self.label = label
+
+    def __enter__(self) -> "_MirroredSpanCtx":
+        super().__enter__()
+        self._ann = self._tracer.annotate(self.label)
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._ann.__exit__(*exc)
+        super().__exit__(*exc)
+
+
 class _NullSpanCtx:
     """The shared no-op span: what a disabled tracer hands out.  One
     instance for the whole process — entering it does nothing, so the
@@ -128,28 +157,39 @@ class SpanTracer:
     enabled:  False hands out the no-op singleton (zero work, empty
               ring); flip with ``enable()`` / ``disable()`` at a step
               boundary (open spans of the old mode finish recording).
+    annotate: optional factory ``name -> context manager`` (e.g.
+              ``jax.profiler.TraceAnnotation``); when set, every span
+              and instant the ring records is mirrored into it.
     """
 
-    def __init__(self, capacity: int = 65536, enabled: bool = True):
+    def __init__(self, capacity: int = 65536, enabled: bool = True,
+                 annotate: Optional[Callable[[str], Any]] = None):
         self.capacity = int(capacity)
         self.enabled = bool(enabled)
+        self.annotate = annotate
         self._ring: deque = deque(maxlen=self.capacity)
         self._depth = 0
         self._total = 0
 
     # ------------------------------------------------------------ record
     def span(self, name: str, cat: str = "host",
-             args: Optional[dict] = None):
-        """Open a nested span: ``with tracer.span("plan"): ...``."""
+             args: Optional[dict] = None, label: Optional[str] = None):
+        """Open a nested span: ``with tracer.span("plan"): ...``.
+        ``label`` names its profiler annotation (default: ``name``)."""
         if not self.enabled:
             return _NULL_CTX
-        return _SpanCtx(self, name, cat, args)
+        if self.annotate is None:
+            return _SpanCtx(self, name, cat, args)
+        return _MirroredSpanCtx(self, name, cat, args, label or name)
 
     def instant(self, name: str, cat: str = "event",
                 args: Optional[dict] = None) -> None:
-        """Record a zero-duration lifecycle mark (e.g. ``req.arrival``)."""
+        """Record a zero-duration mark (e.g. ``req.arrival``)."""
         if not self.enabled:
             return
+        if self.annotate is not None:
+            with self.annotate(name):
+                pass
         self._total += 1
         self._ring.append(Span(name, cat, perf_counter_ns(), None,
                                self._depth, args))
@@ -236,6 +276,21 @@ def validate_chrome_trace(doc: Dict) -> List[str]:
     return problems
 
 
+def _top_level(spans: List[Span], cat: str) -> List[Span]:
+    """The ``cat`` spans not nested inside an earlier one, in start order
+    (``spans`` sorted by start, longest first on ties): a sweep that
+    keeps a span only when it ends past every earlier one."""
+    top: List[Span] = []
+    reach = -1
+    for s in spans:
+        if s.cat == cat:
+            end = s.ts + s.dur
+            if end > reach:
+                top.append(s)
+                reach = end
+    return top
+
+
 def attribute_steps(spans: Iterable[Span], window: Optional[int] = None,
                     step_name: str = "engine.step",
                     device_cat: str = "device") -> Dict[str, float]:
@@ -244,23 +299,26 @@ def attribute_steps(spans: Iterable[Span], window: Optional[int] = None,
 
     For each ``step_name`` span, device time is the sum of top-level
     ``device_cat`` spans it contains — dispatch issue plus the readback
-    sync boundary — and host time is the remainder (plan, absorb,
-    detokenize, scheduler bookkeeping).  Returns per-step means in
+    sync boundary — and host time is the remainder (plan, inputs, table
+    sync, absorb, detokenize, scheduler bookkeeping).  Linear after one
+    sort: top-level device spans end in increasing order, so one
+    pointer walks them across the steps.  Returns per-step means in
     milliseconds plus the host share; all-NaN when no step qualifies
     (e.g. the tracer was disabled).
     """
-    spans = list(spans)
-    steps = [s for s in spans if s.name == step_name and s.dur is not None]
-    device = [s for s in spans if s.cat == device_cat and s.dur is not None]
-    # guard against double counting if a device span ever nests inside
-    # another (today they are siblings; keep the invariant cheap to hold)
-    top = [d for d in device
-           if not any(o is not d and o.ts <= d.ts
-                      and d.ts + d.dur <= o.ts + o.dur for o in device)]
+    spans = sorted((s for s in spans if s.dur is not None),
+                   key=lambda s: (s.ts, -s.dur))
+    top = _top_level(spans, device_cat)
     rows: List[tuple] = []
-    for st in steps:
+    j = 0
+    for st in (s for s in spans if s.name == step_name):
         end = st.ts + st.dur
-        dev = sum(d.dur for d in top if st.ts <= d.ts and d.ts + d.dur <= end)
+        while j < len(top) and top[j].ts < st.ts:
+            j += 1
+        dev, k = 0, j
+        while k < len(top) and top[k].ts + top[k].dur <= end:
+            dev += top[k].dur
+            k += 1
         if dev > 0:                       # work steps only
             rows.append((st.dur, dev))
     if window is not None:

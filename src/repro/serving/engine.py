@@ -177,9 +177,14 @@ class ServingEngine:
         # engine and scheduler call sites are unchanged.  The span
         # tracer is the only piece ``enable_telemetry`` gates: metrics
         # are core accounting (report()'s contract) and stay on.
+        # ``profile_labels`` mirrors every span into the profiler's
+        # trace (jax.profiler.TraceAnnotation), so it keeps the tracer on.
         self.obs = MetricsRegistry()
-        self.tracer = SpanTracer(capacity=trace_capacity,
-                                 enabled=enable_telemetry)
+        self.tracer = SpanTracer(
+            capacity=trace_capacity,
+            enabled=enable_telemetry or profile_labels,
+            annotate=jax.profiler.TraceAnnotation if profile_labels
+            else None)
         self.metrics: Dict[str, float] = MetricsDict(self.obs, initial={
             "prompt_tokens": 0, "gen_tokens": 0, "preemptions": 0,
             "host_syncs": 0, "decode_dispatches": 0, "decode_steps": 0,
@@ -277,7 +282,7 @@ class ServingEngine:
                                   chunk_tokens=chunk_tokens,
                                   unified=self.unified,
                                   tracer=self.tracer,
-                                  profile_labels=profile_labels)
+                                  metrics=self.metrics)
         self.kv_cache_dtype = self.runner.kv_cache_dtype
         self._t0: Optional[float] = None
         self._next_rid = 0
@@ -579,44 +584,53 @@ class ServingEngine:
         and non-final chunk rows).  The nan fault site is consulted only
         for live rows, so a scheduled fault cannot burn itself on a
         sample nobody reads.  None = every non-pad row is live."""
-        B = len(recs)
-        arr = {"keys": np.zeros((B, 2), np.uint32),
-               "counts": np.zeros((B,), np.int32),
-               "temps": np.zeros((B,), np.float32),
-               "top_ks": np.zeros((B,), np.int32),
-               "top_ps": np.ones((B,), np.float32)}
-        for i, r in enumerate(recs):
-            if r is None:
-                continue
-            arr["keys"][i] = r.base_key
-            arr["counts"][i] = len(r.output)
-            arr["temps"][i] = r.sampling.temperature
-            arr["top_ks"][i] = r.sampling.top_k
-            arr["top_ps"][i] = r.sampling.top_p
-        # nan-site fault injection: a NaN bias row added to the chosen
-        # requests' logits ON DEVICE, so the non-finite guard is
-        # exercised end to end.  The "poison" key is present only when a
-        # spec fires (its presence is static per trace, so fault-free
-        # serving never traces a poisoned executable).
-        eligible = [r.rid for r in recs if r is not None
-                    and (live is None or r.rid in live)]
-        nan = self.faults.nan_rids(eligible) \
-            if self.faults is not None else ()
-        if nan:
-            rows = [i for i, r in enumerate(recs)
-                    if r is not None and r.rid in nan]
-            if rows:
-                p = np.zeros((B,), np.float32)
-                p[rows] = np.nan
-                arr["poison"] = p
-        return arr
+        with self.tracer.span("inputs", cat="host"):
+            B = len(recs)
+            arr = {"keys": np.zeros((B, 2), np.uint32),
+                   "counts": np.zeros((B,), np.int32),
+                   "temps": np.zeros((B,), np.float32),
+                   "top_ks": np.zeros((B,), np.int32),
+                   "top_ps": np.ones((B,), np.float32)}
+            for i, r in enumerate(recs):
+                if r is None:
+                    continue
+                arr["keys"][i] = r.base_key
+                arr["counts"][i] = len(r.output)
+                arr["temps"][i] = r.sampling.temperature
+                arr["top_ks"][i] = r.sampling.top_k
+                arr["top_ps"][i] = r.sampling.top_p
+            # nan-site fault injection: a NaN bias row added to the
+            # chosen requests' logits ON DEVICE, so the non-finite guard
+            # is exercised end to end.  The "poison" key is present only
+            # when a spec fires (its presence is static per trace, so
+            # fault-free serving never traces a poisoned executable).
+            eligible = [r.rid for r in recs if r is not None
+                        and (live is None or r.rid in live)]
+            nan = self.faults.nan_rids(eligible) \
+                if self.faults is not None else ()
+            if nan:
+                rows = [i for i, r in enumerate(recs)
+                        if r is not None and r.rid in nan]
+                if rows:
+                    p = np.zeros((B,), np.float32)
+                    p[rows] = np.nan
+                    arr["poison"] = p
+            return arr
 
     def _slot_sampling(self, live: Optional[set] = None
                        ) -> Dict[str, np.ndarray]:
-        recs: List[Optional[RequestState]] = [None] * self.max_slots
-        for slot, s in self.scheduler.running.items():
-            recs[slot] = s.req
-        return self._sampling_rows(recs, live=live)
+        with self.tracer.span("inputs", cat="host"):
+            recs: List[Optional[RequestState]] = [None] * self.max_slots
+            for slot, s in self.scheduler.running.items():
+                recs[slot] = s.req
+            return self._sampling_rows(recs, live=live)
+
+    def _sync_tables(self, slots) -> None:
+        """Device tables carrying exactly ``slots`` of the running set
+        (every other slot gets seq_len 0, so its KV writes drop)."""
+        with self.tracer.span("sync_tables", cat="host"):
+            self.runner.sync_tables({slot: self.scheduler.running[slot]
+                                     for slot in slots})
 
     def _run_prefill_oracle(self, seqs: List[Sequence],
                             outs: List[RequestOutput]) -> None:
@@ -642,12 +656,13 @@ class ServingEngine:
             logits, self._sampling_rows([s.req for s in seqs])))
         self.metrics["host_syncs"] += 1
         now = time.perf_counter()
-        for i, s in enumerate(seqs):
-            self._absorb(s, [int(nxt[i])], now, outs)
+        with self.tracer.span("absorb", cat="host"):
+            for i, s in enumerate(seqs):
+                self._absorb(s, [int(nxt[i])], now, outs)
         # leave device tables consistent with the host bookkeeping
         # (slots just prefilled or freed) instead of relying on the next
         # decode's sync.
-        self.runner.sync_tables(self.scheduler.running)
+        self._sync_tables(self.scheduler.running)
 
     def _run_prefill_chunks(self, chunks: List[PrefillChunk],
                             outs: List[RequestOutput]) -> None:
@@ -662,9 +677,10 @@ class ServingEngine:
                     [c.seq.req.rid],
                     lambda c=c: self.runner.prefill_chunk(c.seq, c.start,
                                                           c.length))
-                self.scheduler.complete_chunk(c)
-                self.metrics["prefill_chunks"] += 1
-                self.metrics["prompt_tokens"] += c.length
+                with self.tracer.span("plan", cat="host"):
+                    self.scheduler.complete_chunk(c)
+                    self.metrics["prefill_chunks"] += 1
+                    self.metrics["prompt_tokens"] += c.length
                 if c.last:
                     final.append((c.seq, logits))
         except PoisonedDispatchError as e:
@@ -680,18 +696,20 @@ class ServingEngine:
         # regardless of how many prompts finish in a step (and shares its
         # shape with the legacy decode path's per-slot sample)
         pad = self.max_slots - len(final)
-        stacked = jnp.concatenate(
-            [lg for _, lg in final]
-            + ([jnp.zeros((pad,) + final[0][1].shape[1:],
-                          final[0][1].dtype)] if pad else []), axis=0)
+        with self.tracer.span("inputs", cat="host"):
+            stacked = jnp.concatenate(
+                [lg for _, lg in final]
+                + ([jnp.zeros((pad,) + final[0][1].shape[1:],
+                              final[0][1].dtype)] if pad else []), axis=0)
         nxt = self._protected(
             [s.req.rid for s, _ in final],
             lambda: self.runner.sample(stacked, self._sampling_rows(
                 [s.req for s, _ in final] + [None] * pad)))
         self.metrics["host_syncs"] += 1
         now = time.perf_counter()
-        for i, (s, _) in enumerate(final):
-            self._absorb(s, [int(nxt[i])], now, outs)
+        with self.tracer.span("absorb", cat="host"):
+            for i, (s, _) in enumerate(final):
+                self._absorb(s, [int(nxt[i])], now, outs)
 
     # ------------------------------------------------------------ readback
     def _readback(self, out) -> np.ndarray:
@@ -743,35 +761,33 @@ class ServingEngine:
         # device tables carry EXACTLY the planned slots: everything else
         # (mid-prefill, or decodables a degenerate budget left out) gets
         # seq_len 0, so the decode KV scatter drops their writes
-        self.runner.sync_tables({slot: self.scheduler.running[slot]
-                                 for slot in plan.decode_slots})
-        toks = np.zeros((self.max_slots,), np.int32)
-        for slot in plan.decode_slots:
-            toks[slot] = self.scheduler.running[slot].last_token
-        rids = [self.scheduler.running[sl].req.rid
-                for sl in plan.decode_slots]
-        if self.use_fused:
+        self._sync_tables(plan.decode_slots)
+        with self.tracer.span("inputs", cat="host"):
+            toks = np.zeros((self.max_slots,), np.int32)
+            for slot in plan.decode_slots:
+                toks[slot] = self.scheduler.running[slot].last_token
+            rids = [self.scheduler.running[sl].req.rid
+                    for sl in plan.decode_slots]
             active = np.zeros((self.max_slots,), bool)
             active[plan.decode_slots] = True
+        if self.use_fused:
             out_np = self._protected(rids, lambda: self.runner.megastep(
                 toks, self._slot_sampling(live=set(rids)), active,
                 plan.horizon))
-            nxt_rows = {slot: out_np[:, slot].tolist()
-                        for slot in plan.decode_slots}
         else:
             def _decode_and_sample():
-                logits = self.runner.decode(toks)
+                logits = self.runner.decode(toks, len(plan.decode_slots))
                 return self.runner.sample(
                     logits, self._slot_sampling(live=set(rids)))
-            nxt = self._protected(rids, _decode_and_sample)
-            nxt_rows = {slot: [int(nxt[slot])] for slot in plan.decode_slots}
-        self.metrics["host_syncs"] += 1
-        self.metrics["decode_dispatches"] += 1
-        self.metrics["decode_steps"] += plan.horizon
-        now = time.perf_counter()
-        for slot in plan.decode_slots:
-            self._absorb(self.scheduler.running[slot], nxt_rows[slot],
-                         now, outs)
+            out_np = self._protected(rids, _decode_and_sample)[None]
+        with self.tracer.span("absorb", cat="host"):
+            self.metrics["host_syncs"] += 1
+            self.metrics["decode_dispatches"] += 1
+            self.metrics["decode_steps"] += plan.horizon
+            now = time.perf_counter()
+            for slot in plan.decode_slots:
+                self._absorb(self.scheduler.running[slot],
+                             out_np[:, slot].tolist(), now, outs)
         self._record_decode_time(time.perf_counter() - t0, plan.horizon)
 
     def _dispatch_unified(self, plan: StepPlan,
@@ -793,21 +809,22 @@ class ServingEngine:
                 # device tables carry EXACTLY this dispatch's decode slots:
                 # everything else gets seq_len 0, so the decode KV scatter
                 # drops its writes (chunk-only dispatches decode nothing)
-                self.runner.sync_tables({slot: self.scheduler.running[slot]
-                                         for slot in d.decode_slots})
-                toks = np.zeros((self.max_slots,), np.int32)
-                active = np.zeros((self.max_slots,), bool)
-                recs: List[Optional[RequestState]] = [None] * self.max_slots
-                rids = []
-                for slot in d.decode_slots:
-                    toks[slot] = self.scheduler.running[slot].last_token
-                    active[slot] = True
-                    recs[slot] = self.scheduler.running[slot].req
-                    rids.append(recs[slot].rid)
-                c = d.chunk
-                recs.append(c.seq.req)          # row max_slots: the chunk
-                live = set(rids) | ({c.seq.req.rid} if d.sample_chunk
-                                    else set())
+                self._sync_tables(d.decode_slots)
+                with self.tracer.span("inputs", cat="host"):
+                    toks = np.zeros((self.max_slots,), np.int32)
+                    active = np.zeros((self.max_slots,), bool)
+                    recs: List[Optional[RequestState]] = \
+                        [None] * self.max_slots
+                    rids = []
+                    for slot in d.decode_slots:
+                        toks[slot] = self.scheduler.running[slot].last_token
+                        active[slot] = True
+                        recs[slot] = self.scheduler.running[slot].req
+                        rids.append(recs[slot].rid)
+                    c = d.chunk
+                    recs.append(c.seq.req)      # row max_slots: the chunk
+                    live = set(rids) | ({c.seq.req.rid} if d.sample_chunk
+                                        else set())
                 out = self._protected(
                     rids + [c.seq.req.rid],
                     lambda: self.runner.unified_step(
@@ -815,17 +832,18 @@ class ServingEngine:
                         c.seq.req.prompt, c.seq.block_ids, c.start,
                         c.length))
                 done.append((d, out))
-                self.scheduler.complete_chunk(c)
-                self.metrics["prefill_chunks"] += 1
-                self.metrics["prompt_tokens"] += c.length
-                if d.decode_slots:
-                    # decode bookkeeping rides the unified dispatch; its
-                    # *timing* is not recorded — decode_step_latency_us
-                    # stays a pure-decode figure (mixed dispatches include
-                    # chunk compute the two-call path never timed as
-                    # decode)
-                    self.metrics["decode_dispatches"] += 1
-                    self.metrics["decode_steps"] += 1
+                with self.tracer.span("plan", cat="host"):
+                    self.scheduler.complete_chunk(c)
+                    self.metrics["prefill_chunks"] += 1
+                    self.metrics["prompt_tokens"] += c.length
+                    if d.decode_slots:
+                        # decode bookkeeping rides the unified dispatch;
+                        # its *timing* is not recorded —
+                        # decode_step_latency_us stays a pure-decode
+                        # figure (mixed dispatches include chunk compute
+                        # the two-call path never timed as decode)
+                        self.metrics["decode_dispatches"] += 1
+                        self.metrics["decode_steps"] += 1
         finally:
             # the step's ONE blocking point: token buffers are absorbed
             # after every dispatch is in flight (an admission burst of
@@ -838,13 +856,14 @@ class ServingEngine:
                 now = time.perf_counter()
                 for d, out in done:
                     out_np = self._readback(out)
-                    for slot in d.decode_slots:
-                        self._absorb(self.scheduler.running[slot],
-                                     [int(out_np[slot])], now, outs)
-                    if d.sample_chunk:
-                        self._absorb(d.chunk.seq,
-                                     [int(out_np[self.max_slots])],
-                                     now, outs)
+                    with self.tracer.span("absorb", cat="host"):
+                        for slot in d.decode_slots:
+                            self._absorb(self.scheduler.running[slot],
+                                         [int(out_np[slot])], now, outs)
+                        if d.sample_chunk:
+                            self._absorb(d.chunk.seq,
+                                         [int(out_np[self.max_slots])],
+                                         now, outs)
 
     # ------------------------------------------------------------ pipeline
     def _enqueue_unified(self, d: UnifiedDispatch,
@@ -865,34 +884,36 @@ class ServingEngine:
         # device tables: each slot's seq_len already counts its
         # speculated token (the one this dispatch feeds and whose KV it
         # writes at seq_len - 1) — exactly the sync post-absorb state
-        self.runner.sync_tables({slot: sched.running[slot]
-                                 for slot in d.decode_slots})
-        toks = np.zeros((self.max_slots,), np.int32)
-        chain_idx = np.zeros((self.max_slots,), np.int32)
-        use_prev = np.zeros((self.max_slots,), bool)
-        active = np.zeros((self.max_slots,), bool)
-        recs: List[Optional[RequestState]] = [None] * self.max_slots
-        rids = []
-        for slot in d.decode_slots:
-            s = sched.running[slot]
-            active[slot] = True
-            recs[slot] = s.req
-            rids.append(s.req.rid)
-            row = prev.source_row.get(id(s)) if prev is not None else None
-            if row is None:
-                toks[slot] = s.last_token     # host-known feed
-            else:
-                use_prev[slot] = True         # gather from in-flight buffer
-                chain_idx[slot] = row
-        c = d.chunk
-        recs.append(c.seq.req)                # row max_slots: the chunk
-        live = set(rids) | ({c.seq.req.rid} if d.sample_chunk else set())
-        sp = self._sampling_rows(recs, live=live)
-        for slot in d.decode_slots:
-            # the PRNG stream position counts every token SAMPLED so
-            # far — including the in-flight one this dispatch feeds,
-            # which req.output does not hold yet
-            sp["counts"][slot] += sched.running[slot].speculated
+        self._sync_tables(d.decode_slots)
+        with self.tracer.span("inputs", cat="host"):
+            toks = np.zeros((self.max_slots,), np.int32)
+            chain_idx = np.zeros((self.max_slots,), np.int32)
+            use_prev = np.zeros((self.max_slots,), bool)
+            active = np.zeros((self.max_slots,), bool)
+            recs: List[Optional[RequestState]] = [None] * self.max_slots
+            rids = []
+            for slot in d.decode_slots:
+                s = sched.running[slot]
+                active[slot] = True
+                recs[slot] = s.req
+                rids.append(s.req.rid)
+                row = prev.source_row.get(id(s)) if prev is not None \
+                    else None
+                if row is None:
+                    toks[slot] = s.last_token     # host-known feed
+                else:
+                    use_prev[slot] = True         # gather from in-flight
+                    chain_idx[slot] = row
+            c = d.chunk
+            recs.append(c.seq.req)                # row max_slots: the chunk
+            live = set(rids) | ({c.seq.req.rid} if d.sample_chunk
+                                else set())
+            sp = self._sampling_rows(recs, live=live)
+            for slot in d.decode_slots:
+                # the PRNG stream position counts every token SAMPLED so
+                # far — including the in-flight one this dispatch feeds,
+                # which req.output does not hold yet
+                sp["counts"][slot] += sched.running[slot].speculated
         try:
             out = self._protected(
                 rids + [c.seq.req.rid],
@@ -906,24 +927,25 @@ class ServingEngine:
             # and the fold-and-replay stays token-exact
             self._collect_flight(outs)
             raise
-        sched.complete_chunk(c)
-        self.metrics["prefill_chunks"] += 1
-        self.metrics["prompt_tokens"] += c.length
-        if d.decode_slots:
-            self.metrics["decode_dispatches"] += 1
-            self.metrics["decode_steps"] += 1
-        # speculation bumps AFTER the successful enqueue: every row
-        # whose sample this dispatch's buffer carries
-        flight = _Flight(out=out)
-        for slot in d.decode_slots:
-            s = sched.running[slot]
-            sched.speculate(s)
-            flight.decode_rows.append((slot, s))
-            flight.source_row[id(s)] = slot
-        if d.sample_chunk:
-            sched.speculate(c.seq)
-            flight.chunk_seq = c.seq
-            flight.source_row[id(c.seq)] = self.max_slots
+        with self.tracer.span("plan", cat="host"):
+            sched.complete_chunk(c)
+            self.metrics["prefill_chunks"] += 1
+            self.metrics["prompt_tokens"] += c.length
+            if d.decode_slots:
+                self.metrics["decode_dispatches"] += 1
+                self.metrics["decode_steps"] += 1
+            # speculation bumps AFTER the successful enqueue: every row
+            # whose sample this dispatch's buffer carries
+            flight = _Flight(out=out)
+            for slot in d.decode_slots:
+                s = sched.running[slot]
+                sched.speculate(s)
+                flight.decode_rows.append((slot, s))
+                flight.source_row[id(s)] = slot
+            if d.sample_chunk:
+                sched.speculate(c.seq)
+                flight.chunk_seq = c.seq
+                flight.source_row[id(c.seq)] = self.max_slots
         return flight
 
     def _collect_flight(self, outs: List[RequestOutput]) -> None:
@@ -942,17 +964,18 @@ class ServingEngine:
             return
         self._flight = None
         out_np = self._readback(fl.out)
-        self.metrics["host_syncs"] += 1
-        now = time.perf_counter()
-        rows = list(fl.decode_rows)
-        if fl.chunk_seq is not None:
-            rows.append((self.max_slots, fl.chunk_seq))
-        for row, s in rows:
-            if s.req.finish_reason is not None \
-                    or self.scheduler.running.get(s.slot) is not s:
-                continue
-            self.scheduler.reconcile(s)
-            self._absorb(s, [int(out_np[row])], now, outs)
+        with self.tracer.span("absorb", cat="host"):
+            self.metrics["host_syncs"] += 1
+            now = time.perf_counter()
+            rows = list(fl.decode_rows)
+            if fl.chunk_seq is not None:
+                rows.append((self.max_slots, fl.chunk_seq))
+            for row, s in rows:
+                if s.req.finish_reason is not None \
+                        or self.scheduler.running.get(s.slot) is not s:
+                    continue
+                self.scheduler.reconcile(s)
+                self._absorb(s, [int(out_np[row])], now, outs)
 
     def _prune_plan(self, plan: StepPlan) -> None:
         """Drop plan rows a pipeline flush invalidated: absorbing the
@@ -998,10 +1021,11 @@ class ServingEngine:
         observes every work step's wall time.
 
         Telemetry rides it too (``enable_telemetry``, default on): the
-        whole iteration is an ``engine.step`` span with plan / dispatch
-        / readback / detokenize children on ``self.tracer``, which is
-        what ``attribution()`` decomposes into per-step host vs device
-        milliseconds — see docs/OBSERVABILITY.md.
+        whole iteration is an ``engine.step`` span with plan / inputs /
+        sync_tables / dispatch / readback / absorb / detokenize children
+        on ``self.tracer``, which is what ``attribution()`` decomposes
+        into per-step host vs device milliseconds — see
+        docs/OBSERVABILITY.md.
 
         With ``enable_async_step`` (default, unified mode) the step is
         PIPELINED: it plans and enqueues its dispatch chained on the
@@ -1018,7 +1042,8 @@ class ServingEngine:
                 # under the in-flight dispatch
                 n0 = self._detok.submitted
                 tail = self._step_impl()
-                outs = self._detok.collect_upto(n0) + tail
+                with self.tracer.span("absorb", cat="host"):
+                    outs = self._detok.collect_upto(n0) + tail
             else:
                 outs = self._step_impl()
         self._update_gauges()
@@ -1039,13 +1064,14 @@ class ServingEngine:
         outs: List[RequestOutput] = self._pending  # abort/shed events first
         self._pending = []
         alloc_blocked = False
-        if self.faults is not None:
-            self.faults.step_begin()
-            alloc_blocked = self.faults.alloc_blocked()
-        for req in self.scheduler.expire_deadlines():
-            self.metrics["deadline_expired"] += 1
-            self._emit(req, outs)
-        self._advance_probe()
+        with self.tracer.span("plan", cat="host"):
+            if self.faults is not None:
+                self.faults.step_begin()
+                alloc_blocked = self.faults.alloc_blocked()
+            for req in self.scheduler.expire_deadlines():
+                self.metrics["deadline_expired"] += 1
+                self._emit(req, outs)
+            self._advance_probe()
         d0 = self.runner.dispatches
         t_work = time.perf_counter()
         if self.faults is not None:
@@ -1053,8 +1079,9 @@ class ServingEngine:
             if stall:           # inside the timed window: the watchdog
                 time.sleep(stall)  # must see the stall, like a real one
         try:
-            for req in self.scheduler.finish_at_capacity():
-                self._emit(req, outs)  # free slots/blocks before admission
+            with self.tracer.span("plan", cat="host"):
+                for req in self.scheduler.finish_at_capacity():
+                    self._emit(req, outs)  # free slots/blocks first
             if not self.chunked:
                 admitted = self.scheduler.try_admit(alloc_blocked)
                 self._mark_admitted([s.req for s in admitted],
@@ -1075,10 +1102,10 @@ class ServingEngine:
                     self.max_num_batched_tokens,
                     max_horizon=self.max_horizon if self.use_fused else 1,
                     alloc_blocked=alloc_blocked)
-            self._mark_admitted([c.seq.req for c in plan.prefill],
-                                time.perf_counter())
+                self._mark_admitted([c.seq.req for c in plan.prefill],
+                                    time.perf_counter())
+                ds = plan.unified_dispatches() if self.async_step else None
             if self.async_step:
-                ds = plan.unified_dispatches()
                 if len(ds) == 1 and not plan.cow_pairs:
                     # the tentpole fast path (the steady mixed state):
                     # enqueue this step's single unified dispatch chained

@@ -12,7 +12,6 @@ arrays are dead — always re-read ``runner.state``.
 """
 from __future__ import annotations
 
-import contextlib
 from typing import Dict, List, Optional, Sequence as Seq, Tuple
 
 import jax
@@ -25,6 +24,7 @@ from repro.core.kv_quant import (cache_from_state, cache_to_state,
 from repro.core.paged_cache import copy_blocks
 from repro.core.sampling import sample_from_logits
 from repro.models import transformer as T
+from repro.obs.metrics import MetricsDict, MetricsRegistry
 from repro.obs.trace import NULL_TRACER
 
 # decode-state entries that are pool-shaped [L, NB, ...] and therefore
@@ -39,14 +39,22 @@ class ModelRunner:
                  state_dtype=jnp.float32, kv_cache_dtype: str = "bf16",
                  chunk_tokens: Optional[int] = None,
                  unified: bool = False, tracer=None,
-                 profile_labels: bool = False):
+                 metrics: Optional[MetricsDict] = None):
         self.cfg = cfg
         self.params = params
-        # engine-owned span tracer (obs); NULL_TRACER = zero-work no-op
+        # engine-owned span tracer (obs); NULL_TRACER = zero-work no-op.
+        # Dispatch spans carry the profiler label of their executable.
         self.tracer = tracer if tracer is not None else NULL_TRACER
-        # when True, dispatches also carry jax.profiler.TraceAnnotation
-        # labels so a --profile-dir capture names each device region
-        self.profile_labels = bool(profile_labels)
+        # engine-owned counters: forward passes, the live decode rows
+        # they compute, and compiles seen at dispatch time
+        self.metrics = metrics if metrics is not None \
+            else MetricsDict(MetricsRegistry())
+        for k in ("forward_passes", "decode_rows", "compiles"):
+            self.metrics.setdefault(k, 0)
+        # executable name -> jit cache size last seen after a dispatch
+        # (``copy_blocks`` is one jit for the process: start from now)
+        self._compiled: Dict[str, float] = {
+            "copy_cow": self._cache_size(copy_blocks)}
         self.max_slots = max_slots
         self.num_blocks = num_blocks
         self.mb = max_blocks_per_seq
@@ -121,13 +129,23 @@ class ModelRunner:
                                static_argnames=("guard",))
 
     # ------------------------------------------------------------ obs
-    def _label(self, name: str):
-        """A ``jax.profiler.TraceAnnotation`` region when deep-dive
-        profiling is on (``--profile-dir``), else a free nullcontext —
-        the hot path never touches the profiler by default."""
-        if self.profile_labels:
-            return jax.profiler.TraceAnnotation(name)
-        return contextlib.nullcontext()
+    def _count_pass(self, rows: int, passes: int = 1) -> None:
+        """Counters of one forward-pass dispatch: ``passes`` passes (a
+        megastep's horizon) each computing ``rows`` live decode rows."""
+        self.metrics["forward_passes"] += passes
+        self.metrics["decode_rows"] += rows * passes
+
+    def _note_compile(self, name: str, fn) -> None:
+        """After a dispatch: when the executable's jit cache grew, the
+        call compiled — record a ``compile`` instant naming it and count
+        it in ``repro_compiles``."""
+        n = self._cache_size(fn)
+        seen = self._compiled.get(name, 0.0)
+        if n > seen:                      # NaN (API drift) never fires
+            self._compiled[name] = n
+            self.metrics["compiles"] += n - seen
+            self.tracer.instant("compile", cat="compile",
+                                args={"executable": name})
 
     # ------------------------------------------------------------ tables
     def sync_tables(self, running: Dict[int, "object"]) -> None:
@@ -147,33 +165,40 @@ class ModelRunner:
         scatters pool / per-slot state rows back into the live engine
         state and returns last-token logits [len(seqs), V]."""
         B = len(seqs)
-        toks = np.zeros((B, maxlen), np.int32)
-        lens = np.zeros((B,), np.int32)
-        for i, s in enumerate(seqs):
-            toks[i, :s.seq_len] = s.req.prompt
-            lens[i] = s.seq_len
-        # temporary contiguous state for the prefill batch, then scatter
-        # into the live engine state at each sequence's slot/table.
-        sub = dict(self.state)
-        bt = np.zeros((B, self.mb), np.int32)
-        for i, s in enumerate(seqs):
-            bt[i, :len(s.block_ids)] = s.block_ids
-        sub["block_table"] = jnp.asarray(bt) if "block_table" in sub else None
-        sub = {k: v for k, v in sub.items() if v is not None}
-        # prefill writes pools in-place via the shared pool arrays: pools
-        # are engine-global, per-slot state rows are gathered/scattered.
-        per_seq = {}
-        for k in ("ssm_h", "ssm_conv", "lru_h", "rec_conv"):
-            if k in sub:
-                per_seq[k] = sub[k][:, [s.slot for s in seqs]]
-                sub[k] = per_seq[k]
-        sub["seq_lens"] = jnp.asarray(lens)
-        batch = {"tokens": jnp.asarray(toks), "ctx_lens": jnp.asarray(lens)}
+        with self.tracer.span("inputs", cat="host"):
+            toks = np.zeros((B, maxlen), np.int32)
+            lens = np.zeros((B,), np.int32)
+            for i, s in enumerate(seqs):
+                toks[i, :s.seq_len] = s.req.prompt
+                lens[i] = s.seq_len
+            # temporary contiguous state for the prefill batch, then
+            # scatter into the live engine state at each sequence's
+            # slot/table.
+            sub = dict(self.state)
+            bt = np.zeros((B, self.mb), np.int32)
+            for i, s in enumerate(seqs):
+                bt[i, :len(s.block_ids)] = s.block_ids
+            sub["block_table"] = jnp.asarray(bt) if "block_table" in sub \
+                else None
+            sub = {k: v for k, v in sub.items() if v is not None}
+            # prefill writes pools in-place via the shared pool arrays:
+            # pools are engine-global, per-slot state rows are
+            # gathered/scattered.
+            per_seq = {}
+            for k in ("ssm_h", "ssm_conv", "lru_h", "rec_conv"):
+                if k in sub:
+                    per_seq[k] = sub[k][:, [s.slot for s in seqs]]
+                    sub[k] = per_seq[k]
+            sub["seq_lens"] = jnp.asarray(lens)
+            batch = {"tokens": jnp.asarray(toks),
+                     "ctx_lens": jnp.asarray(lens)}
         self.dispatches += 1
         with self.tracer.span("dispatch:prefill", cat="device",
-                              args={"batch": B, "maxlen": maxlen}), \
-                self._label("prefill"):
+                              args={"batch": B, "maxlen": maxlen, "rows": 0},
+                              label="prefill"):
             logits, sub = self._prefill(self.params, sub, batch)
+        self._count_pass(0)
+        self._note_compile("prefill", self._prefill)
         for k in _POOL_KEYS:
             if k in sub:
                 self.state[k] = sub[k]
@@ -189,21 +214,35 @@ class ModelRunner:
         and returns the last-live-token logits [1, V] as a *device*
         array — the engine batches first-token sampling across the
         step's final chunks, so no host sync happens here."""
-        W = self.chunk_tokens
-        toks = np.zeros((1, W), np.int32)
-        toks[0, :length] = seq.req.prompt[start:start + length]
-        bt = np.zeros((1, self.mb), np.int32)
-        bt[0, :len(seq.block_ids)] = seq.block_ids
+        toks, bt = self._chunk_inputs(seq.req.prompt, seq.block_ids, start,
+                                      length)
         cache = cache_from_state(self.state)
         self.dispatches += 1
         with self.tracer.span("dispatch:chunk", cat="device",
-                              args={"start": start, "length": length}), \
-                self._label("prefill_chunk"):
+                              args={"start": start, "length": length,
+                                    "rows": 0}, label="prefill_chunk"):
             logits, cache = self._prefill_chunk(
                 self.params, cache, jnp.asarray(toks), jnp.asarray(bt),
                 jnp.int32(start), jnp.int32(start + length))
         self.state.update(cache_to_state(cache))
+        self._count_pass(0)
+        self._note_compile("prefill_chunk", self._prefill_chunk)
         return logits
+
+    def _chunk_inputs(self, prompt: Seq[int], block_ids: Seq[int],
+                      start: int, length: int):
+        """The chunk operands of one dispatch: the ``[1, W]`` right-padded
+        tokens and the sequence's ``[1, MB]`` block row."""
+        with self.tracer.span("inputs", cat="host"):
+            toks = np.zeros((1, self.chunk_tokens), np.int32)
+            toks[0, :length] = prompt[start:start + length]
+            bt = np.zeros((1, self.mb), np.int32)
+            bt[0, :len(block_ids)] = block_ids
+        return toks, bt
+
+    def _put_sampling(self, sampling: Dict[str, np.ndarray]):
+        with self.tracer.span("inputs", cat="host"):
+            return {k: jnp.asarray(v) for k, v in sampling.items()}
 
     def unified_step(self, tokens: np.ndarray,
                      sampling: Dict[str, np.ndarray], active: np.ndarray,
@@ -217,20 +256,19 @@ class ModelRunner:
         an admission burst of several chunks pipelines behind one sync.
         Rows [0, max_slots) are the decode slots' samples; row max_slots
         is the chunk's first token (meaningful only on final chunks)."""
-        W = self.chunk_tokens
-        toks = np.zeros((1, W), np.int32)
-        toks[0, :length] = chunk_prompt[start:start + length]
-        bt = np.zeros((1, self.mb), np.int32)
-        bt[0, :len(block_ids)] = block_ids
-        sp = {k: jnp.asarray(v) for k, v in sampling.items()}
+        toks, bt = self._chunk_inputs(chunk_prompt, block_ids, start, length)
+        sp = self._put_sampling(sampling)
+        rows = int(np.count_nonzero(active))
         self.dispatches += 1
         with self.tracer.span("dispatch:unified", cat="device",
-                              args={"start": start, "length": length}), \
-                self._label("unified_step"):
+                              args={"start": start, "length": length,
+                                    "rows": rows}, label="unified_step"):
             out, self.state = self._unified(
                 self.params, self.state, jnp.asarray(tokens), sp,
                 jnp.asarray(active), jnp.asarray(toks), jnp.asarray(bt),
                 jnp.int32(start), jnp.int32(start + length))
+        self._count_pass(rows)
+        self._note_compile("unified_step", self._unified)
         return out
 
     def unified_step_chained(self, prev_out, chain_idx: np.ndarray,
@@ -247,24 +285,24 @@ class ModelRunner:
         dispatch's own ``[max_slots + 1]`` buffer as a device array the
         engine reads back one step later.  Non-donating (see __init__):
         the previous state stays alive until its readback."""
-        W = self.chunk_tokens
-        toks = np.zeros((1, W), np.int32)
-        toks[0, :length] = chunk_prompt[start:start + length]
-        bt = np.zeros((1, self.mb), np.int32)
-        bt[0, :len(block_ids)] = block_ids
-        sp = {k: jnp.asarray(v) for k, v in sampling.items()}
+        toks, bt = self._chunk_inputs(chunk_prompt, block_ids, start, length)
+        sp = self._put_sampling(sampling)
         if prev_out is None:
             prev_out = self.zero_prev
+        rows = int(np.count_nonzero(active))
         self.dispatches += 1
         with self.tracer.span("dispatch:unified_chained", cat="device",
-                              args={"start": start, "length": length}), \
-                self._label("unified_step_chained"):
+                              args={"start": start, "length": length,
+                                    "rows": rows},
+                              label="unified_step_chained"):
             out, self.state = self._unified_chained(
                 self.params, self.state, prev_out,
                 jnp.asarray(chain_idx), jnp.asarray(use_prev),
                 jnp.asarray(tokens), sp, jnp.asarray(active),
                 jnp.asarray(toks), jnp.asarray(bt),
                 jnp.int32(start), jnp.int32(start + length))
+        self._count_pass(rows)
+        self._note_compile("unified_step_chained", self._unified_chained)
         return out
 
     @staticmethod
@@ -304,29 +342,36 @@ class ModelRunner:
         return float(max(counts))
 
     # ------------------------------------------------------------ decode
-    def decode(self, tokens: np.ndarray) -> jnp.ndarray:
-        """One per-token decode step for all slots; tokens: [max_slots]."""
+    def decode(self, tokens: np.ndarray, rows: int) -> jnp.ndarray:
+        """One per-token decode step for all slots; tokens: [max_slots],
+        ``rows`` of them live (the slots ``sync_tables`` gave a length)."""
         self.dispatches += 1
-        with self.tracer.span("dispatch:decode", cat="device"), \
-                self._label("decode"):
+        with self.tracer.span("dispatch:decode", cat="device",
+                              args={"rows": rows}, label="decode"):
             logits, self.state = self._decode(self.params, self.state,
                                               jnp.asarray(tokens))
+        self._count_pass(rows)
+        self._note_compile("decode", self._decode)
         return logits
 
     def megastep(self, tokens: np.ndarray, sampling: Dict[str, np.ndarray],
                  active: np.ndarray, n_steps: int) -> np.ndarray:
         """Dispatch one fused horizon; returns the [n_steps, max_slots]
         token buffer as numpy (the ONE host sync of the dispatch)."""
-        sp = {k: jnp.asarray(v) for k, v in sampling.items()}
+        sp = self._put_sampling(sampling)
+        rows = int(np.count_nonzero(active))
         self.dispatches += 1
         with self.tracer.span("dispatch:megastep", cat="device",
-                              args={"n_steps": int(n_steps)}), \
-                self._label("megastep"):
+                              args={"n_steps": int(n_steps), "rows": rows},
+                              label="megastep"):
             out, self.state = self._megastep(
                 self.params, self.state, jnp.asarray(tokens), sp,
                 jnp.asarray(active), jnp.int32(n_steps))
             with self.tracer.span("readback", cat="device"):
-                return np.asarray(out[:n_steps])
+                out_np = np.asarray(out[:n_steps])
+        self._count_pass(rows, int(n_steps))
+        self._note_compile("megastep", self._megastep)
+        return out_np
 
     def sample(self, logits, sampling: Dict[str, np.ndarray]) -> np.ndarray:
         """Per-slot sampling for the legacy loop / prefill first token.
@@ -337,15 +382,17 @@ class ModelRunner:
         kw = {}
         if "poison" in sampling:
             kw["poison"] = jnp.asarray(sampling["poison"])
-        with self.tracer.span("dispatch:sample", cat="device"), \
-                self._label("sample"):
-            return np.asarray(self._sample(
+        with self.tracer.span("dispatch:sample", cat="device",
+                              label="sample"):
+            out = np.asarray(self._sample(
                 logits, jnp.asarray(sampling["keys"]),
                 jnp.asarray(sampling["counts"]),
                 jnp.asarray(sampling["temps"]),
                 jnp.asarray(sampling["top_ks"]),
                 jnp.asarray(sampling["top_ps"]),
                 guard=bool(self.rt.get("sampling_guard")), **kw))
+        self._note_compile("sample", self._sample)
+        return out
 
     # ------------------------------------------------------------ CoW
     def copy_cow(self, pairs: Seq[Tuple[int, int]]) -> None:
@@ -363,11 +410,11 @@ class ModelRunner:
         # int8 mode: the scale rows ride along with the value blocks —
         # a fork that dropped them would dequantize its prefix with junk
         with self.tracer.span("dispatch:cow", cat="device",
-                              args={"pairs": len(pairs)}), \
-                self._label("copy_cow"):
+                              args={"pairs": len(pairs)}, label="copy_cow"):
             for k in _POOL_KEYS:
                 if k in self.state:
                     self.state[k] = copy_blocks(self.state[k], src, dst)
+        self._note_compile("copy_cow", copy_blocks)
 
     # ------------------------------------------------------------ memory
     def kv_pool_bytes(self) -> int:

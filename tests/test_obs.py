@@ -117,6 +117,114 @@ def test_attribution_host_plus_device_is_step():
     assert empty["steps"] == 0.0 and empty["host_ms"] != empty["host_ms"]
 
 
+class _Annotations:
+    """A fake annotation factory: records (event, label) in order."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, label):
+        log = self.log
+
+        class _Ann:
+            def __enter__(self):
+                log.append(("enter", label))
+
+            def __exit__(self, *exc):
+                log.append(("exit", label))
+        return _Ann()
+
+
+@pytest.mark.parametrize("with_factory", [True, False],
+                         ids=["factory", "no-factory"])
+def test_spans_mirror_into_annotation_factory(with_factory):
+    ann = _Annotations()
+    tr = SpanTracer(annotate=ann if with_factory else None)
+    with tr.span("engine.step", cat="step"):
+        with tr.span("dispatch:megastep", cat="device", label="megastep"):
+            with tr.span("readback", cat="device"):
+                pass
+        tr.instant("compile", cat="compile", args={"executable": "x"})
+    # the ring is the same either way
+    assert [s.name for s in tr.spans()] == [
+        "readback", "dispatch:megastep", "compile", "engine.step"]
+    if not with_factory:
+        assert ann.log == []
+        return
+    # each span is one annotation of the same nesting, under its label
+    assert ann.log == [("enter", "engine.step"), ("enter", "megastep"),
+                       ("enter", "readback"), ("exit", "readback"),
+                       ("exit", "megastep"), ("enter", "compile"),
+                       ("exit", "compile"), ("exit", "engine.step")]
+
+
+def test_disabled_tracer_with_factory_is_zero_work():
+    ann = _Annotations()
+    tr = SpanTracer(enabled=False, annotate=ann)
+    assert tr.span("a") is tr.span("b", label="c")
+    with tr.span("a", cat="device"):
+        tr.instant("mark")
+    assert ann.log == [] and tr.spans() == []
+
+
+def _attribute_steps_quadratic(spans, window=None):
+    """The pre-sweep ``attribute_steps``: a device span counts when no
+    other device span contains it (quadratic), summed per step."""
+    spans = list(spans)
+    steps = [s for s in spans if s.name == "engine.step"
+             and s.dur is not None]
+    device = [s for s in spans if s.cat == "device" and s.dur is not None]
+    top = [d for d in device
+           if not any(o is not d and o.ts <= d.ts
+                      and d.ts + d.dur <= o.ts + o.dur for o in device)]
+    rows = []
+    for st in steps:
+        end = st.ts + st.dur
+        dev = sum(d.dur for d in top
+                  if st.ts <= d.ts and d.ts + d.dur <= end)
+        if dev > 0:
+            rows.append((st.dur, dev))
+    if window is not None:
+        rows = rows[-window:]
+    n = len(rows)
+    return (n, sum(r[0] for r in rows) / n / 1e6,
+            sum(r[1] for r in rows) / n / 1e6)
+
+
+def test_attribute_steps_sweep_equals_quadratic_on_nested_spans():
+    from repro.obs import Span
+    rng = np.random.default_rng(1)
+    spans, t = [], 0
+    for i in range(40):
+        t0 = t
+        t += int(rng.integers(5, 50))
+        kids = []
+        for _ in range(int(rng.integers(0, 4))):
+            a = t
+            t += int(rng.integers(1, 30))
+            kids.append(Span("plan", "host", a, t - a, 1, None))
+            a = t
+            t += int(rng.integers(10, 300))
+            disp = Span("dispatch:megastep", "device", a, t - a, 1, None)
+            # a readback nested in the dispatch, as the megastep has
+            rb = Span("readback", "device", a + (t - a) // 2,
+                      (t - a) // 4, 2, None)
+            kids += [rb, disp]
+        if i % 7 == 3:
+            a = t
+            t += 20
+            kids.append(Span("readback", "device", a, 20, 1, None))
+        t += int(rng.integers(1, 9))
+        spans += kids + [Span("engine.step", "step", t0, t - t0, 0, None)]
+        spans.append(Span("req.arrival", "request", t, None, 0, None))
+    for window in (None, 5):
+        new = attribute_steps(spans, window=window)
+        n, step_ms, device_ms = _attribute_steps_quadratic(spans, window)
+        assert new["steps"] == n > 0
+        assert new["step_ms"] == pytest.approx(step_ms)
+        assert new["device_ms"] == pytest.approx(device_ms)
+
+
 # ----------------------------------------------------------------- metrics
 def test_histogram_bucket_edges_le_semantics():
     h = Histogram("h_ms", buckets=(1.0, 5.0, 10.0))
@@ -286,6 +394,68 @@ def test_telemetry_off_engine_still_serves(small):
     assert attr["steps"] == 0.0                  # NaN columns, no crash
     assert rep["itl_p50_ms"] == rep["itl_p50_ms"]  # histograms still on
     assert eng.metrics["gen_tokens"] == 3
+
+
+# the runner's forward-pass dispatch spans and how many passes each makes
+FORWARD = ("dispatch:megastep", "dispatch:unified",
+           "dispatch:unified_chained", "dispatch:decode", "dispatch:chunk",
+           "dispatch:prefill")
+
+
+@pytest.mark.parametrize("mode", [
+    {}, {"enable_async_step": False}, {"enable_unified_step": False},
+    {"use_fused": False}, {"enable_chunked_prefill": False}],
+    ids=["async", "unified", "two-call", "legacy-decode", "oracle-prefill"])
+def test_forward_pass_spans_carry_rows(small, mode):
+    cfg, params = small
+    eng = ServingEngine(cfg, params, max_slots=4, num_blocks=128,
+                        max_blocks_per_seq=8, prefill_bucket=16,
+                        max_num_batched_tokens=16, **mode)
+    rng = np.random.default_rng(2)
+    for _ in range(6):
+        eng.add(list(rng.integers(1, 200, int(rng.integers(3, 30)))),
+                SamplingParams(max_tokens=int(rng.integers(2, 12))))
+    eng.run_until_done()
+    passes = rows = 0
+    for s in eng.tracer.spans():
+        if s.name not in FORWARD:
+            continue
+        assert 0 <= s.args["rows"] <= 4, s
+        n = s.args["n_steps"] if s.name == "dispatch:megastep" else 1
+        passes += n
+        rows += s.args["rows"] * n
+    assert passes > 0 and rows > 0
+    # the registry counts the same passes and rows at the same boundary
+    assert eng.obs.get("repro_forward_passes").get() == passes
+    assert eng.obs.get("repro_decode_rows").get() == rows
+    text = eng.obs.to_prometheus()
+    assert "repro_forward_passes" in text and "repro_decode_rows" in text
+
+
+def test_compile_fires_once_per_executable(small):
+    cfg, params = small
+    eng = ServingEngine(cfg, params, max_slots=4, num_blocks=128,
+                        max_blocks_per_seq=8, max_num_batched_tokens=16)
+
+    def serve(seed):
+        rng = np.random.default_rng(seed)
+        for _ in range(5):
+            eng.add(list(rng.integers(1, 200, int(rng.integers(3, 40)))),
+                    SamplingParams(max_tokens=6))
+        eng.run_until_done()
+
+    def compiles():
+        return [s.args["executable"] for s in eng.tracer.spans()
+                if s.name == "compile"]
+    serve(0)
+    first = compiles()
+    assert first and len(first) == len(set(first))     # once each
+    assert {"unified_step_chained", "megastep"} <= set(first)
+    assert eng.obs.get("repro_compiles").get() == len(first)
+    eng.tracer.clear()
+    serve(1)                                            # a warm loop
+    assert compiles() == []
+    assert eng.obs.get("repro_compiles").get() == len(first)
 
 
 def test_straggler_events_bounded():

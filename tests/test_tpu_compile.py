@@ -10,6 +10,8 @@ pages, a 256-token chunk, the 1024 x 2816 MLP).
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and xdist workers import every file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -54,6 +56,17 @@ def chip(topo):
 
 def _hlo(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
+
+
+_COMPILED = {}
+
+
+def _built(chip, build, args):
+    """The compiled text of one kernel case, compiled once per module."""
+    key = (build.__name__, args)
+    if key not in _COMPILED:
+        _COMPILED[key] = build(chip, *args)
+    return _COMPILED[key]
 
 
 def _decode(chip, kv, dtype, alibi):
@@ -125,7 +138,25 @@ def _gptq(chip, group_size, m):
         "prefill-kv2-alibi", "prefill-kv16", "gptq-g32", "gptq-g128",
         "gptq-g32-chunk"])
 def test_kernel_compiles_for_v5e(chip, build, args):
-    assert "tpu_custom_call" in build(chip, *args)
+    assert "tpu_custom_call" in _built(chip, build, args)
+
+
+@pytest.mark.parametrize("build,args,name", [
+    (_decode, (16, jnp.bfloat16, False), "paged_attention"),
+    (_decode, (2, jnp.int8, False), "paged_attention_quant"),
+    (_chunk, (2, jnp.int8, False), "flash_attention_chunk"),
+    (_prefill, (16, jnp.bfloat16, False), "flash_attention"),
+    (_gptq, (128, SLOTS), "gptq_matmul"),
+], ids=["paged_attention", "paged_attention_quant", "flash_attention_chunk",
+        "flash_attention", "gptq_matmul"])
+def test_kernel_hlo_names_are_stable(chip, build, args, name):
+    """Each kernel's instruction carries the name its ``pallas_call``
+    gives, whatever wrapper calls it: the names the benchmark's trace
+    reduction finds kernels by."""
+    calls = re.findall(r"^\s*(?:ROOT )?%([\w\-]+?)(?:\.\d+)* = .*"
+                       r'custom_call_target="tpu_custom_call"',
+                       _built(chip, build, args), re.M)
+    assert calls == [name]
 
 
 @pytest.mark.parametrize("kv,kv_dtype,quant", [

@@ -2,11 +2,15 @@
 (``enable_unified_step=False``): greedy token-exactness on both KV pool
 formats, bitwise-identical fused sampling, preemption mid-prefill, the
 single-compile guarantee, and the dispatch-count accounting."""
+import re
+
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs.registry import get_reduced
+from repro.core.kv_quant import cache_from_state
 from repro.models import transformer as T
 from repro.serving import SamplingParams, ServingEngine
 
@@ -153,3 +157,50 @@ def test_unified_requires_chunked_and_fused(small):
                           enable_unified_step=False),
                   prompts, [SamplingParams(max_tokens=4)] * 2)
     assert a == b
+
+
+# the step's named regions, by executable: every region of the decode
+# rows and of the chunk, and the feed gather of the chained step.  On the
+# CPU the chunk's attention is the XLA reference over the whole pool, so
+# its pool_slice shows only where Pallas runs (interpret mode here).
+_REGIONS = ("embed", "attention", "kv_write", "pool_slice", "mlp",
+            "lm_head")
+
+
+@pytest.mark.parametrize("executable,scopes", [
+    ("unified", _REGIONS + ("sample",)),
+    ("unified_chained", _REGIONS + ("sample", "chain_gather")),
+    ("megastep", _REGIONS + ("sample",)),
+    ("chunk", _REGIONS)])
+def test_step_regions_are_named_scopes(small, executable, scopes):
+    cfg, params = small
+    slots, mb, w = 3, 8, 16
+    state = T.make_decode_state(cfg, slots, 64, mb, kv_cache_dtype="int8")
+    n = slots + 1
+    sp = {"keys": jnp.zeros((n, 2), jnp.uint32),
+          "counts": jnp.zeros((n,), jnp.int32),
+          "temps": jnp.zeros((n,), jnp.float32),
+          "top_ks": jnp.zeros((n,), jnp.int32),
+          "top_ps": jnp.ones((n,), jnp.float32)}
+    toks = jnp.zeros((slots,), jnp.int32)
+    active = jnp.ones((slots,), bool)
+    chunk = (jnp.zeros((1, w), jnp.int32), jnp.zeros((1, mb), jnp.int32),
+             jnp.int32(0), jnp.int32(5))
+    fns = {
+        "unified": (lambda: T.unified_step(
+            cfg, params, state, toks, sp, active, *chunk)),
+        "unified_chained": (lambda: T.unified_step_chained(
+            cfg, params, state, jnp.zeros((n,), jnp.int32), toks,
+            active, toks, sp, active, *chunk)),
+        "megastep": (lambda: T.decode_megastep(
+            cfg, params, state, toks, {k: v[:slots] for k, v in sp.items()},
+            active, jnp.int32(2), max_horizon=2)),
+        "chunk": (lambda: T.prefill_chunk(
+            cfg, params, cache_from_state(state), *chunk,
+            rt={"use_pallas": True, "interpret": True})),
+    }
+    text = jax.jit(fns[executable]).lower().as_text(debug_info=True)
+    found = {part for loc in re.findall(r'loc\("([^"]*)"', text)
+             for part in loc.split("/")}
+    missing = [s for s in scopes if s not in found]
+    assert not missing, f"{executable}: no ops in {missing}"
