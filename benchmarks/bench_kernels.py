@@ -19,12 +19,13 @@ def run() -> None:
     NB = B * MB
     ks = jax.random.split(key, 4)
     q = jax.random.normal(ks[0], (B, H, D), jnp.float32)
-    kp = jax.random.normal(ks[1], (NB, BS, KV, D), jnp.float32)
-    vp = jax.random.normal(ks[2], (NB, BS, KV, D), jnp.float32)
+    kp = jax.random.normal(ks[1], (1, NB, KV, BS, D), jnp.float32)
+    vp = jax.random.normal(ks[2], (1, NB, KV, BS, D), jnp.float32)
     bt = jnp.arange(NB, dtype=jnp.int32).reshape(B, MB)
     sl = jnp.full((B,), MB * BS, jnp.int32)
     slo = alibi_slopes(H)
-    f_ref = jax.jit(lambda *a: ref.paged_attention_ref(*a, alibi_slopes=slo))
+    f_ref = jax.jit(lambda q, k, v, bt, sl: ref.paged_attention_ref(
+        q, k, v, 0, bt, sl, alibi_slopes=slo))
     us_ref = timeit(f_ref, q, kp, vp, bt, sl)
     kv_bytes = 2 * NB * BS * KV * D * 4
     ai = (4 * B * H * MB * BS * D) / kv_bytes

@@ -5,7 +5,8 @@ management is what buys serving headroom; the GPTQ side only quantizes
 weights.  This module quantizes the other big HBM consumer — the paged
 KV pool — to symmetric per-block-per-head int8:
 
-* values pool ``[L, NB, BS, KV, D]`` int8 (vs bf16/f32), and
+* values pool ``[L, NB, KV, BS, D]`` int8 (vs bf16/f32), head-major so
+  each (block, kv head) is one dense ``[BS, D]`` tile, and
 * scales pool ``[L, NB, KV]`` f32 — ONE scale per (block, kv head),
   covering all ``BS`` tokens × ``D`` dims of that head's tile,
 
@@ -15,7 +16,10 @@ dequantize in-register: the Pallas decode kernel
 (``kernels/paged_attention_quant.py``) multiplies each int8 K/V tile by
 its scale inside the online-softmax loop — the quantized cache is never
 materialized densely (TurboAttention, arXiv 2412.08585; MILLION, arXiv
-2504.03661).
+2504.03661).  Writers and kernels address the stacked pool in place:
+a write is a scatter into ``values[layer, block]``, a read is the
+kernel's DMA of ``values[layer, block]``; no per-layer slice of the
+pool is ever copied out or written back.
 
 Write discipline (what keeps one scale per block sound):
 
@@ -43,8 +47,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.paged_cache import (copy_blocks, gather_kv,
-                                    gather_kv_bounded, write_decode_kv,
-                                    write_prefill_kv)
+                                    gather_kv_bounded, pages_to_tokens,
+                                    write_decode_kv, write_prefill_kv)
 
 INT8_MAX = 127.0
 # floor on amax before the /127: keeps all-zero blocks at scale ~1e-22
@@ -74,7 +78,7 @@ def normalize_kv_cache_dtype(kv_cache_dtype: Optional[str]) -> str:
 class KVCache(NamedTuple):
     """K/V pools plus (optionally) their scale pools, as one pytree.
 
-    ``k``/``v``: [L, NB, BS, KV, D] — bf16/f32/fp8 in the unquantized
+    ``k``/``v``: [L, NB, KV, BS, D] — bf16/f32/fp8 in the unquantized
     mode, int8 in the quantized one.  ``k_scale``/``v_scale``: [L, NB, KV]
     f32 in int8 mode, ``None`` otherwise (None is an empty pytree, so the
     same scan/shard_map plumbing carries both modes).
@@ -90,7 +94,7 @@ class KVCache(NamedTuple):
 
     @property
     def block_size(self) -> int:
-        return self.k.shape[2]
+        return self.k.shape[3]
 
     def nbytes(self) -> int:
         return sum(int(a.size) * a.dtype.itemsize
@@ -115,9 +119,9 @@ def make_kv_pool_quant(num_layers: int, num_blocks: int, block_size: int,
                        num_kv_heads: int, head_dim: int
                        ) -> Tuple[jnp.ndarray, jnp.ndarray,
                                   jnp.ndarray, jnp.ndarray]:
-    """(k_values, v_values [L,NB,BS,KV,D] int8, k_scales, v_scales
+    """(k_values, v_values [L,NB,KV,BS,D] int8, k_scales, v_scales
     [L,NB,KV] f32)."""
-    vshape = (num_layers, num_blocks, block_size, num_kv_heads, head_dim)
+    vshape = (num_layers, num_blocks, num_kv_heads, block_size, head_dim)
     sshape = (num_layers, num_blocks, num_kv_heads)
     return (jnp.zeros(vshape, jnp.int8), jnp.zeros(vshape, jnp.int8),
             jnp.zeros(sshape, jnp.float32), jnp.zeros(sshape, jnp.float32))
@@ -132,23 +136,24 @@ def quantize_blocks(x: jnp.ndarray, live: jnp.ndarray
                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Symmetric per-block-per-head int8 quantization.
 
-    x: [..., BS, KV, D] float; live: [..., BS] bool — slots outside the
-    mask are zeroed *before* the amax so junk can never inflate the scale
-    (and they quantize to exactly 0).  Returns (q int8 like x,
-    scales [..., KV] f32) with ``scale = amax / 127`` so the roundtrip
-    error of any live value is <= scale / 2.
+    x: [..., KV, BS, D] float (head-major pages); live: [..., BS] bool —
+    slots outside the mask are zeroed *before* the amax so junk can never
+    inflate the scale (and they quantize to exactly 0).  Returns (q int8
+    like x, scales [..., KV] f32) with ``scale = amax / 127`` over each
+    head's ``[BS, D]`` tile, so the roundtrip error of any live value is
+    <= scale / 2.
     """
-    xf = jnp.where(live[..., None, None], x.astype(jnp.float32), 0.0)
-    amax = jnp.max(jnp.abs(xf), axis=(-3, -1))                 # [..., KV]
+    xf = jnp.where(live[..., None, :, None], x.astype(jnp.float32), 0.0)
+    amax = jnp.max(jnp.abs(xf), axis=(-2, -1))                 # [..., KV]
     scales = jnp.maximum(amax, AMAX_FLOOR) / INT8_MAX
-    q = jnp.round(xf / scales[..., None, :, None])
+    q = jnp.round(xf / scales[..., None, None])
     q = jnp.clip(q, -INT8_MAX, INT8_MAX).astype(jnp.int8)
     return q, scales
 
 
 def dequantize_blocks(q: jnp.ndarray, scales: jnp.ndarray) -> jnp.ndarray:
-    """q: [..., BS, KV, D] int8, scales: [..., KV] -> f32 values."""
-    return q.astype(jnp.float32) * scales[..., None, :, None]
+    """q: [..., KV, BS, D] int8, scales: [..., KV] -> f32 values."""
+    return q.astype(jnp.float32) * scales[..., None, None]
 
 
 # --------------------------------------------------------------------------
@@ -162,13 +167,14 @@ def write_prefill_kv_quant(values: jnp.ndarray, scales: jnp.ndarray,
                            ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Quantize a prompt (or prompt chunk) into the int8 pool.
 
-    values: [L, NB, BS, KV, D] int8; scales: [L, NB, KV] f32;
+    values: [L, NB, KV, BS, D] int8; scales: [L, NB, KV] f32;
     k: [B, S, KV, D] holding positions ``pos_offset + i``; only absolute
     positions < ctx_lens are live.  Each touched block is quantized whole:
     blocks starting at/after ``pos_offset`` are fresh (scale overwritten);
     the one boundary block a chunked prefill appends into merges the
     dequantized live prefix first (``lead == 0`` degenerates to the
-    fresh write).
+    fresh write).  The blocks are scattered straight into
+    ``values[layer]`` of the stacked pool.
 
     ``pos_offset`` may be a Python int or a *traced* scalar: the serving
     chunk-prefill executable compiles once for a fixed ``[B, S]`` chunk
@@ -177,7 +183,7 @@ def write_prefill_kv_quant(values: jnp.ndarray, scales: jnp.ndarray,
     forms — which constant-fold when the offset is static.
     """
     B, S, KV, D = k.shape
-    NB, bs = values.shape[1], values.shape[2]
+    NB, bs = values.shape[1], values.shape[3]
     nb = -(-S // bs) + 1                       # static max touched blocks
     j0 = pos_offset // bs                      # first touched block (traced)
     lead = pos_offset - j0 * bs                # live prefix rows in block j0
@@ -185,13 +191,11 @@ def write_prefill_kv_quant(values: jnp.ndarray, scales: jnp.ndarray,
     buf = jnp.zeros((B, nb * bs, KV, D), jnp.float32)
     buf = jax.lax.dynamic_update_slice(buf, k.astype(jnp.float32),
                                        (0, lead, 0, 0))
-    buf = buf.reshape(B, nb, bs, KV, D)
+    buf = jnp.swapaxes(buf.reshape(B, nb, bs, KV, D), 2, 3)  # [B,nb,KV,bs,D]
     pos = (j0 * bs + jnp.arange(nb * bs)).reshape(nb, bs)
     live = ((pos[None] >= pos_offset)
             & (pos[None] < ctx_lens[:, None, None]))           # [B, nb, bs]
 
-    lp = values[layer]                                         # [NB,BS,KV,D]
-    ls = scales[layer]                                         # [NB,KV]
     # pad the table with the OOB sentinel so the dynamic slice never
     # clamps (a clamped start would misalign every block of the chunk);
     # sentinel rows are dead (live is False past the capacity) anyway.
@@ -201,17 +205,17 @@ def write_prefill_kv_quant(values: jnp.ndarray, scales: jnp.ndarray,
     # chunk boundary: block j0 may already hold this sequence's tokens at
     # slots [0, lead) — dequantize and merge them before requantizing.
     safe0 = jnp.minimum(blk[:, 0], NB - 1)
-    old = dequantize_blocks(lp[safe0], ls[safe0])              # [B,bs,KV,D]
+    old = dequantize_blocks(values[layer, safe0],
+                            scales[layer, safe0])              # [B,KV,bs,D]
     old_live = ((jnp.arange(bs)[None] < lead)
                 & (pos[0][None] < ctx_lens[:, None]))          # [B, bs]
-    buf = buf.at[:, 0].add(jnp.where(old_live[..., None, None], old, 0.0))
+    buf = buf.at[:, 0].add(jnp.where(old_live[:, None, :, None], old, 0.0))
     live = live.at[:, 0].set(live[:, 0] | old_live)
 
     q, sc = quantize_blocks(buf, live)
     tgt = jnp.where(live.any(-1), blk, NB)                     # [B, nb]
-    lp = lp.at[tgt].set(q, mode="drop")
-    ls = ls.at[tgt].set(sc, mode="drop")
-    return values.at[layer].set(lp), scales.at[layer].set(ls)
+    return (values.at[layer, tgt].set(q, mode="drop"),
+            scales.at[layer, tgt].set(sc, mode="drop"))
 
 
 def write_decode_kv_quant(values: jnp.ndarray, scales: jnp.ndarray,
@@ -220,30 +224,30 @@ def write_decode_kv_quant(values: jnp.ndarray, scales: jnp.ndarray,
                           ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Append one token per sequence to its (private, CoW-guaranteed) tail
     block: dequantize the live prefix, insert the token, requantize the
-    block with the recomputed amax.  positions: [B] absolute position of
-    the new token; negative => inactive slot, write dropped.
+    block with the recomputed amax, and scatter the block straight into
+    ``values[layer]`` (in place: one [KV, BS, D] block per sequence, never
+    the layer's whole slice).  positions: [B] absolute position of the
+    new token; negative => inactive slot, write dropped.
     """
-    NB, bs = values.shape[1], values.shape[2]
+    NB, bs = values.shape[1], values.shape[3]
     valid = positions >= 0
     pos = jnp.maximum(positions, 0)
     blk = jnp.take_along_axis(block_table, (pos // bs)[:, None],
                               axis=1)[:, 0]                    # [B]
     off = pos % bs                                             # [B]
 
-    lp = values[layer]
-    ls = scales[layer]
-    old = dequantize_blocks(lp[blk], ls[blk])                  # [B,bs,KV,D]
+    old = dequantize_blocks(values[layer, blk],
+                            scales[layer, blk])                # [B,KV,bs,D]
     slot = jnp.arange(bs)[None, :]                             # [1, bs]
-    buf = jnp.where((slot < off[:, None])[..., None, None], old, 0.0)
-    buf = jnp.where((slot == off[:, None])[..., None, None],
-                    k_new[:, None].astype(jnp.float32), buf)
+    buf = jnp.where((slot < off[:, None])[:, None, :, None], old, 0.0)
+    buf = jnp.where((slot == off[:, None])[:, None, :, None],
+                    k_new[:, :, None].astype(jnp.float32), buf)
     live = slot <= off[:, None]                                # [B, bs]
     q, sc = quantize_blocks(buf, live)
 
     tgt = jnp.where(valid, blk, NB)                            # OOB -> dropped
-    lp = lp.at[tgt].set(q, mode="drop")
-    ls = ls.at[tgt].set(sc, mode="drop")
-    return values.at[layer].set(lp), scales.at[layer].set(ls)
+    return (values.at[layer, tgt].set(q, mode="drop"),
+            scales.at[layer, tgt].set(sc, mode="drop"))
 
 
 def gather_kv_quant(values: jnp.ndarray, scales: jnp.ndarray, layer,
@@ -251,12 +255,11 @@ def gather_kv_quant(values: jnp.ndarray, scales: jnp.ndarray, layer,
                     dtype=jnp.float32) -> jnp.ndarray:
     """Dequantizing counterpart of ``gather_kv`` (reference / chunked
     prefill path): [B, max_len, KV, D] in ``dtype``."""
-    bs = values.shape[2]
+    bs = values.shape[3]
     nb = -(-max_len // bs)
     blk = block_table[:, :nb]                                  # [B, nb]
-    x = dequantize_blocks(values[layer][blk], scales[layer][blk])
-    return x.reshape(blk.shape[0], nb * bs,
-                     *values.shape[3:])[:, :max_len].astype(dtype)
+    x = dequantize_blocks(values[layer, blk], scales[layer, blk])
+    return pages_to_tokens(x)[:, :max_len].astype(dtype)
 
 
 def gather_kv_quant_bounded(values: jnp.ndarray, scales: jnp.ndarray, layer,
@@ -268,10 +271,10 @@ def gather_kv_quant_bounded(values: jnp.ndarray, scales: jnp.ndarray, layer,
     page per ``fori_loop`` iteration), the rest of the static
     ``[B, max_len, KV, D]`` view stays zero — O(live) dequant work
     instead of O(capacity) per layer per chunk."""
-    bs = values.shape[2]
+    bs = values.shape[3]
     nb = -(-max_len // bs)
     B = block_table.shape[0]
-    buf = jnp.zeros((B, nb, bs) + values.shape[3:], dtype)
+    buf = jnp.zeros((B, nb) + values.shape[2:], dtype)
 
     def body(j, buf):
         blk = block_table[:, j]                            # [B]
@@ -283,7 +286,7 @@ def gather_kv_quant_bounded(values: jnp.ndarray, scales: jnp.ndarray, layer,
     buf = jax.lax.fori_loop(
         0, jnp.minimum(jnp.asarray(num_live_blocks, jnp.int32), nb),
         body, buf)
-    return buf.reshape(B, nb * bs, *values.shape[3:])[:, :max_len]
+    return pages_to_tokens(buf)[:, :max_len]
 
 
 def copy_blocks_quant(values: jnp.ndarray, scales: jnp.ndarray,

@@ -6,12 +6,13 @@ Two halves, mirroring vLLM on TPU:
   free-list allocation, ref-counted blocks, prefix-hash reuse
   (copy-on-write), watermark admission. Pure Python, drives the scheduler.
 
-* **Device side** — the pool itself is ONE dense array per layer
-  ``[num_blocks, block_size, kv_heads, head_dim]`` (pre-allocated: the
-  paper's "pre-allocate memory pools to minimize allocation overhead"),
-  plus an int32 ``block_table [max_seqs, max_blocks_per_seq]``. Jitted
-  scatter/gather ops below; the Pallas decode kernel consumes the pool +
-  table directly.
+* **Device side** — the pool itself is ONE dense array stacked over
+  layers, ``[layers, num_blocks, kv_heads, block_size, head_dim]``
+  (pre-allocated: the paper's "pre-allocate memory pools to minimize
+  allocation overhead"), plus an int32 ``block_table [max_seqs,
+  max_blocks_per_seq]``. Jitted scatter/gather ops below index the
+  stacked pool directly; the Pallas kernels read the pool + table in
+  place, one ``[block_size, head_dim]`` page tile per (block, head).
 """
 from __future__ import annotations
 
@@ -309,57 +310,62 @@ class BlockAllocator:
 
 def make_kv_pool(num_layers: int, num_blocks: int, block_size: int,
                  num_kv_heads: int, head_dim: int, dtype=jnp.bfloat16):
-    """Pre-allocated pool: (k_pool, v_pool) each [L, num_blocks, bs, KV, D]."""
-    shape = (num_layers, num_blocks, block_size, num_kv_heads, head_dim)
+    """Pre-allocated pool: (k_pool, v_pool) each [L, NB, KV, BS, D].
+
+    Head-major pages: each (layer, block, head) is one dense ``[BS, D]``
+    tile, which the paged kernels read in place from the stacked pool
+    (no per-layer slice or relayout) and which is aligned to the TPU's
+    tiling for any KV count once BS is a multiple of the sublane tile.
+    """
+    shape = (num_layers, num_blocks, num_kv_heads, block_size, head_dim)
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
 
-def write_decode_kv(pool: jnp.ndarray, layer: int, k_new: jnp.ndarray,
+def pages_to_tokens(pages: jnp.ndarray) -> jnp.ndarray:
+    """[..., n, KV, BS, D] pages -> the token-major [..., n * BS, KV, D]
+    view the contiguous attention references take."""
+    *lead, n, kv, bs, d = pages.shape
+    return jnp.swapaxes(pages, -3, -2).reshape(*lead, n * bs, kv, d)
+
+
+def write_decode_kv(pool: jnp.ndarray, layer, k_new: jnp.ndarray,
                     block_table: jnp.ndarray, positions: jnp.ndarray) -> jnp.ndarray:
     """Scatter one token's K (or V) per sequence into the paged pool.
 
-    pool: [L, NB, BS, KV, D]; k_new: [B, KV, D]; block_table: [B, MB];
+    pool: [L, NB, KV, BS, D]; k_new: [B, KV, D]; block_table: [B, MB];
     positions: [B] absolute position of the new token. Negative positions
     (inactive decode slots, seq_len == 0) are dropped instead of wrapping
-    around and corrupting a live block.
+    around and corrupting a live block.  Indexes the stacked pool
+    directly: an in-place scatter, no per-layer slice.
     """
-    bs = pool.shape[2]
+    bs = pool.shape[3]
     valid = positions >= 0
     pos = jnp.maximum(positions, 0)
     blk = jnp.take_along_axis(block_table, (pos // bs)[:, None], axis=1)[:, 0]
     blk = jnp.where(valid, blk, pool.shape[1])                 # OOB -> dropped
     off = pos % bs
-    return pool.at[layer, blk, off].set(k_new.astype(pool.dtype),
-                                        mode="drop")
+    return pool.at[layer, blk, :, off].set(k_new.astype(pool.dtype),
+                                           mode="drop")
 
 
-def write_prefill_kv(pool: jnp.ndarray, layer: int, k: jnp.ndarray,
+def write_prefill_kv(pool: jnp.ndarray, layer, k: jnp.ndarray,
                      block_table: jnp.ndarray, ctx_lens: jnp.ndarray,
-                     pos_offset: int = 0) -> jnp.ndarray:
+                     pos_offset=0) -> jnp.ndarray:
     """Scatter a prompt (or prompt chunk) K/V into the pool.
 
-    k: [B, S, KV, D] (padded); k[:, i] holds position pos_offset + i; only
-    absolute positions < ctx_lens are written.
+    pool: [L, NB, KV, BS, D]; k: [B, S, KV, D] (padded); k[:, i] holds
+    position pos_offset + i; only absolute positions < ctx_lens are
+    written (the rest are routed out of bounds and dropped).
     """
-    B, S = k.shape[:2]
-    bs = pool.shape[2]
+    S = k.shape[1]
+    bs = pool.shape[3]
     pos = pos_offset + jnp.arange(S)
     blk = block_table[:, pos // bs]                       # [B, S]
     off = pos % bs                                         # [S]
     valid = pos[None, :] < ctx_lens[:, None]               # [B, S]
-    # route invalid tokens to a scratch (last) block offset that is then
-    # overwritten by valid data — use mode='drop' semantics via clipping +
-    # where on the payload.
-    blk = jnp.where(valid, blk, pool.shape[1] - 1)
-    k = jnp.where(valid[..., None, None], k, 0).astype(pool.dtype)
-    flat_idx = (blk * bs + off[None, :]).reshape(-1)
-    upd = k.reshape(B * S, *k.shape[2:])
-    L, NB, BS = pool.shape[:3]
-    lp = pool[layer].reshape(NB * BS, *pool.shape[3:])
-    # guard scratch writes: drop invalid rows entirely
-    flat_idx = jnp.where(valid.reshape(-1), flat_idx, NB * BS)   # OOB -> dropped
-    lp = lp.at[flat_idx].set(upd, mode="drop")
-    return pool.at[layer].set(lp.reshape(NB, BS, *pool.shape[3:]))
+    blk = jnp.where(valid, blk, pool.shape[1])             # OOB -> dropped
+    return pool.at[layer, blk, :, off[None, :]].set(k.astype(pool.dtype),
+                                                    mode="drop")
 
 
 def gather_kv_bounded(pool: jnp.ndarray, layer, block_table: jnp.ndarray,
@@ -377,34 +383,33 @@ def gather_kv_bounded(pool: jnp.ndarray, layer, block_table: jnp.ndarray,
     invisible in the output — the full-capacity gather path and this one
     are bitwise interchangeable.
     """
-    bs = pool.shape[2]
+    bs = pool.shape[3]
     nb = -(-max_len // bs)
     B = block_table.shape[0]
-    buf = jnp.zeros((B, nb, bs) + pool.shape[3:], pool.dtype)
+    buf = jnp.zeros((B, nb) + pool.shape[2:], pool.dtype)
 
     def body(j, buf):
-        page = pool[layer, block_table[:, j]]          # [B, bs, ...]
+        page = pool[layer, block_table[:, j]]          # [B, KV, bs, D]
         return jax.lax.dynamic_update_slice_in_dim(buf, page[:, None], j,
                                                    axis=1)
 
     buf = jax.lax.fori_loop(
         0, jnp.minimum(jnp.asarray(num_live_blocks, jnp.int32), nb),
         body, buf)
-    return buf.reshape(B, nb * bs, *pool.shape[3:])[:, :max_len]
+    return pages_to_tokens(buf)[:, :max_len]
 
 
-def gather_kv(pool: jnp.ndarray, layer: int, block_table: jnp.ndarray,
+def gather_kv(pool: jnp.ndarray, layer, block_table: jnp.ndarray,
               max_len: int) -> jnp.ndarray:
     """Gather a contiguous [B, max_len, KV, D] view (reference path only).
 
     ``max_len`` need not be a block multiple: the tail partial block is
     gathered too and the result sliced back to exactly max_len rows.
     """
-    bs = pool.shape[2]
+    bs = pool.shape[3]
     nb = -(-max_len // bs)                                 # ceil: keep the tail
     blk = block_table[:, :nb]                              # [B, nb]
-    g = pool[layer][blk]                                   # [B, nb, bs, KV, D]
-    return g.reshape(blk.shape[0], nb * bs, *pool.shape[3:])[:, :max_len]
+    return pages_to_tokens(pool[layer, blk])[:, :max_len]
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -412,7 +417,8 @@ def copy_blocks(pool: jnp.ndarray, src: jnp.ndarray,
                 dst: jnp.ndarray) -> jnp.ndarray:
     """Device-side block copy for the allocator's copy-on-write path.
 
-    pool: [L, NB, BS, KV, D]; src/dst: [n] int32 physical block ids. Copies
+    pool: [L, NB, ...] (a value pool [L, NB, KV, BS, D] or a scale pool
+    [L, NB, KV]); src/dst: [n] int32 physical block ids. Copies
     pool[:, src[i]] -> pool[:, dst[i]] for every layer without the contents
     ever round-tripping through host numpy. Donated: updates in place.
     """

@@ -108,24 +108,26 @@ def _fa_chunk_kernel(block_table_ref, info_ref,      # scalar prefetch (SMEM)
 
     The K axis of the grid walks TWO sources: the first
     ``num_pool_blocks`` steps are paged-pool pages holding the already-
-    prefilled prefix ``[0, q_offset)`` (physical page ids resolved from
-    the prefetched block table, exactly like ``paged_attention.py``), the
+    prefilled prefix ``[0, q_offset)`` (``(layer, physical page)`` of the
+    stacked pool resolved from the prefetched scalars, exactly like
+    ``paged_attention.py``: the pool is read in place), the
     remaining ``num_raw_blocks`` steps are the chunk's own raw K/V tiles
     at absolute positions ``[q_offset, q_offset + W)`` — the chunk
     attends its own tokens unquantized / un-roundtripped, matching the
     whole-prompt prefill semantics (and keeping int8 parity).
 
-    Every tile holds all KV heads (``[BS, KV, D]``, the pool's own
-    layout); a static loop over heads contracts each head's slice with
+    Every tile holds all KV heads, head-major (``[KV, BS, D]``, the
+    pool's own page layout; the raw tiles are laid out the same way); a
+    static loop over heads contracts each head's ``[BS, D]`` tile with
     its G query heads, as the decode kernel does.
 
-    ``info_ref`` holds the two *traced* scalars ``[q_offset, total_len]``
-    — the causal mask, ALiBi distances and the live-page clamp are all
-    computed from them, so every chunk of every prompt runs from one
-    compiled executable.  ``quantized`` reuses the in-register dequant
-    of ``paged_attention_quant.py``: pool tiles are int8 with one f32
-    scale per (page, kv head) in SMEM; raw tiles are always full
-    precision.
+    ``info_ref`` holds the *traced* scalars ``[q_offset, total_len,
+    layer]`` — the causal mask, ALiBi distances, the live-page clamp and
+    the pool's layer are all computed from them, so every chunk of every
+    prompt and every layer runs from one compiled executable.
+    ``quantized`` reuses the in-register dequant of
+    ``paged_attention_quant.py``: pool tiles are int8 with one f32 scale
+    per (page, kv head) in SMEM; raw tiles are always full precision.
     """
     if quantized:
         (kp_ref, ks_ref, vp_ref, vs_ref, kr_ref, vr_ref,
@@ -177,11 +179,11 @@ def _fa_chunk_kernel(block_table_ref, info_ref,      # scalar prefetch (SMEM)
         k_pos = ik * block_size + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_size), 1)
         for h in range(num_kv_heads):
-            k = kp_ref[0, :, h, :].astype(jnp.float32)     # [BS, D]
-            v = vp_ref[0, :, h, :].astype(jnp.float32)
+            k = kp_ref[0, 0, h].astype(jnp.float32)        # [BS, D]
+            v = vp_ref[0, 0, h].astype(jnp.float32)
             if quantized:
-                k = k * ks_ref[0, 0, h]
-                v = v * vs_ref[0, 0, h]
+                k = k * ks_ref[0, 0, 0, h]
+                v = v * vs_ref[0, 0, 0, h]
             _accum(h, k, v, k_pos, k_pos < q_off)
 
     pool_live = jnp.logical_and(ik < num_pool_blocks,
@@ -201,8 +203,8 @@ def _fa_chunk_kernel(block_table_ref, info_ref,      # scalar prefetch (SMEM)
             jnp.int32, (block_q, block_size), 1)
         mask = (k_pos < tlen) & (q_pos - k_pos >= 0)
         for h in range(num_kv_heads):
-            k = kr_ref[0, :, h, :].astype(jnp.float32)     # [BS, D]
-            v = vr_ref[0, :, h, :].astype(jnp.float32)
+            k = kr_ref[0, h].astype(jnp.float32)           # [BS, D]
+            v = vr_ref[0, h].astype(jnp.float32)
             _accum(h, k, v, k_pos, mask)
 
     j = ik - num_pool_blocks
@@ -227,8 +229,9 @@ def _fa_chunk_kernel(block_table_ref, info_ref,      # scalar prefetch (SMEM)
     static_argnames=("sliding_window", "block_q", "interpret"))
 def flash_attention_chunk(
     q: jnp.ndarray,                  # [1, W, H, D] — one chunk, one sequence
-    k_pool: jnp.ndarray,             # [NB, BS, KV, D] (int8 when quantized)
+    k_pool: jnp.ndarray,             # [L, NB, KV, BS, D] (int8 when quantized)
     v_pool: jnp.ndarray,
+    layer: jnp.ndarray,              # i32 scalar: the layer read in place
     block_table: jnp.ndarray,        # [1, MB] int32
     q_offset: jnp.ndarray,           # i32 scalar (traced)
     total_len: jnp.ndarray,          # i32 scalar (traced): q_offset + live len
@@ -236,7 +239,7 @@ def flash_attention_chunk(
     v_raw: jnp.ndarray,
     alibi_slopes: Optional[jnp.ndarray] = None,   # [H]
     *,
-    k_scales: Optional[jnp.ndarray] = None,       # [NB, KV] f32 (int8 pools)
+    k_scales: Optional[jnp.ndarray] = None,       # [L, NB, KV] f32 (int8)
     v_scales: Optional[jnp.ndarray] = None,
     sliding_window: int = 0,
     block_q: int = 128,
@@ -247,9 +250,10 @@ def flash_attention_chunk(
     The dynamic-offset counterpart of ``flash_attention``: ``q_offset``
     and ``total_len`` are *device scalars* (scalar-prefetch operands), so
     the fixed-shape ``[1, W]`` serving chunk executable needs no gather
-    of the pool to a contiguous ``[cap]`` view and no per-offset
-    recompile — the page walk is bounded by the live prefix length the
-    way ``paged_attention`` bounds its decode walk.  Causality within the
+    of the pool to a contiguous ``[cap]`` view, no per-layer slice of the
+    stacked pool and no per-offset recompile — the page walk is bounded
+    by the live prefix length the way ``paged_attention`` bounds its
+    decode walk.  Causality within the
     chunk is handled by raw-tile masking; the chunk's own K/V come from
     ``k_raw``/``v_raw`` (never pool-roundtripped, so int8 quantization
     noise only enters for *earlier* chunks' positions — identical
@@ -257,7 +261,7 @@ def flash_attention_chunk(
     """
     B, W, H, D = q.shape
     assert B == 1, "chunk executable serves one sequence per dispatch"
-    NB, BS, KV, _ = k_pool.shape
+    L, NB, KV, BS, _ = k_pool.shape
     G = H // KV
     MB = block_table.shape[1]
     quantized = k_scales is not None
@@ -270,13 +274,14 @@ def flash_attention_chunk(
     nr = (W + pr) // BS                              # raw chunk K tiles
     qg = jnp.pad(q, ((0, 0), (0, pq), (0, 0), (0, 0)))[0] \
         .reshape(W + pq, KV, G, D).transpose(1, 2, 0, 3)   # [KV, G, Wq, D]
-    # raw tiles keep the pool's page layout: [nr, BS, KV, D]
+    # raw tiles keep the pool's head-major page layout: [nr, KV, BS, D]
     kr = jnp.pad(k_raw, ((0, 0), (0, pr), (0, 0), (0, 0))) \
-        .reshape(nr, BS, KV, D)
+        .reshape(nr, BS, KV, D).swapaxes(1, 2)
     vr = jnp.pad(v_raw, ((0, 0), (0, pr), (0, 0), (0, 0))) \
-        .reshape(nr, BS, KV, D)
+        .reshape(nr, BS, KV, D).swapaxes(1, 2)
     info = jnp.stack([jnp.asarray(q_offset, jnp.int32),
-                      jnp.asarray(total_len, jnp.int32)])
+                      jnp.asarray(total_len, jnp.int32),
+                      jnp.asarray(layer, jnp.int32)])
 
     kernel = functools.partial(
         _fa_chunk_kernel, block_q=bq, block_size=BS, num_pool_blocks=MB,
@@ -287,36 +292,37 @@ def flash_attention_chunk(
         # pages past the live prefix re-resolve to its last live page
         # (Pallas skips the DMA when consecutive steps map to the same
         # block), so the walk is bounded by ceil(q_offset / BS).
-        return (bt[0, _chunk_clamp(ik, info[0], BS, MB)], 0, 0, 0)
+        return (info[2], bt[0, _chunk_clamp(ik, info[0], BS, MB)], 0, 0, 0)
 
     def scale_map(iq, ik, bt, info):
-        return (bt[0, _chunk_clamp(ik, info[0], BS, MB)], 0, 0)
+        return (info[2], bt[0, _chunk_clamp(ik, info[0], BS, MB)], 0, 0)
 
     def raw_map(iq, ik, bt, info):
         return (jnp.clip(ik - MB, 0, nr - 1), 0, 0, 0)
 
-    page = pl.BlockSpec((1, BS, KV, D), page_map)
+    page = pl.BlockSpec((1, 1, KV, BS, D), page_map)
     in_specs = [
         pl.BlockSpec((KV, G, 1, 1), lambda iq, ik, bt, info: (0, 0, 0, 0)),
         pl.BlockSpec((KV, G, bq, D), lambda iq, ik, bt, info: (0, 0, iq, 0)),
     ]
     if quantized:
         # one f32 per (page, head) in SMEM; see paged_decode_call
-        scale = pl.BlockSpec((1, 1, KV), scale_map, memory_space=pltpu.SMEM)
+        scale = pl.BlockSpec((1, 1, 1, KV), scale_map,
+                             memory_space=pltpu.SMEM)
         in_specs += [page, scale, page, scale]
-        args = [k_pool, k_scales.reshape(NB, 1, KV),
-                v_pool, v_scales.reshape(NB, 1, KV)]
+        args = [k_pool, k_scales.reshape(L, NB, 1, KV),
+                v_pool, v_scales.reshape(L, NB, 1, KV)]
     else:
         in_specs += [page, page]
         args = [k_pool, v_pool]
-    in_specs += [pl.BlockSpec((1, BS, KV, D), raw_map),
-                 pl.BlockSpec((1, BS, KV, D), raw_map)]
+    in_specs += [pl.BlockSpec((1, KV, BS, D), raw_map),
+                 pl.BlockSpec((1, KV, BS, D), raw_map)]
     args += [kr, vr]
 
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,                 # block_table, [off, len]
+            num_scalar_prefetch=2,         # block_table, [off, len, layer]
             grid=(nq, MB + nr),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((KV, G, bq, D),

@@ -63,13 +63,14 @@ def chunk_prefill_attention(q, k_pool, v_pool, k_scales, v_scales, layer,
     """Serving chunk-prefill attention with a *traced* ``q_offset``.
 
     One chunk of one sequence attends over the paged pool's live prefix
-    plus its own raw K/V — the Pallas path walks the pool pages directly
-    (scalar-prefetch block table, page walk clamped to the live prefix,
-    in-register int8 dequant when scales are given); the XLA path is the
-    bounded-gather + raw-overlay oracle in ``ref.py``.  Both cost
-    O(total_len) pool bytes per layer per chunk, never O(capacity).
+    plus its own raw K/V — the Pallas path walks the stacked pool's pages
+    in place (scalar-prefetch block table and layer, page walk clamped to
+    the live prefix, in-register int8 dequant when scales are given); the
+    XLA path is the bounded-gather + raw-overlay oracle in ``ref.py``.
+    Both cost O(total_len) pool bytes per layer per chunk, never
+    O(capacity).
 
-    q: [1, W, H, D]; k_pool/v_pool: [L, NB, BS, KV, D]; k_scales/
+    q: [1, W, H, D]; k_pool/v_pool: [L, NB, KV, BS, D]; k_scales/
     v_scales: [L, NB, KV] f32 or None (bf16 pools); layer: traced layer
     index; block_table: [1, MB]; q_offset/total_len: traced i32 scalars;
     k_raw/v_raw: [1, W, KV, D] (the chunk's own full-precision K/V).
@@ -77,15 +78,10 @@ def chunk_prefill_attention(q, k_pool, v_pool, k_scales, v_scales, layer,
     if use_pallas is None:
         use_pallas = _on_tpu()
     if use_pallas:
-        quant = k_scales is not None
-        with jax.named_scope("pool_slice"):
-            kl, vl = k_pool[layer], v_pool[layer]
-            ks = k_scales[layer] if quant else None
-            vs = v_scales[layer] if quant else None
         return _flash_chunk_pallas(
-            q, kl, vl, block_table, q_offset, total_len, k_raw, v_raw,
-            alibi_slopes, k_scales=ks, v_scales=vs,
-            sliding_window=sliding_window,
+            q, k_pool, v_pool, layer, block_table, q_offset, total_len,
+            k_raw, v_raw, alibi_slopes, k_scales=k_scales,
+            v_scales=v_scales, sliding_window=sliding_window,
             interpret=_interpret(interpret))
     return _ref.chunk_prefill_attention_ref(
         q, k_pool, v_pool, k_scales, v_scales, layer, block_table,
@@ -93,38 +89,42 @@ def chunk_prefill_attention(q, k_pool, v_pool, k_scales, v_scales, layer,
         sliding_window=sliding_window)
 
 
-def paged_attention(q, k_pool, v_pool, block_table, seq_lens,
+def paged_attention(q, k_pool, v_pool, layer, block_table, seq_lens,
                     alibi_slopes=None, *, sliding_window=0,
                     use_pallas: Optional[bool] = None,
                     interpret: Optional[bool] = None):
+    """Decode attention over layer ``layer`` of the stacked
+    ``[L, NB, KV, BS, D]`` pool, read in place."""
     if use_pallas is None:
         use_pallas = _on_tpu()
     if use_pallas:
-        return _paged_pallas(q, k_pool, v_pool, block_table, seq_lens,
-                             alibi_slopes, sliding_window=sliding_window,
+        return _paged_pallas(q, k_pool, v_pool, layer, block_table,
+                             seq_lens, alibi_slopes,
+                             sliding_window=sliding_window,
                              interpret=_interpret(interpret))
-    return _ref.paged_attention_ref(q, k_pool, v_pool, block_table, seq_lens,
-                                    alibi_slopes=alibi_slopes,
+    return _ref.paged_attention_ref(q, k_pool, v_pool, layer, block_table,
+                                    seq_lens, alibi_slopes=alibi_slopes,
                                     sliding_window=sliding_window)
 
 
-def paged_attention_quant(q, k_values, k_scales, v_values, v_scales,
+def paged_attention_quant(q, k_values, k_scales, v_values, v_scales, layer,
                           block_table, seq_lens, alibi_slopes=None, *,
                           sliding_window=0,
                           use_pallas: Optional[bool] = None,
                           interpret: Optional[bool] = None):
-    """Decode attention over the int8 KV pool (per-block-per-head scales),
-    dequantizing inside the kernel instead of materializing bf16 pages."""
+    """Decode attention over layer ``layer`` of the stacked int8 KV pool
+    (per-block-per-head scales), read in place and dequantized inside the
+    kernel instead of materializing bf16 pages."""
     if use_pallas is None:
         use_pallas = _on_tpu()
     if use_pallas:
         return _paged_quant_pallas(
-            q, k_values, k_scales, v_values, v_scales, block_table, seq_lens,
-            alibi_slopes, sliding_window=sliding_window,
+            q, k_values, k_scales, v_values, v_scales, layer, block_table,
+            seq_lens, alibi_slopes, sliding_window=sliding_window,
             interpret=_interpret(interpret))
     return _ref.paged_attention_quant_ref(
-        q, k_values, k_scales, v_values, v_scales, block_table, seq_lens,
-        alibi_slopes=alibi_slopes, sliding_window=sliding_window)
+        q, k_values, k_scales, v_values, v_scales, layer, block_table,
+        seq_lens, alibi_slopes=alibi_slopes, sliding_window=sliding_window)
 
 
 def quant_matmul(x: jnp.ndarray, params: Dict[str, jnp.ndarray], *,

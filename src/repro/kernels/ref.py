@@ -26,37 +26,37 @@ def flash_attention_ref(q, k, v, *, causal=True, sliding_window=0,
                              alibi_slopes=alibi_slopes, q_offset=q_offset)
 
 
-def paged_attention_ref(q, k_pool, v_pool, block_table, seq_lens, *,
+def paged_attention_ref(q, k_pool, v_pool, layer, block_table, seq_lens, *,
                         alibi_slopes=None, sliding_window=0):
-    """Decode attention over the paged pool.
+    """Decode attention over layer ``layer`` of the paged pool.
 
-    q: [B, H, D]; k_pool/v_pool: [NB, BS, KV, D] (single layer's pool);
-    block_table: [B, MB]; seq_lens: [B].
+    q: [B, H, D]; k_pool/v_pool: [L, NB, KV, BS, D] (the stacked pool);
+    layer: scalar; block_table: [B, MB]; seq_lens: [B].
     """
-    bs = k_pool.shape[1]
+    bs = k_pool.shape[3]
     max_len = block_table.shape[1] * bs
-    kc = gather_kv(k_pool[None], 0, block_table, max_len)
-    vc = gather_kv(v_pool[None], 0, block_table, max_len)
+    kc = gather_kv(k_pool, layer, block_table, max_len)
+    vc = gather_kv(v_pool, layer, block_table, max_len)
     return decode_attention(q, kc, vc, seq_lens, alibi_slopes=alibi_slopes,
                             sliding_window=sliding_window)
 
 
 def paged_attention_quant_ref(q, k_values, k_scales, v_values, v_scales,
-                              block_table, seq_lens, *,
+                              layer, block_table, seq_lens, *,
                               alibi_slopes=None, sliding_window=0):
-    """Decode attention over the int8 paged pool: dequantize the gathered
-    pages (per-block-per-head scales), then the same contiguous oracle.
+    """Decode attention over layer ``layer`` of the int8 paged pool:
+    dequantize the gathered pages (per-block-per-head scales), then the
+    same contiguous oracle.
 
-    q: [B, H, D]; k_values/v_values: [NB, BS, KV, D] int8 (single layer);
-    k_scales/v_scales: [NB, KV] f32; block_table: [B, MB]; seq_lens: [B].
+    q: [B, H, D]; k_values/v_values: [L, NB, KV, BS, D] int8 (stacked);
+    k_scales/v_scales: [L, NB, KV] f32; layer: scalar; block_table:
+    [B, MB]; seq_lens: [B].
     """
     from repro.core.kv_quant import gather_kv_quant
-    bs = k_values.shape[1]
+    bs = k_values.shape[3]
     max_len = block_table.shape[1] * bs
-    kc = gather_kv_quant(k_values[None], k_scales[None], 0, block_table,
-                         max_len)
-    vc = gather_kv_quant(v_values[None], v_scales[None], 0, block_table,
-                         max_len)
+    kc = gather_kv_quant(k_values, k_scales, layer, block_table, max_len)
+    vc = gather_kv_quant(v_values, v_scales, layer, block_table, max_len)
     return decode_attention(q, kc, vc, seq_lens, alibi_slopes=alibi_slopes,
                             sliding_window=sliding_window)
 
@@ -76,7 +76,7 @@ def chunk_prefill_attention_ref(q, k_pool, v_pool, k_scales, v_scales,
     causal mask.  This is also the lowering the serving engine runs off
     TPU and the multi-pod dry-run compiles.
 
-    q: [1, W, H, D]; k_pool/v_pool: [L, NB, BS, KV, D] (int8 when scales
+    q: [1, W, H, D]; k_pool/v_pool: [L, NB, KV, BS, D] (int8 when scales
     are given, with k_scales/v_scales [L, NB, KV] f32); layer: traced
     index; block_table: [1, MB]; q_offset/total_len: traced i32 scalars;
     k_raw/v_raw: [1, W, KV, D].

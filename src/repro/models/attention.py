@@ -99,7 +99,7 @@ def attn_prefill(cfg: ModelConfig, p: Params, x: jnp.ndarray,
                  rt: Optional[dict] = None):
     """Prefill: attention over the prompt AND write K/V into the paged pool.
 
-    Returns (y, cache). cache pools: [L, NB, BS, KV, D] (quantize-on-write
+    Returns (y, cache). cache pools: [L, NB, KV, BS, D] (quantize-on-write
     when the cache carries int8 values + scales).
     """
     rt = rt or {}
@@ -124,7 +124,7 @@ def attn_decode(cfg: ModelConfig, p: Params, x: jnp.ndarray,
                 ctx: Optional[ParallelCtx], *, kind: str,
                 cache: KVCache, layer: int, block_table, seq_lens,
                 rt: Optional[dict] = None):
-    """One-token decode. x: [B, d]; cache pools [L, NB, BS, KV, D] (ring
+    """One-token decode. x: [B, d]; cache pools [L, NB, KV, BS, D] (ring
     for SWA; int8 values + [L, NB, KV] scales when quantized).
 
     Returns (y [B, d], cache).
@@ -151,8 +151,8 @@ def attn_decode(cfg: ModelConfig, p: Params, x: jnp.ndarray,
 
     if ctx is not None and B % ctx.dp_size == 0 and ctx.dp_size > 1:
         dp = ctx.dp_axes
-        # every cache leaf — value pool [L,NB,...] or scale pool [L,NB,KV]
-        # — shards over dp on the blocks dim.
+        # every cache leaf — value pool [L,NB,KV,BS,D] or scale pool
+        # [L,NB,KV] — shards over dp on the blocks dim.
         leaf_specs = tuple(P(None, dp) for _ in cache_leaves)
         o, *leaves = shard_map(
             island, mesh=ctx.mesh,
@@ -179,7 +179,7 @@ def _decode_cache_attend(cfg, q, k, v, cache: KVCache, block_table,
         # is rejected for sliding archs at decode-state construction.
         from repro.core.paged_cache import gather_kv, write_decode_kv
         k_pool, v_pool = cache.k, cache.v
-        cache_len = block_table.shape[1] * k_pool.shape[2]
+        cache_len = block_table.shape[1] * cache.block_size
         # inactive slots (seq_len == 0) get position -1 -> write dropped
         ring_pos = jnp.where(seq_lens > 0, (seq_lens - 1) % cache_len, -1)
         k_pool = write_decode_kv(k_pool, layer, k, block_table, ring_pos)
@@ -204,18 +204,14 @@ def _decode_cache_attend(cfg, q, k, v, cache: KVCache, block_table,
         if rt.get("skip_mixer_core"):
             o = q * (1 + 1e-30 * seq_lens.sum())
         elif cache.quantized:
-            with jax.named_scope("pool_slice"):
-                kl, ks = cache.k[layer], cache.k_scale[layer]
-                vl, vs = cache.v[layer], cache.v_scale[layer]
             o = ops.paged_attention_quant(
-                q, kl, ks, vl, vs, block_table, seq_lens, _slopes(cfg),
+                q, cache.k, cache.k_scale, cache.v, cache.v_scale, layer,
+                block_table, seq_lens, _slopes(cfg),
                 use_pallas=rt.get("use_pallas"),
                 interpret=rt.get("interpret"))
         else:
-            with jax.named_scope("pool_slice"):
-                kl, vl = cache.k[layer], cache.v[layer]
-            o = ops.paged_attention(q, kl, vl, block_table, seq_lens,
-                                    _slopes(cfg),
+            o = ops.paged_attention(q, cache.k, cache.v, layer, block_table,
+                                    seq_lens, _slopes(cfg),
                                     use_pallas=rt.get("use_pallas"),
                                     interpret=rt.get("interpret"))
     return o, cache

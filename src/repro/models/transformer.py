@@ -411,7 +411,7 @@ def decode_megastep(cfg: ModelConfig, params: Params,
 
     Returns (out_tokens [max_horizon, B] i32 — rows >= n_steps are zero,
     new state). Jit with ``donate_argnums`` on ``state`` so the
-    [L, NB, BS, KV, D] pools update in place instead of being copied
+    [L, NB, KV, BS, D] pools update in place instead of being copied
     every token.
     """
     rt = rt or {}
@@ -872,7 +872,7 @@ def attn_prefill_ring(cfg, p, x, ctx, *, kind, cache, layer,
                              sliding_window=cfg.sliding_window,
                              use_pallas=rt.get("use_pallas"),
                              interpret=rt.get("interpret"))
-    cache_len = block_table.shape[1] * cache.k.shape[2]
+    cache_len = block_table.shape[1] * cache.block_size
     # keep only the last cache_len tokens per sequence: token at position p
     # lands at ring slot p % cache_len; older tokens in the same slot must
     # be dropped, so mask tokens with p < ctx_len - cache_len.
@@ -890,15 +890,11 @@ def attn_prefill_ring(cfg, p, x, ctx, *, kind, cache, layer,
 
 
 def _write_ring(pool, layer, k, block_table, positions, keep, cache_len):
-    B, S = k.shape[:2]
-    bs = pool.shape[2]
+    """Scatter the kept tokens' K (or V) [B, S, KV, D] into their ring
+    slots of the stacked [L, NB, KV, BS, D] pool; the rest are dropped."""
+    bs = pool.shape[3]
     slot = positions % cache_len                              # [S]
     blk = block_table[:, slot // bs]                          # [B, S]
-    off = slot % bs
-    NB, BS = pool.shape[1], pool.shape[2]
-    flat_idx = (blk * bs + off[None, :]).reshape(-1)
-    flat_idx = jnp.where(keep.reshape(-1), flat_idx, NB * BS)
-    lp = pool[layer].reshape(NB * BS, *pool.shape[3:])
-    lp = lp.at[flat_idx].set(k.reshape(B * S, *k.shape[2:]).astype(pool.dtype),
-                             mode="drop")
-    return pool.at[layer].set(lp.reshape(NB, BS, *pool.shape[3:]))
+    blk = jnp.where(keep, blk, pool.shape[1])                 # OOB -> dropped
+    off = (slot % bs)[None, :]
+    return pool.at[layer, blk, :, off].set(k.astype(pool.dtype), mode="drop")

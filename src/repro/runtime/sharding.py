@@ -231,8 +231,8 @@ def state_shardings(ctx: Optional[ParallelCtx], state: Any, cfg) -> Any:
     out = {}
     for k, v in state.items():
         s = v.shape
-        if k in ("k_pool", "v_pool"):            # [L, NB, BS, KV, D]
-            spec = P(None, dp_if(s[1]), None, tp_if(s[3]), None)
+        if k in ("k_pool", "v_pool"):            # [L, NB, KV, BS, D]
+            spec = P(None, dp_if(s[1]), tp_if(s[2]), None, None)
         elif k in ("k_scales", "v_scales"):      # [L, NB, KV] (int8 KV mode)
             spec = P(None, dp_if(s[1]), tp_if(s[2]))
         elif k == "block_table":                 # [B, MB]

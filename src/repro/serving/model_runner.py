@@ -77,7 +77,7 @@ class ModelRunner:
         # the serving chunk executable: [1, chunk_tokens] + scalar offsets
         # regardless of prompt length or batch composition, so it compiles
         # exactly once. Pools are donated: the chunk scatter updates the
-        # [L, NB, BS, KV, D] arrays (+ int8 scales) in place.
+        # [L, NB, KV, BS, D] arrays (+ int8 scales) in place.
         self._prefill_chunk = None
         if chunk_tokens:
             self._prefill_chunk = jax.jit(

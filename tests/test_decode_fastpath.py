@@ -118,9 +118,9 @@ def test_fork_append_triggers_cow_with_device_copy():
     kp = copy_blocks(kp, jnp.asarray([src], jnp.int32),
                      jnp.asarray([dst], jnp.int32))
     # every layer's tail contents survived the copy; original untouched
-    np.testing.assert_allclose(np.asarray(kp[:, dst, :2, 0]),
-                               np.asarray(kp[:, src, :2, 0]))
-    np.testing.assert_allclose(np.asarray(kp[0, src, :2, 0]),
+    np.testing.assert_allclose(np.asarray(kp[:, dst, 0, :2]),
+                               np.asarray(kp[:, src, 0, :2]))
+    np.testing.assert_allclose(np.asarray(kp[0, src, 0, :2]),
                                np.asarray(k[0, 4:6, 0], np.float32))
 
 

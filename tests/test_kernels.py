@@ -45,14 +45,14 @@ def test_paged_attention_sweep(B, H, KV, D, BS, MB, dtype):
     NB = B * MB + 2
     ks = jax.random.split(jax.random.PRNGKey(1), 4)
     q = jax.random.normal(ks[0], (B, H, D), dtype)
-    kp = jax.random.normal(ks[1], (NB, BS, KV, D), dtype)
-    vp = jax.random.normal(ks[2], (NB, BS, KV, D), dtype)
+    kp = jax.random.normal(ks[1], (1, NB, KV, BS, D), dtype)
+    vp = jax.random.normal(ks[2], (1, NB, KV, BS, D), dtype)
     bt = jax.random.permutation(ks[3], NB)[:B * MB].reshape(B, MB)
     bt = bt.astype(jnp.int32)
     sl = jnp.asarray(np.random.default_rng(0).integers(1, MB * BS + 1, B),
                      jnp.int32)
-    o = paged_attention(q, kp, vp, bt, sl, interpret=True)
-    r = ref.paged_attention_ref(q, kp, vp, bt, sl)
+    o = paged_attention(q, kp, vp, 0, bt, sl, interpret=True)
+    r = ref.paged_attention_ref(q, kp, vp, 0, bt, sl)
     np.testing.assert_allclose(np.asarray(o, np.float32),
                                np.asarray(r, np.float32), atol=TOL[dtype])
 
@@ -62,14 +62,14 @@ def test_paged_attention_alibi_and_window():
     NB = B * MB
     ks = jax.random.split(jax.random.PRNGKey(2), 4)
     q = jax.random.normal(ks[0], (B, H, D))
-    kp = jax.random.normal(ks[1], (NB, BS, KV, D))
-    vp = jax.random.normal(ks[2], (NB, BS, KV, D))
+    kp = jax.random.normal(ks[1], (1, NB, KV, BS, D))
+    vp = jax.random.normal(ks[2], (1, NB, KV, BS, D))
     bt = jnp.arange(NB, dtype=jnp.int32).reshape(B, MB)
     sl = jnp.array([37, 12], jnp.int32)
     slo = alibi_slopes(H)
-    o = paged_attention(q, kp, vp, bt, sl, slo, sliding_window=16,
+    o = paged_attention(q, kp, vp, 0, bt, sl, slo, sliding_window=16,
                         interpret=True)
-    r = ref.paged_attention_ref(q, kp, vp, bt, sl, alibi_slopes=slo,
+    r = ref.paged_attention_ref(q, kp, vp, 0, bt, sl, alibi_slopes=slo,
                                 sliding_window=16)
     np.testing.assert_allclose(o, r, atol=5e-5)
 
@@ -93,21 +93,20 @@ def test_flash_attention_chunk_dynamic_offset(q_off, alibi, win, quant):
     vr = jnp.asarray(rng.normal(size=(1, W, KV, D)), jnp.float32)
     bt = jnp.asarray(rng.permutation(NB)[:MB][None], jnp.int32)
     if quant:
-        kp = jnp.asarray(rng.integers(-127, 128, (L, NB, BS, KV, D)),
+        kp = jnp.asarray(rng.integers(-127, 128, (L, NB, KV, BS, D)),
                          jnp.int8)
-        vp = jnp.asarray(rng.integers(-127, 128, (L, NB, BS, KV, D)),
+        vp = jnp.asarray(rng.integers(-127, 128, (L, NB, KV, BS, D)),
                          jnp.int8)
         ks = jnp.asarray(rng.uniform(0.01, 0.1, (L, NB, KV)), jnp.float32)
         vs = jnp.asarray(rng.uniform(0.01, 0.1, (L, NB, KV)), jnp.float32)
     else:
-        kp = jnp.asarray(rng.normal(size=(L, NB, BS, KV, D)), jnp.float32)
-        vp = jnp.asarray(rng.normal(size=(L, NB, BS, KV, D)), jnp.float32)
+        kp = jnp.asarray(rng.normal(size=(L, NB, KV, BS, D)), jnp.float32)
+        vp = jnp.asarray(rng.normal(size=(L, NB, KV, BS, D)), jnp.float32)
         ks = vs = None
     sl = alibi_slopes(H) if alibi else None
     o = flash_attention_chunk(
-        q, kp[0], vp[0], bt, jnp.int32(q_off), jnp.int32(total), kr, vr,
-        sl, k_scales=None if ks is None else ks[0],
-        v_scales=None if vs is None else vs[0], sliding_window=win,
+        q, kp, vp, jnp.int32(0), bt, jnp.int32(q_off), jnp.int32(total),
+        kr, vr, sl, k_scales=ks, v_scales=vs, sliding_window=win,
         block_q=8, interpret=True)
     r = ref.chunk_prefill_attention_ref(
         q, kp, vp, ks, vs, 0, bt, jnp.int32(q_off), jnp.int32(total),
@@ -119,21 +118,95 @@ def test_flash_attention_chunk_dynamic_offset(q_off, alibi, win, quant):
 
 
 def test_flash_attention_chunk_one_compile_across_offsets():
-    """q_offset / total_len are traced operands: every chunk shape of a
-    serving run hits one executable (the whole point of the variant)."""
+    """q_offset / total_len / layer are traced operands: every chunk
+    shape of a serving run, at every layer, hits one executable (the
+    whole point of the variant)."""
     from repro.kernels.flash_attention import flash_attention_chunk
     rng = np.random.default_rng(7)
     NB, BS, KV, D, H, MB, W = 8, 8, 2, 16, 4, 4, 8
     q = jnp.asarray(rng.normal(size=(1, W, H, D)), jnp.float32)
     kr = jnp.asarray(rng.normal(size=(1, W, KV, D)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(NB, BS, KV, D)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(2, NB, KV, BS, D)), jnp.float32)
     bt = jnp.arange(MB, dtype=jnp.int32)[None]
     before = flash_attention_chunk._cache_size()
-    for off in (0, 3, 8, 17):
-        flash_attention_chunk(q, kp, kp, bt, jnp.int32(off),
-                              jnp.int32(off + 5), kr, kr, None,
-                              block_q=8, interpret=True)
+    for off, layer in ((0, 0), (3, 1), (8, 0), (17, 1)):
+        flash_attention_chunk(q, kp, kp, jnp.int32(layer), bt,
+                              jnp.int32(off), jnp.int32(off + 5), kr, kr,
+                              None, block_q=8, interpret=True)
     assert flash_attention_chunk._cache_size() - before == 1
+
+
+@pytest.mark.parametrize("kind", ["decode", "chunk"])
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_kernels_read_stacked_pool_at_layer(kind, quant):
+    """The decode and chunk kernels read layer ``layer != 0`` of the
+    stacked ``[L, NB, KV, BS, D]`` pool in place: they match the
+    reference at that layer, and the reference over that layer's slice
+    alone, so no other layer's pages leak in."""
+    from repro.kernels.flash_attention import flash_attention_chunk
+    from repro.kernels.paged_attention_quant import paged_attention_quant
+    rng = np.random.default_rng(11)
+    L, NB, BS, KV, D, H, MB, layer = 3, 16, 8, 2, 16, 4, 5, 2
+    # int8 pages meet f32 queries (as in the int8 tests above); the dense
+    # pool is bf16 throughout
+    dtype = jnp.float32 if quant else jnp.bfloat16
+    if quant:
+        kp = jnp.asarray(rng.integers(-127, 128, (L, NB, KV, BS, D)),
+                         jnp.int8)
+        vp = jnp.asarray(rng.integers(-127, 128, (L, NB, KV, BS, D)),
+                         jnp.int8)
+        ks = jnp.asarray(rng.uniform(0.01, 0.1, (L, NB, KV)), jnp.float32)
+        vs = jnp.asarray(rng.uniform(0.01, 0.1, (L, NB, KV)), jnp.float32)
+        one = (kp[layer][None], vp[layer][None], ks[layer][None],
+               vs[layer][None])
+    else:
+        kp = jnp.asarray(rng.normal(size=(L, NB, KV, BS, D)), dtype)
+        vp = jnp.asarray(rng.normal(size=(L, NB, KV, BS, D)), dtype)
+        ks = vs = None
+        one = (kp[layer][None], vp[layer][None], None, None)
+    slo = alibi_slopes(H)
+    if kind == "decode":
+        B = 3
+        q = jnp.asarray(rng.normal(size=(B, H, D)), dtype)
+        bt = jnp.asarray(rng.permutation(NB)[:B * MB].reshape(B, MB),
+                         jnp.int32)
+        sl = jnp.asarray([17, 1, MB * BS], jnp.int32)
+        if quant:
+            o = paged_attention_quant(q, kp, ks, vp, vs, jnp.int32(layer),
+                                      bt, sl, slo, interpret=True)
+            rs = [ref.paged_attention_quant_ref(
+                q, k, kscale, v, vscale, li, bt, sl, alibi_slopes=slo)
+                for (k, v, kscale, vscale), li in (((kp, vp, ks, vs), layer),
+                                                   (one, 0))]
+        else:
+            o = paged_attention(q, kp, vp, jnp.int32(layer), bt, sl, slo,
+                                interpret=True)
+            rs = [ref.paged_attention_ref(q, k, v, li, bt, sl,
+                                          alibi_slopes=slo)
+                  for (k, v, _, _), li in (((kp, vp, None, None), layer),
+                                           (one, 0))]
+        live = slice(None)
+    else:
+        W, q_off = 16, 13
+        total = q_off + 11
+        q = jnp.asarray(rng.normal(size=(1, W, H, D)), dtype)
+        kr = jnp.asarray(rng.normal(size=(1, W, KV, D)), dtype)
+        vr = jnp.asarray(rng.normal(size=(1, W, KV, D)), dtype)
+        bt = jnp.asarray(rng.permutation(NB)[:MB][None], jnp.int32)
+        o = flash_attention_chunk(
+            q, kp, vp, jnp.int32(layer), bt, jnp.int32(q_off),
+            jnp.int32(total), kr, vr, slo, k_scales=ks, v_scales=vs,
+            block_q=8, interpret=True)
+        rs = [ref.chunk_prefill_attention_ref(
+            q, k, v, kscale, vscale, li, bt, jnp.int32(q_off),
+            jnp.int32(total), kr, vr, alibi_slopes=slo)
+            for (k, v, kscale, vscale), li in (((kp, vp, ks, vs), layer),
+                                               (one, 0))]
+        live = (slice(None), slice(0, total - q_off))
+    for r in rs:
+        np.testing.assert_allclose(np.asarray(o[live], np.float32),
+                                   np.asarray(r[live], np.float32),
+                                   atol=TOL[dtype])
 
 
 @pytest.mark.parametrize("M,K,N,gs", [(16, 64, 32, 32), (8, 128, 48, 128),

@@ -29,16 +29,16 @@ def test_roundtrip_error_bounded_by_half_scale():
         BS, KV, D = (int(rng.integers(1, 17)), int(rng.integers(1, 5)),
                      int(rng.integers(1, 33)))
         mag = 10.0 ** rng.uniform(-3, 3)
-        x = jnp.asarray(rng.normal(size=(4, BS, KV, D)) * mag, jnp.float32)
+        x = jnp.asarray(rng.normal(size=(4, KV, BS, D)) * mag, jnp.float32)
         live = jnp.asarray(rng.random((4, BS)) < 0.8)
         q, scales = quantize_blocks(x, live)
         deq = dequantize_blocks(q, scales)
-        err = jnp.abs(jnp.where(live[..., None, None], x, 0.0) - deq)
+        err = jnp.abs(jnp.where(live[:, None, :, None], x, 0.0) - deq)
         # worst live element per (block, head) vs that head's scale bound
-        bound = (scales / 2 * (1 + 1e-5))[:, None, :, None]
+        bound = (scales / 2 * (1 + 1e-5))[:, :, None, None]
         assert bool(jnp.all(err <= bound)), f"trial {trial}"
         # dead slots quantize to exactly 0
-        assert bool(jnp.all(jnp.where(live[..., None, None], 0, deq) == 0))
+        assert bool(jnp.all(jnp.where(live[:, None, :, None], 0, deq) == 0))
 
 
 def test_roundtrip_exact_on_int8_grid():
@@ -47,6 +47,7 @@ def test_roundtrip_exact_on_int8_grid():
     amax = 3.7
     n = rng.integers(-127, 128, size=(2, 8, 2, 16))
     n.flat[0] = 127                          # pin the amax so scale is known
+    n = n.transpose(0, 2, 1, 3)              # head-major pages [2, KV, BS, D]
     x = jnp.asarray(n * (amax / 127.0), jnp.float32)
     live = jnp.ones((2, 8), bool)
     q, scales = quantize_blocks(x, live)
@@ -153,6 +154,122 @@ def test_cow_fork_carries_scales():
     assert float(np.abs(np.asarray(ks[:, dst])).max()) > 0
 
 
+# ------------------------------------------------- head-major layout
+
+def _old_quantize(x, live):
+    """The token-major ``[..., BS, KV, D]`` quantizer the pool had before
+    its pages became head-major: the reference for the layout tests."""
+    xf = jnp.where(live[..., None, None], x.astype(jnp.float32), 0.0)
+    amax = jnp.max(jnp.abs(xf), axis=(-3, -1))
+    scales = jnp.maximum(amax, 1e-20) / 127.0
+    q = jnp.clip(jnp.round(xf / scales[..., None, :, None]), -127.0, 127.0)
+    return q.astype(jnp.int8), scales
+
+
+def _old_dequantize(q, scales):
+    return q.astype(jnp.float32) * scales[..., None, :, None]
+
+
+def _old_write_decode(values, scales, layer, k_new, bt, positions):
+    """Decode write into a token-major ``[L, NB, BS, KV, D]`` pool,
+    through the layer slice as before."""
+    NB, bs = values.shape[1], values.shape[2]
+    valid = positions >= 0
+    pos = jnp.maximum(positions, 0)
+    blk = jnp.take_along_axis(bt, (pos // bs)[:, None], axis=1)[:, 0]
+    off = pos % bs
+    lp, ls = values[layer], scales[layer]
+    old = _old_dequantize(lp[blk], ls[blk])
+    slot = jnp.arange(bs)[None, :]
+    buf = jnp.where((slot < off[:, None])[..., None, None], old, 0.0)
+    buf = jnp.where((slot == off[:, None])[..., None, None],
+                    k_new[:, None].astype(jnp.float32), buf)
+    q, sc = _old_quantize(buf, slot <= off[:, None])
+    tgt = jnp.where(valid, blk, NB)
+    lp = lp.at[tgt].set(q, mode="drop")
+    ls = ls.at[tgt].set(sc, mode="drop")
+    return values.at[layer].set(lp), scales.at[layer].set(ls)
+
+
+def _old_write_prefill(values, scales, layer, k, bt, ctx_lens, pos_offset):
+    """Prefill (chunk) write into a token-major pool, as before."""
+    B, S, KV, D = k.shape
+    NB, bs = values.shape[1], values.shape[2]
+    nb = -(-S // bs) + 1
+    j0 = pos_offset // bs
+    lead = pos_offset - j0 * bs
+    buf = jnp.zeros((B, nb * bs, KV, D), jnp.float32)
+    buf = jax.lax.dynamic_update_slice(buf, k.astype(jnp.float32),
+                                       (0, lead, 0, 0))
+    buf = buf.reshape(B, nb, bs, KV, D)
+    pos = (j0 * bs + jnp.arange(nb * bs)).reshape(nb, bs)
+    live = (pos[None] >= pos_offset) & (pos[None] < ctx_lens[:, None, None])
+    lp, ls = values[layer], scales[layer]
+    btp = jnp.concatenate([bt, jnp.full((B, nb), NB, bt.dtype)], axis=1)
+    blk = jax.lax.dynamic_slice_in_dim(btp, j0, nb, axis=1)
+    safe0 = jnp.minimum(blk[:, 0], NB - 1)
+    old = _old_dequantize(lp[safe0], ls[safe0])
+    old_live = ((jnp.arange(bs)[None] < lead)
+                & (pos[0][None] < ctx_lens[:, None]))
+    buf = buf.at[:, 0].add(jnp.where(old_live[..., None, None], old, 0.0))
+    live = live.at[:, 0].set(live[:, 0] | old_live)
+    q, sc = _old_quantize(buf, live)
+    tgt = jnp.where(live.any(-1), blk, NB)
+    lp = lp.at[tgt].set(q, mode="drop")
+    ls = ls.at[tgt].set(sc, mode="drop")
+    return values.at[layer].set(lp), scales.at[layer].set(ls)
+
+
+@pytest.mark.parametrize("case,layer,off", [
+    ("decode", 0, 0), ("decode", -1, 0), ("decode", 0, -1),
+    ("decode", -1, -1), ("decode-inactive", -1, -1),
+    ("prefill", 0, 0), ("prefill", -1, -1), ("prefill-chunk", -1, -1),
+    ("cow", -1, -1)])
+def test_head_major_writes_equal_token_major_transposed(case, layer, off):
+    """The head-major ``[L, NB, KV, BS, D]`` writes give int8 values and
+    f32 scales bitwise equal to the token-major ``[L, NB, BS, KV, D]``
+    pool's, transposed: layers 0 and L-1, in-block offsets 0 and BS-1,
+    an inactive slot (position -1: write dropped), a chunk boundary that
+    merges a live prefix, and a CoW block copy."""
+    L, NB, BS, KV, D, MB = 3, 16, 8, 2, 16, 4
+    layer, off = layer % L, off % BS
+    rng = np.random.default_rng(5)
+    # a pool with live contents in every block, token-major
+    old_v, old_s = _old_quantize(
+        jnp.asarray(rng.normal(size=(L, NB, BS, KV, D)), jnp.float32),
+        jnp.ones((L, NB, BS), bool))
+
+    def head_major(v):
+        return jnp.swapaxes(v, 2, 3)
+    new_v = head_major(old_v)
+    bt = jnp.asarray(rng.permutation(NB)[:3 * MB].reshape(3, MB), jnp.int32)
+    if case.startswith("decode"):
+        k = jnp.asarray(rng.normal(size=(3, KV, D)) * 3, jnp.float32)
+        pos = jnp.asarray([2 * BS + off, off, BS + off], jnp.int32)
+        if case == "decode-inactive":
+            pos = pos.at[1].set(-1)
+        ov, os_ = _old_write_decode(old_v, old_s, layer, k, bt, pos)
+        nv, ns = write_decode_kv_quant(new_v, old_s, jnp.int32(layer), k,
+                                       bt, pos)
+    elif case.startswith("prefill"):
+        S = 2 * BS
+        start = off if case == "prefill-chunk" else 0
+        k = jnp.asarray(rng.normal(size=(3, S, KV, D)), jnp.float32)
+        ctx = jnp.asarray([start + S, start + 5, start + BS], jnp.int32)
+        ov, os_ = _old_write_prefill(old_v, old_s, layer, k, bt, ctx, start)
+        nv, ns = write_prefill_kv_quant(new_v, old_s, jnp.int32(layer), k,
+                                        bt, ctx, pos_offset=jnp.int32(start))
+    else:
+        src = jnp.asarray([int(bt[0, 1]), int(bt[1, 0])], jnp.int32)
+        dst = jnp.asarray([int(bt[2, 3]), int(bt[2, 2])], jnp.int32)
+        ov, os_ = copy_blocks_quant(jnp.copy(old_v), jnp.copy(old_s), src,
+                                    dst)
+        nv, ns = copy_blocks_quant(new_v, jnp.copy(old_s), src, dst)
+    assert not np.array_equal(np.asarray(ov), np.asarray(old_v))
+    np.testing.assert_array_equal(np.asarray(nv), np.asarray(head_major(ov)))
+    np.testing.assert_array_equal(np.asarray(ns), np.asarray(os_))
+
+
 # ------------------------------------------------------------ kernel
 
 @pytest.mark.parametrize("use_alibi", [False, True])
@@ -164,18 +281,18 @@ def test_paged_attention_quant_kernel_matches_ref(use_alibi):
     from repro.kernels.ref import paged_attention_quant_ref
     B, H, KV, D, NB, BS, MB = 3, 8, 2, 16, 16, 8, 4
     q = jax.random.normal(jax.random.fold_in(KEY, 5), (B, H, D), jnp.float32)
-    kraw = jax.random.normal(jax.random.fold_in(KEY, 6), (NB, BS, KV, D))
-    vraw = jax.random.normal(jax.random.fold_in(KEY, 7), (NB, BS, KV, D))
-    full = jnp.ones((NB, BS), bool)
+    kraw = jax.random.normal(jax.random.fold_in(KEY, 6), (1, NB, KV, BS, D))
+    vraw = jax.random.normal(jax.random.fold_in(KEY, 7), (1, NB, KV, BS, D))
+    full = jnp.ones((1, NB, BS), bool)
     kq, ks = quantize_blocks(kraw, full)
     vq, vs = quantize_blocks(vraw, full)
     bt = jnp.asarray(np.random.default_rng(0).permutation(NB)[:B * MB]
                      .reshape(B, MB), jnp.int32)
     sl = jnp.asarray([17, 8, 30], jnp.int32)
     slopes = alibi_slopes(H) if use_alibi else None
-    out = paged_attention_quant(q, kq, ks, vq, vs, bt, sl, slopes,
+    out = paged_attention_quant(q, kq, ks, vq, vs, 0, bt, sl, slopes,
                                 interpret=True)
-    ref = paged_attention_quant_ref(q, kq, ks, vq, vs, bt, sl,
+    ref = paged_attention_quant_ref(q, kq, ks, vq, vs, 0, bt, sl,
                                     alibi_slopes=slopes)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 

@@ -5,8 +5,8 @@ import numpy as np
 import pytest
 
 from repro.core.paged_cache import (BlockAllocator, OutOfBlocksError,
-                                    gather_kv, make_kv_pool, write_decode_kv,
-                                    write_prefill_kv)
+                                    copy_blocks, gather_kv, make_kv_pool,
+                                    write_decode_kv, write_prefill_kv)
 
 
 def test_alloc_free_refcount():
@@ -59,7 +59,7 @@ def test_decode_write_targets_correct_slot():
     bt = jnp.array([[2, 3]], jnp.int32)
     kn = jnp.ones((1, 1, 4))
     kp = write_decode_kv(kp, 1, kn, bt, jnp.array([5]))
-    assert float(kp[1, 3, 1].sum()) == 4.0          # block 3, offset 1
+    assert float(kp[1, 3, :, 1].sum()) == 4.0       # block 3, offset 1
     assert float(kp.sum()) == 4.0                   # nothing else written
 
 
@@ -127,3 +127,72 @@ def test_gather_kv_bounded_matches_full_gather_on_live_prefix():
     np.testing.assert_array_equal(np.asarray(kb[:, :live * BS]),
                                   np.asarray(kf[:, :live * BS]))
     assert not np.any(np.asarray(kb[:, live * BS:]))
+
+
+def _old_write_decode(pool, layer, k_new, bt, positions):
+    """Decode write into the token-major ``[L, NB, BS, KV, D]`` pool the
+    cache had before its pages became head-major (the reference)."""
+    bs = pool.shape[2]
+    valid = positions >= 0
+    pos = jnp.maximum(positions, 0)
+    blk = jnp.take_along_axis(bt, (pos // bs)[:, None], axis=1)[:, 0]
+    blk = jnp.where(valid, blk, pool.shape[1])
+    return pool.at[layer, blk, pos % bs].set(k_new.astype(pool.dtype),
+                                             mode="drop")
+
+
+def _old_write_prefill(pool, layer, k, bt, ctx_lens, pos_offset):
+    """Prefill write into the token-major pool through its flattened
+    layer slice, as before."""
+    B, S = k.shape[:2]
+    L, NB, BS = pool.shape[:3]
+    pos = pos_offset + jnp.arange(S)
+    blk = bt[:, pos // BS]
+    valid = pos[None, :] < ctx_lens[:, None]
+    flat = jnp.where(valid, blk * BS + (pos % BS)[None, :], NB * BS)
+    lp = pool[layer].reshape(NB * BS, *pool.shape[3:])
+    lp = lp.at[flat.reshape(-1)].set(
+        k.reshape(B * S, *k.shape[2:]).astype(pool.dtype), mode="drop")
+    return pool.at[layer].set(lp.reshape(pool.shape[1:]))
+
+
+@pytest.mark.parametrize("case,layer,off", [
+    ("decode", 0, 0), ("decode", -1, -1), ("decode-inactive", -1, -1),
+    ("prefill", 0, 0), ("prefill", -1, -1), ("cow", -1, -1)])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_head_major_writes_equal_token_major_transposed(case, layer, off,
+                                                        dtype):
+    """The dense pool's head-major ``[L, NB, KV, BS, D]`` writes equal the
+    token-major pool's, transposed, bit for bit: layers 0 and L-1,
+    in-block offsets 0 and BS-1, an inactive slot (position -1: dropped),
+    a prompt chunk starting mid-block, and a CoW block copy."""
+    L, NB, BS, KV, D, MB = 3, 16, 8, 2, 16, 4
+    layer, off = layer % L, off % BS
+    rng = np.random.default_rng(6)
+    old = jnp.asarray(rng.normal(size=(L, NB, BS, KV, D)), dtype)
+    new = jnp.swapaxes(old, 2, 3)
+    bt = jnp.asarray(rng.permutation(NB)[:3 * MB].reshape(3, MB), jnp.int32)
+    if case.startswith("decode"):
+        k = jnp.asarray(rng.normal(size=(3, KV, D)), jnp.float32)
+        pos = jnp.asarray([2 * BS + off, off, BS + off], jnp.int32)
+        if case == "decode-inactive":
+            pos = pos.at[1].set(-1)
+        o = _old_write_decode(old, layer, k, bt, pos)
+        n = write_decode_kv(new, jnp.int32(layer), k, bt, pos)
+    elif case == "prefill":
+        S = 2 * BS
+        k = jnp.asarray(rng.normal(size=(3, S, KV, D)), jnp.float32)
+        ctx = jnp.asarray([off + S, off + 5, off + BS], jnp.int32)
+        o = _old_write_prefill(old, layer, k, bt, ctx, off)
+        n = write_prefill_kv(new, jnp.int32(layer), k, bt, ctx,
+                             pos_offset=jnp.int32(off))
+    else:
+        src = jnp.asarray([int(bt[0, 1]), int(bt[1, 0])], jnp.int32)
+        dst = jnp.asarray([int(bt[2, 3]), int(bt[2, 2])], jnp.int32)
+        o = copy_blocks(jnp.copy(old), src, dst)
+        n = copy_blocks(new, src, dst)
+    assert not np.array_equal(np.asarray(o, np.float32),
+                              np.asarray(old, np.float32))
+    np.testing.assert_array_equal(np.asarray(n, np.float32),
+                                  np.asarray(jnp.swapaxes(o, 2, 3),
+                                             np.float32))

@@ -100,14 +100,14 @@ def test_kv_quant_roundtrip_bounded(seed, BS, KV, D, log_mag):
     on-grid values exact."""
     from repro.core.kv_quant import dequantize_blocks, quantize_blocks
     rng = np.random.default_rng(seed)
-    x = jnp.asarray(rng.normal(size=(2, BS, KV, D)) * 10.0 ** log_mag,
+    x = jnp.asarray(rng.normal(size=(2, KV, BS, D)) * 10.0 ** log_mag,
                     jnp.float32)
     live = jnp.asarray(rng.random((2, BS)) < 0.7)
     q, scales = quantize_blocks(x, live)
     deq = dequantize_blocks(q, scales)
-    err = jnp.abs(jnp.where(live[..., None, None], x, 0.0) - deq)
-    assert bool(jnp.all(err <= (scales / 2 * (1 + 1e-5))[:, None, :, None]))
-    assert bool(jnp.all(jnp.where(live[..., None, None], 0.0, deq) == 0))
+    err = jnp.abs(jnp.where(live[:, None, :, None], x, 0.0) - deq)
+    assert bool(jnp.all(err <= (scales / 2 * (1 + 1e-5))[:, :, None, None]))
+    assert bool(jnp.all(jnp.where(live[:, None, :, None], 0.0, deq) == 0))
     # a second pass over the dequantized values is a fixed point when the
     # scale is unchanged (round(int) == int) -- no drift without growth
     q2, scales2 = quantize_blocks(deq, live)

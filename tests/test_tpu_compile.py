@@ -5,7 +5,8 @@ described, not attached, which is where Mosaic refuses a block shape the
 TPU's (8, 128) tiling rule forbids or more VMEM than a kernel may use —
 faults that interpret mode cannot see.  Shapes are qwen1.5-0.5b's
 published widths (16 x 64 heads, KV 16 or the Opt-GQA grouping 2, 16-token
-pages, a 256-token chunk, the 1024 x 2816 MLP).
+pages, a 256-token chunk, the 1024 x 2816 MLP); the kernels read a
+two-layer stacked ``[L, NB, KV, BS, D]`` pool at a traced layer.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and xdist workers import every file.
@@ -24,7 +25,7 @@ from repro.kernels.gptq_matmul import gptq_matmul
 from repro.kernels.paged_attention import paged_attention
 from repro.kernels.paged_attention_quant import paged_attention_quant
 
-H, D, BS, NB, MB, W, SLOTS = 16, 64, 16, 4096, 64, 256, 8
+H, D, BS, NB, MB, W, SLOTS, L = 16, 64, 16, 4096, 64, 256, 8, 2
 
 
 @pytest.fixture(scope="module")
@@ -71,33 +72,35 @@ def _built(chip, build, args):
 
 def _decode(chip, kv, dtype, alibi):
     q = chip((SLOTS, H, D), jnp.bfloat16)
-    pool = chip((NB, BS, kv, D), dtype)
+    pool = chip((L, NB, kv, BS, D), dtype)
     bt = chip((SLOTS, MB), jnp.int32)
     sl = chip((SLOTS,), jnp.int32)
+    layer = chip((), jnp.int32)
     slopes = alibi_slopes(H) if alibi else None
     if dtype == jnp.int8:
-        sc = chip((NB, kv), jnp.float32)
-        return _hlo(lambda q, k, ks, v, vs, bt, sl: paged_attention_quant(
-            q, k, ks, v, vs, bt, sl, slopes, interpret=False),
-            q, pool, sc, pool, sc, bt, sl)
-    return _hlo(lambda q, k, v, bt, sl: paged_attention(
-        q, k, v, bt, sl, slopes, interpret=False), q, pool, pool, bt, sl)
+        sc = chip((L, NB, kv), jnp.float32)
+        return _hlo(lambda q, k, ks, v, vs, ly, bt, sl: paged_attention_quant(
+            q, k, ks, v, vs, ly, bt, sl, slopes, interpret=False),
+            q, pool, sc, pool, sc, layer, bt, sl)
+    return _hlo(lambda q, k, v, ly, bt, sl: paged_attention(
+        q, k, v, ly, bt, sl, slopes, interpret=False),
+        q, pool, pool, layer, bt, sl)
 
 
 def _chunk(chip, kv, dtype, alibi):
     q = chip((1, W, H, D), jnp.bfloat16)
     raw = chip((1, W, kv, D), jnp.bfloat16)
-    pool = chip((NB, BS, kv, D), dtype)
+    pool = chip((L, NB, kv, BS, D), dtype)
     bt = chip((1, MB), jnp.int32)
     off = chip((), jnp.int32)
     slopes = alibi_slopes(H) if alibi else None
-    sc = chip((NB, kv), jnp.float32) if dtype == jnp.int8 else None
+    sc = chip((L, NB, kv), jnp.float32) if dtype == jnp.int8 else None
 
-    def f(q, k, v, bt, off, tl, kr, vr, ks, vs):
-        return flash_attention_chunk(q, k, v, bt, off, tl, kr, vr, slopes,
-                                     k_scales=ks, v_scales=vs,
+    def f(q, k, v, ly, bt, off, tl, kr, vr, ks, vs):
+        return flash_attention_chunk(q, k, v, ly, bt, off, tl, kr, vr,
+                                     slopes, k_scales=ks, v_scales=vs,
                                      interpret=False)
-    return _hlo(f, q, pool, pool, bt, off, off, raw, raw, sc, sc)
+    return _hlo(f, q, pool, pool, off, bt, off, off, raw, raw, sc, sc)
 
 
 def _prefill(chip, kv, dtype, alibi):
@@ -190,3 +193,90 @@ def test_unified_step_compiles_with_kernels(chip, kv, kv_dtype, quant):
         *shapes, chip((SLOTS,), jnp.int32), sampling, chip((SLOTS,), jnp.bool_),
         chip((1, W), jnp.int32), chip((1, MB), jnp.int32), i32, i32)
     assert "tpu_custom_call" in hlo
+
+
+# ops that move no pool bytes of their own: views, loop plumbing, inputs
+_NO_BYTES = {"parameter", "get-tuple-element", "tuple", "bitcast", "while",
+             "conditional", "call", "opt-barrier"}
+
+
+def _big_int8_ops(hlo: str, min_elems: int):
+    """(name, opcode, shape, fused root opcode) of every compiled
+    instruction that outputs an int8 array of at least ``min_elems``
+    elements and is not a view or loop plumbing."""
+    roots, comp = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if head:
+            comp = head.group(1)
+        root = re.match(r"^\s*ROOT %[\w.\-]+ = .*? ([\w\-]+)\(", line)
+        if root and comp:
+            roots[comp] = root.group(1)
+    found = []
+    for line in hlo.splitlines():
+        m = re.match(r"^\s*(?:ROOT )?%([\w.\-]+) = (\([^=]*?\)|\S+) "
+                     r"([\w\-]+)\(", line)
+        if not m:
+            continue
+        name, shape, op = m.groups()
+        if op in _NO_BYTES:
+            continue
+        for dims in re.findall(r"s8\[([\d,]*)\]", shape):
+            n = 1
+            for d in filter(None, dims.split(",")):
+                n *= int(d)
+            if n >= min_elems:
+                called = re.search(r"calls=%([\w.\-]+)", line)
+                found.append((name, op, shape,
+                              roots.get(called.group(1)) if called else None))
+    return found
+
+
+@pytest.mark.parametrize("path", ["decode", "chunk"])
+def test_int8_pool_read_and_written_in_place(chip, path):
+    """Two layers of a serving path at the offline cell's widths — the
+    decode write + ``paged_attention_quant``, or a 512-token prompt
+    chunk's write + ``flash_attention_chunk`` — compiled for the chip:
+    the only compiled op that outputs an int8 array as large as one
+    layer's pool slice is the in-place scatter into the stacked pool, so
+    no layer slice is copied, relaid out, sliced out or written back."""
+    from repro.core.kv_quant import KVCache, kv_write_decode, kv_write_prefill
+    nl, nb, bs, kv, d, h, slots, mb, w = 2, 1024, 128, 2, 128, 12, 64, 48, 512
+    pool = chip((nl, nb, kv, bs, d), jnp.int8)
+    sc = chip((nl, nb, kv), jnp.float32)
+    rows = slots if path == "decode" else 1
+
+    def step(q, kn, vn, kp, ks, vp, vs, bt, sl):
+        def layer(li, carry):
+            cache, o = carry
+            if path == "decode":
+                cache = kv_write_decode(cache, li, kn, vn, bt, sl - 1)
+                o = paged_attention_quant(
+                    q + o, cache.k, cache.k_scale, cache.v, cache.v_scale,
+                    li, bt, sl, interpret=False)
+            else:
+                cache = kv_write_prefill(cache, li, kn, vn, bt, sl + w,
+                                         pos_offset=sl[0])
+                o = flash_attention_chunk(
+                    q + o, cache.k, cache.v, li, bt, sl[0], sl[0] + w, kn,
+                    vn, k_scales=cache.k_scale, v_scales=cache.v_scale,
+                    interpret=False)
+            return cache, o
+        cache, o = jax.lax.fori_loop(
+            0, nl, layer, (KVCache(kp, vp, ks, vs), jnp.zeros_like(q)))
+        return o, cache
+
+    lead = (slots,) if path == "decode" else (1, w)
+    hlo = jax.jit(step, donate_argnums=(3, 4, 5, 6)).lower(
+        chip(lead + (h, d), jnp.bfloat16), chip(lead + (kv, d), jnp.bfloat16),
+        chip(lead + (kv, d), jnp.bfloat16), pool, sc, pool, sc,
+        chip((rows, mb), jnp.int32), chip((rows,), jnp.int32)
+    ).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    big = _big_int8_ops(hlo, nb * kv * bs * d)
+    stacked = f"s8[{nl},{nb},{kv},{bs},{d}]"
+    bad = [b for b in big
+           if not (b[2].startswith(stacked)
+                   and "scatter" in (b[1], b[3]))]
+    assert not bad, bad
+    assert big                   # the pools' scatters themselves are seen

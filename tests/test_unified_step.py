@@ -160,11 +160,10 @@ def test_unified_requires_chunked_and_fused(small):
 
 
 # the step's named regions, by executable: every region of the decode
-# rows and of the chunk, and the feed gather of the chained step.  On the
-# CPU the chunk's attention is the XLA reference over the whole pool, so
-# its pool_slice shows only where Pallas runs (interpret mode here).
-_REGIONS = ("embed", "attention", "kv_write", "pool_slice", "mlp",
-            "lm_head")
+# rows and of the chunk, and the feed gather of the chained step.  The
+# kernels read the stacked pools in place, so no executable has a
+# pool_slice region (each layer's pool slice) any more.
+_REGIONS = ("embed", "attention", "kv_write", "mlp", "lm_head")
 
 
 @pytest.mark.parametrize("executable,scopes", [
@@ -204,3 +203,4 @@ def test_step_regions_are_named_scopes(small, executable, scopes):
              for part in loc.split("/")}
     missing = [s for s in scopes if s not in found]
     assert not missing, f"{executable}: no ops in {missing}"
+    assert "pool_slice" not in found, executable
