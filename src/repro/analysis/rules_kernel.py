@@ -12,17 +12,25 @@ small constant environment (representative shapes for anything that
 cannot be computed statically):
 
 * ``kernel.index-map-arity`` — every ``index_map`` must take
-  ``len(grid) + num_scalar_prefetch`` arguments;
+  ``len(grid) + num_scalar_prefetch`` arguments (at most that many
+  named ones when it takes ``*args``; at least ``len(grid)`` when
+  ``num_scalar_prefetch`` is not a literal);
 * ``kernel.body-arity`` — the kernel body's unbound positional params
   must equal prefetch + inputs + outputs + scratch (skipped for
-  ``*refs`` bodies and non-literal spec lists);
+  ``*refs`` bodies, non-literal spec lists and a non-literal
+  ``num_scalar_prefetch``);
 * ``kernel.operand-count`` — the immediate call must pass
-  ``num_scalar_prefetch + len(in_specs)`` operands;
+  ``num_scalar_prefetch + len(in_specs)`` operands (same skips);
 * ``kernel.page-walk-unbounded`` — every index map that subscripts a
   prefetched block table is evaluated over the full grid x a set of
   live lengths; each table column must stay within
   ``[0, max(ceil(live/block_size) - 1, 0)]`` and ``[0, table_width)``.
-  Helper clamps (``_clamp_live``, ``_chunk_clamp``) are inlined;
+  Helper clamps (``_clamp_live``, ``_chunk_clamp``) are inlined.  The
+  prefetch refs are the map's named params past the grid dims (a map
+  may take the rest as ``*args``).  Only index maps are walked: a table
+  walk that lives in the kernel body — the paged decode kernel's manual
+  DMA loop over the live pages — is outside what this rule sees, and
+  its bounds are held by the kernel tests instead;
 * ``kernel.out-dtype`` — stores to the output ref must ``.astype`` the
   ref's dtype (f32 accumulators silently upcast the output otherwise).
 """
@@ -250,7 +258,8 @@ def _const_env(project: Project, fn: FunctionInfo) -> Dict[str, object]:
 class _Site:
     def __init__(self, call: ast.Call):
         self.call = call
-        self.n_prefetch = 0
+        # None: not a literal, so the arity checks that need it are off
+        self.n_prefetch: Optional[int] = 0
         self.grid_expr: Optional[ast.expr] = None
         self.in_specs_expr: Optional[ast.expr] = None
         self.out_specs_expr: Optional[ast.expr] = None
@@ -271,7 +280,7 @@ def _parse_site(call: ast.Call) -> _Site:
         skw = {k.arg: k.value for k in spec.keywords}
         n = literal_or_none(skw.get("num_scalar_prefetch")) \
             if skw.get("num_scalar_prefetch") is not None else 0
-        site.n_prefetch = n if isinstance(n, int) else 0
+        site.n_prefetch = n if isinstance(n, int) else None
         site.grid_expr = skw.get("grid")
         site.in_specs_expr = skw.get("in_specs")
         site.out_specs_expr = skw.get("out_specs")
@@ -298,7 +307,8 @@ def _spec_count(expr: Optional[ast.expr]) -> Optional[int]:
 
 def _index_maps(fn: FunctionInfo):
     """Every ``pl.BlockSpec(shape, index_map)`` in the wrapper: yields
-    (display name, lineno, params, body-or-None, FunctionInfo-or-None)."""
+    (display name, lineno, params, variadic, body-or-None), ``variadic``
+    when the map takes ``*args`` after its named params."""
     seen = set()
     for node in ast.walk(fn.node):
         if not (isinstance(node, ast.Call)
@@ -308,7 +318,8 @@ def _index_maps(fn: FunctionInfo):
         m = node.args[1]
         if isinstance(m, ast.Lambda):
             params = [p.arg for p in m.args.posonlyargs + m.args.args]
-            yield ("<lambda>", m.lineno, params, m.body, None)
+            yield ("<lambda>", m.lineno, params, m.args.vararg is not None,
+                   m.body)
         elif isinstance(m, ast.Name):
             target = fn.module.functions.get(f"{fn.qualname}.{m.id}") \
                 or fn.module.functions.get(m.id)
@@ -320,7 +331,7 @@ def _index_maps(fn: FunctionInfo):
                 if isinstance(stmt, ast.Return):
                     body = stmt.value
             yield (m.id, target.node.lineno, target.positional_params,
-                   body, target)
+                   target.node.args.vararg is not None, body)
 
 
 def _resolve_kernel(fn: FunctionInfo, expr: Optional[ast.expr],
@@ -392,24 +403,33 @@ class KernelChecker:
 
         # (a) index-map arity + (d) page-walk boundedness
         if grid is not None:
-            want = len(grid) + site.n_prefetch
-            for name, lineno, params, body, _tgt in _index_maps(fn):
-                if len(params) != want:
+            n_pre = site.n_prefetch
+            want = len(grid) + (n_pre or 0)
+            for name, lineno, params, variadic, body in _index_maps(fn):
+                if variadic:
+                    ok = n_pre is None or len(params) <= want
+                elif n_pre is None:
+                    ok = len(params) >= len(grid)
+                else:
+                    ok = len(params) == want
+                if not ok:
                     findings.append(Finding(
                         RULE, fn.module.rel, fn.qualname,
                         f"kernel.index-map-arity.{name}",
                         f"index_map `{name}` takes {len(params)} args but "
                         f"the grid has {len(grid)} dims + "
-                        f"{site.n_prefetch} scalar-prefetch refs "
+                        f"{n_pre} scalar-prefetch refs "
                         f"(= {want})", lineno))
                     continue
                 if body is not None:
-                    self._walk_check(fn, env, grid, site.n_prefetch, name,
-                                     lineno, params, body, findings)
+                    # the named params past the grid dims are prefetch refs
+                    self._walk_check(fn, env, grid, len(params) - len(grid),
+                                     name, lineno, params, body, findings)
 
         # (b) kernel body arity
         kernel, bound = _resolve_kernel(fn, site.kernel_expr, self.project)
-        if kernel is not None and None not in (n_in, n_out, n_scr) \
+        if kernel is not None \
+                and None not in (site.n_prefetch, n_in, n_out, n_scr) \
                 and isinstance(kernel.node, ast.FunctionDef) \
                 and kernel.node.args.vararg is None:
             free = [p for p in kernel.positional_params if p not in bound]
@@ -426,7 +446,7 @@ class KernelChecker:
 
         # (c) operand count at the immediate call
         outer = self._outer_call(fn, site.call)
-        if outer is not None and n_in is not None \
+        if outer is not None and None not in (site.n_prefetch, n_in) \
                 and not any(isinstance(a, ast.Starred) for a in outer.args):
             want = site.n_prefetch + n_in
             if len(outer.args) != want:

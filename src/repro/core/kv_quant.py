@@ -13,8 +13,9 @@ KV pool — to symmetric per-block-per-head int8:
 so KV bytes per cached token drop ~2x vs bf16 (~4x vs the f32 CPU pools)
 with a ``2 * L * KV * 4 / BS`` bytes/token scales overhead.  Reads
 dequantize in-register: the Pallas decode kernel
-(``kernels/paged_attention_quant.py``) multiplies each int8 K/V tile by
-its scale inside the online-softmax loop — the quantized cache is never
+(``kernels/paged_attention_quant.py``) multiplies each int8 page's
+scores and ``p @ V`` product by the page's scale inside the
+online-softmax loop — the quantized cache is never
 materialized densely (TurboAttention, arXiv 2412.08585; MILLION, arXiv
 2504.03661).  Writers and kernels address the stacked pool in place:
 a write is a scatter into ``values[layer, block]``, a read is the

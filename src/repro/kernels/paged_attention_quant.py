@@ -1,19 +1,16 @@
 """Pallas TPU paged-attention decode kernel over the int8 KV pool.
 
-Same grid / scalar-prefetch structure as ``paged_attention.py`` — one
-grid step = (sequence, page) covering every KV head; the block table and
-the layer index resolve ``(layer, physical page)`` of the stacked
-``[L, NB, KV, BS, D]`` pool inside the BlockSpec ``index_map``, so the
-pool is read in place with no per-layer slice or relayout; online
-softmax across pages in VMEM scratch; Opt-GQA shared-KV contraction of
-all G grouped query heads per head tile.  The ``pallas_call`` IS
-``paged_decode_call`` with scales: the K/V tiles DMA'd into VMEM are
-**int8** ``[BS, D]`` head tiles with one f32 scale per (page, kv head),
-read from SMEM and applied in-register right before the contraction.
-The quantized cache is never materialized in HBM at full precision:
-attention consumes it directly (the TurboAttention observation, arXiv
-2412.08585), so the kernel moves ~1/2 (bf16) to ~1/4 (f32) of the
-baseline's KV bytes per decode step.
+The ``pallas_call`` IS ``paged_decode_call`` (``paged_attention.py``)
+with scales: the same walk over each slot's live pages of the stacked
+``[L, NB, KV, BS, D]`` pool, read in place, and the same online-softmax
+body, Opt-GQA shared-KV contraction of all G grouped query heads per
+head tile.  The pages DMA'd into VMEM are **int8** ``[BS, D]`` head
+tiles; the layer's ``[KV, NB]`` f32 scales (one per page and KV head)
+sit in SMEM, and each page's scale multiplies its scores and its
+``p @ V`` product in-register.  The quantized cache is never
+materialized in HBM at full precision: attention consumes it directly
+(the TurboAttention observation, arXiv 2412.08585), so the kernel moves
+~1/2 (bf16) to ~1/4 (f32) of the baseline's KV bytes per decode step.
 """
 from __future__ import annotations
 

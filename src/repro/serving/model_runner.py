@@ -46,10 +46,12 @@ class ModelRunner:
         # Dispatch spans carry the profiler label of their executable.
         self.tracer = tracer if tracer is not None else NULL_TRACER
         # engine-owned counters: forward passes, the live decode rows
-        # they compute, and compiles seen at dispatch time
+        # they compute, the live KV pages those rows read per layer, and
+        # compiles seen at dispatch time
         self.metrics = metrics if metrics is not None \
             else MetricsDict(MetricsRegistry())
-        for k in ("forward_passes", "decode_rows", "compiles"):
+        for k in ("forward_passes", "decode_rows", "decode_pages",
+                  "compiles"):
             self.metrics.setdefault(k, 0)
         # executable name -> jit cache size last seen after a dispatch
         # (``copy_blocks`` is one jit for the process: start from now)
@@ -68,6 +70,9 @@ class ModelRunner:
         # ``device_dispatches_per_step`` (host->device table uploads are
         # transfers, not dispatches, and are not counted)
         self.dispatches = 0
+        # live KV pages per layer of the slots the last ``sync_tables``
+        # gave a length: what one decode pass over them reads
+        self.decode_pages = 0
         self.state = T.make_decode_state(cfg, max_slots, num_blocks, self.mb,
                                          dtype=state_dtype,
                                          kv_cache_dtype=self.kv_cache_dtype)
@@ -131,9 +136,16 @@ class ModelRunner:
     # ------------------------------------------------------------ obs
     def _count_pass(self, rows: int, passes: int = 1) -> None:
         """Counters of one forward-pass dispatch: ``passes`` passes (a
-        megastep's horizon) each computing ``rows`` live decode rows."""
+        megastep's horizon) each computing ``rows`` live decode rows that
+        read ``decode_pages`` KV pages per layer (none without rows)."""
         self.metrics["forward_passes"] += passes
         self.metrics["decode_rows"] += rows * passes
+        self.metrics["decode_pages"] += self._pages(rows) * passes
+
+    def _pages(self, rows: int) -> int:
+        """The ``pages`` arg of a forward-pass span with ``rows`` decode
+        rows: the live pages of the synced slots, 0 when none decode."""
+        return self.decode_pages if rows else 0
 
     def _note_compile(self, name: str, fn) -> None:
         """After a dispatch: when the executable's jit cache grew, the
@@ -155,6 +167,8 @@ class ModelRunner:
         for slot, s in running.items():
             bt[slot, :len(s.block_ids)] = s.block_ids
             sl[slot] = s.seq_len
+        bs = self.cfg.paging.block_size
+        self.decode_pages = int((-(-sl // bs)).sum())
         if "block_table" in self.state:
             self.state["block_table"] = jnp.asarray(bt)
         self.state["seq_lens"] = jnp.asarray(sl)
@@ -194,7 +208,8 @@ class ModelRunner:
                      "ctx_lens": jnp.asarray(lens)}
         self.dispatches += 1
         with self.tracer.span("dispatch:prefill", cat="device",
-                              args={"batch": B, "maxlen": maxlen, "rows": 0},
+                              args={"batch": B, "maxlen": maxlen, "rows": 0,
+                                    "pages": 0},
                               label="prefill"):
             logits, sub = self._prefill(self.params, sub, batch)
         self._count_pass(0)
@@ -220,7 +235,8 @@ class ModelRunner:
         self.dispatches += 1
         with self.tracer.span("dispatch:chunk", cat="device",
                               args={"start": start, "length": length,
-                                    "rows": 0}, label="prefill_chunk"):
+                                    "rows": 0, "pages": 0},
+                              label="prefill_chunk"):
             logits, cache = self._prefill_chunk(
                 self.params, cache, jnp.asarray(toks), jnp.asarray(bt),
                 jnp.int32(start), jnp.int32(start + length))
@@ -262,7 +278,8 @@ class ModelRunner:
         self.dispatches += 1
         with self.tracer.span("dispatch:unified", cat="device",
                               args={"start": start, "length": length,
-                                    "rows": rows}, label="unified_step"):
+                                    "rows": rows, "pages": self._pages(rows)},
+                              label="unified_step"):
             out, self.state = self._unified(
                 self.params, self.state, jnp.asarray(tokens), sp,
                 jnp.asarray(active), jnp.asarray(toks), jnp.asarray(bt),
@@ -293,7 +310,7 @@ class ModelRunner:
         self.dispatches += 1
         with self.tracer.span("dispatch:unified_chained", cat="device",
                               args={"start": start, "length": length,
-                                    "rows": rows},
+                                    "rows": rows, "pages": self._pages(rows)},
                               label="unified_step_chained"):
             out, self.state = self._unified_chained(
                 self.params, self.state, prev_out,
@@ -347,7 +364,8 @@ class ModelRunner:
         ``rows`` of them live (the slots ``sync_tables`` gave a length)."""
         self.dispatches += 1
         with self.tracer.span("dispatch:decode", cat="device",
-                              args={"rows": rows}, label="decode"):
+                              args={"rows": rows, "pages": self._pages(rows)},
+                              label="decode"):
             logits, self.state = self._decode(self.params, self.state,
                                               jnp.asarray(tokens))
         self._count_pass(rows)
@@ -362,7 +380,8 @@ class ModelRunner:
         rows = int(np.count_nonzero(active))
         self.dispatches += 1
         with self.tracer.span("dispatch:megastep", cat="device",
-                              args={"n_steps": int(n_steps), "rows": rows},
+                              args={"n_steps": int(n_steps), "rows": rows,
+                                    "pages": self._pages(rows)},
                               label="megastep"):
             out, self.state = self._megastep(
                 self.params, self.state, jnp.asarray(tokens), sp,

@@ -3,6 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.configs.base import QuantConfig
 from repro.core.alibi import alibi_slopes
@@ -11,7 +12,8 @@ from repro.core.quant import make_quant_params
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.gptq_matmul import gptq_matmul
-from repro.kernels.paged_attention import paged_attention
+from repro.kernels.paged_attention import (paged_attention,
+                                           pages_per_block)
 
 TOL = {jnp.float32: 5e-5, jnp.bfloat16: 2e-2}
 
@@ -36,42 +38,88 @@ def test_flash_attention_sweep(B, S, H, KV, D, dtype, alibi, win):
                                np.asarray(r, np.float32), atol=TOL[dtype])
 
 
+# the TPU interpreter, which raises on any out-of-bounds read: a decode
+# kernel that DMAs a page through a table entry past the live pages
+# (set to out-of-range garbage below) fails here
+OOB = pltpu.InterpretParams(out_of_bounds_reads="raise")
+
+
+def _walk_cases(B, P, BS, MB, rng):
+    """Sequence lengths that hit the live-page walk's edges (a slot
+    with no tokens, one and two whole P-page blocks and one token past
+    them, a full table), then random ones."""
+    edges = [0, P * BS, P * BS + 1, 2 * P * BS, 2 * P * BS + 1, MB * BS, 1]
+    edges = [n for n in edges if n <= MB * BS]
+    rand = rng.integers(1, MB * BS + 1, B)
+    return np.asarray((edges + list(rand))[:B], np.int32)
+
+
+def _garbage_tail(bt, sl, BS, NB):
+    """The table with every entry at or past a slot's live page count
+    set to an out-of-range page id (the kernel must never read them)."""
+    bt = np.array(bt)
+    live = -(-np.asarray(sl) // BS)
+    cols = np.arange(bt.shape[1])[None, :]
+    junk = np.where(np.arange(bt.shape[0]) % 2 == 0, NB + 1000, -5)[:, None]
+    return jnp.asarray(np.where(cols >= live[:, None], junk, bt), jnp.int32)
+
+
+def _check_decode(o, r, sl, atol):
+    """Kernel vs reference on live slots; a slot with no tokens gives
+    zeros (the reference averages over masked positions there)."""
+    o, r = np.asarray(o, np.float32), np.asarray(r, np.float32)
+    live = np.asarray(sl) > 0
+    np.testing.assert_allclose(o[live], r[live], atol=atol)
+    assert not o[~live].any()
+
+
 @pytest.mark.parametrize("B,H,KV,D,BS,MB", [
     (3, 8, 2, 32, 8, 4), (2, 4, 4, 16, 16, 3), (2, 12, 2, 64, 8, 6),
     (1, 8, 1, 128, 16, 2),
+    # head dim 128: the manual live-page walk, with P = MB (16-token
+    # pages), and with P below MB and MB not a multiple of it (64-token
+    # pages: P = 4 in f32, 8 in bf16)
+    (7, 8, 2, 128, 16, 5), (7, 16, 4, 128, 64, 10),
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_attention_sweep(B, H, KV, D, BS, MB, dtype):
-    NB = B * MB + 2
+    """Decode kernel vs reference at the last layer of a two-layer
+    pool, over the walk's edge lengths, with garbage table entries past
+    each slot's live pages."""
+    L, NB = 2, B * MB + 2
     ks = jax.random.split(jax.random.PRNGKey(1), 4)
     q = jax.random.normal(ks[0], (B, H, D), dtype)
-    kp = jax.random.normal(ks[1], (1, NB, KV, BS, D), dtype)
-    vp = jax.random.normal(ks[2], (1, NB, KV, BS, D), dtype)
+    kp = jax.random.normal(ks[1], (L, NB, KV, BS, D), dtype)
+    vp = jax.random.normal(ks[2], (L, NB, KV, BS, D), dtype)
     bt = jax.random.permutation(ks[3], NB)[:B * MB].reshape(B, MB)
     bt = bt.astype(jnp.int32)
-    sl = jnp.asarray(np.random.default_rng(0).integers(1, MB * BS + 1, B),
-                     jnp.int32)
-    o = paged_attention(q, kp, vp, 0, bt, sl, interpret=True)
-    r = ref.paged_attention_ref(q, kp, vp, 0, bt, sl)
-    np.testing.assert_allclose(np.asarray(o, np.float32),
-                               np.asarray(r, np.float32), atol=TOL[dtype])
+    P = pages_per_block(KV, BS, D, jnp.dtype(dtype).itemsize, MB) \
+        if D % 128 == 0 else 1
+    sl = jnp.asarray(_walk_cases(B, P, BS, MB, np.random.default_rng(0)))
+    o = paged_attention(q, kp, vp, L - 1, _garbage_tail(bt, sl, BS, NB), sl,
+                        interpret=OOB)
+    r = ref.paged_attention_ref(q, kp, vp, L - 1, bt, sl)
+    _check_decode(o, r, sl, TOL[dtype])
 
 
 def test_paged_attention_alibi_and_window():
-    B, H, KV, D, BS, MB = 2, 8, 2, 32, 8, 5
+    """ALiBi + sliding window through both page walks: the grid's (head
+    dim 32) and the live-page walk's (head dim 128)."""
+    B, H, KV, BS, MB = 2, 8, 2, 8, 5
     NB = B * MB
-    ks = jax.random.split(jax.random.PRNGKey(2), 4)
-    q = jax.random.normal(ks[0], (B, H, D))
-    kp = jax.random.normal(ks[1], (1, NB, KV, BS, D))
-    vp = jax.random.normal(ks[2], (1, NB, KV, BS, D))
-    bt = jnp.arange(NB, dtype=jnp.int32).reshape(B, MB)
-    sl = jnp.array([37, 12], jnp.int32)
-    slo = alibi_slopes(H)
-    o = paged_attention(q, kp, vp, 0, bt, sl, slo, sliding_window=16,
-                        interpret=True)
-    r = ref.paged_attention_ref(q, kp, vp, 0, bt, sl, alibi_slopes=slo,
-                                sliding_window=16)
-    np.testing.assert_allclose(o, r, atol=5e-5)
+    for D in (32, 128):
+        ks = jax.random.split(jax.random.PRNGKey(2), 4)
+        q = jax.random.normal(ks[0], (B, H, D))
+        kp = jax.random.normal(ks[1], (1, NB, KV, BS, D))
+        vp = jax.random.normal(ks[2], (1, NB, KV, BS, D))
+        bt = jnp.arange(NB, dtype=jnp.int32).reshape(B, MB)
+        sl = jnp.array([37, 12], jnp.int32)
+        slo = alibi_slopes(H)
+        o = paged_attention(q, kp, vp, 0, bt, sl, slo, sliding_window=16,
+                            interpret=True)
+        r = ref.paged_attention_ref(q, kp, vp, 0, bt, sl, alibi_slopes=slo,
+                                    sliding_window=16)
+        np.testing.assert_allclose(o, r, atol=5e-5)
 
 
 @pytest.mark.parametrize("q_off", [0, 8, 5, 11])   # 0 / block-aligned /

@@ -272,29 +272,76 @@ def test_head_major_writes_equal_token_major_transposed(case, layer, off):
 
 # ------------------------------------------------------------ kernel
 
-@pytest.mark.parametrize("use_alibi", [False, True])
-def test_paged_attention_quant_kernel_matches_ref(use_alibi):
+@pytest.mark.parametrize("use_alibi,walk", [
+    pytest.param(False, False, id="False"),
+    pytest.param(True, False, id="True"),
+    # head dim 128: the manual live-page walk, 16 x 32 KB int8 pages per
+    # block against a 20-entry table, at the last layer, over its edge
+    # lengths, with garbage table entries past the live pages
+    pytest.param(False, True, id="walk-edges"),
+    pytest.param(True, True, id="walk-alibi-window"),
+])
+def test_paged_attention_quant_kernel_matches_ref(use_alibi, walk):
     """Interpret-mode Pallas kernel (in-register dequant) == dequantizing
     XLA reference."""
     from repro.core.alibi import alibi_slopes
     from repro.kernels.paged_attention_quant import paged_attention_quant
     from repro.kernels.ref import paged_attention_quant_ref
-    B, H, KV, D, NB, BS, MB = 3, 8, 2, 16, 16, 8, 4
-    q = jax.random.normal(jax.random.fold_in(KEY, 5), (B, H, D), jnp.float32)
-    kraw = jax.random.normal(jax.random.fold_in(KEY, 6), (1, NB, KV, BS, D))
-    vraw = jax.random.normal(jax.random.fold_in(KEY, 7), (1, NB, KV, BS, D))
-    full = jnp.ones((1, NB, BS), bool)
-    kq, ks = quantize_blocks(kraw, full)
-    vq, vs = quantize_blocks(vraw, full)
-    bt = jnp.asarray(np.random.default_rng(0).permutation(NB)[:B * MB]
-                     .reshape(B, MB), jnp.int32)
-    sl = jnp.asarray([17, 8, 30], jnp.int32)
-    slopes = alibi_slopes(H) if use_alibi else None
-    out = paged_attention_quant(q, kq, ks, vq, vs, 0, bt, sl, slopes,
-                                interpret=True)
-    ref = paged_attention_quant_ref(q, kq, ks, vq, vs, 0, bt, sl,
-                                    alibi_slopes=slopes)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+    slopes = alibi_slopes(8) if use_alibi else None
+    if not walk:
+        B, H, KV, D, NB, BS, MB = 3, 8, 2, 16, 16, 8, 4
+        q = jax.random.normal(jax.random.fold_in(KEY, 5), (B, H, D),
+                              jnp.float32)
+        kraw = jax.random.normal(jax.random.fold_in(KEY, 6),
+                                 (1, NB, KV, BS, D))
+        vraw = jax.random.normal(jax.random.fold_in(KEY, 7),
+                                 (1, NB, KV, BS, D))
+        full = jnp.ones((1, NB, BS), bool)
+        kq, ks = quantize_blocks(kraw, full)
+        vq, vs = quantize_blocks(vraw, full)
+        bt = jnp.asarray(np.random.default_rng(0).permutation(NB)[:B * MB]
+                         .reshape(B, MB), jnp.int32)
+        sl = jnp.asarray([17, 8, 30], jnp.int32)
+        out = paged_attention_quant(q, kq, ks, vq, vs, 0, bt, sl, slopes,
+                                    interpret=True)
+        ref = paged_attention_quant_ref(q, kq, ks, vq, vs, 0, bt, sl,
+                                        alibi_slopes=slopes)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5)
+        return
+    from jax.experimental.pallas import tpu as pltpu
+
+    from repro.kernels.paged_attention import pages_per_block
+    B, H, KV, D, BS, MB, L = 7, 8, 2, 128, 128, 20, 2
+    NB = B * MB + 2
+    P = pages_per_block(KV, BS, D, 1, MB)
+    assert 1 < P < MB and MB % P
+    rng = np.random.default_rng(3)
+    q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.bfloat16)
+    kq = jnp.asarray(rng.integers(-127, 128, (L, NB, KV, BS, D)), jnp.int8)
+    vq = jnp.asarray(rng.integers(-127, 128, (L, NB, KV, BS, D)), jnp.int8)
+    ks = jnp.asarray(rng.uniform(0.001, 0.02, (L, NB, KV)), jnp.float32)
+    vs = jnp.asarray(rng.uniform(0.001, 0.02, (L, NB, KV)), jnp.float32)
+    bt = rng.permutation(NB)[:B * MB].reshape(B, MB).astype(np.int32)
+    # no tokens, one whole block and a token past it, a full table, one
+    # token, one block less a token, the second block's last page
+    sl = np.asarray([0, P * BS, P * BS + 1, MB * BS, 1, P * BS - 1,
+                     MB * BS - 5], np.int32)
+    live = -(-sl // BS)
+    junk = np.where(np.arange(MB)[None] >= live[:, None],
+                    np.where(np.arange(B) % 2 == 0, NB + 1000, -5)[:, None],
+                    bt)
+    win = 300 if use_alibi else 0
+    out = paged_attention_quant(
+        q, kq, ks, vq, vs, L - 1, jnp.asarray(junk), jnp.asarray(sl), slopes,
+        sliding_window=win,
+        interpret=pltpu.InterpretParams(out_of_bounds_reads="raise"))
+    ref = paged_attention_quant_ref(q, kq, ks, vq, vs, L - 1,
+                                    jnp.asarray(bt), jnp.asarray(sl),
+                                    alibi_slopes=slopes, sliding_window=win)
+    out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+    np.testing.assert_allclose(out[sl > 0], ref[sl > 0], atol=2e-2)
+    assert not out[sl == 0].any()       # a slot with no tokens: zeros
 
 
 # ------------------------------------------------------------ end to end

@@ -432,6 +432,50 @@ def test_forward_pass_spans_carry_rows(small, mode):
     assert "repro_forward_passes" in text and "repro_decode_rows" in text
 
 
+@pytest.mark.parametrize("mode", [
+    {}, {"enable_async_step": False}, {"use_fused": False}],
+    ids=["async", "unified", "legacy-decode"])
+def test_forward_pass_spans_carry_pages(small, mode):
+    """Each forward-pass span's ``pages`` arg, and ``repro_decode_pages``,
+    is the live KV pages its decode rows read per layer: the sum of
+    ceil(seq_len / BS) over the device lengths the dispatch ran with."""
+    cfg, params = small
+    eng = ServingEngine(cfg, params, max_slots=4, num_blocks=128,
+                        max_blocks_per_seq=8, prefill_bucket=16,
+                        max_num_batched_tokens=16, **mode)
+    runner, bs = eng.runner, cfg.paging.block_size
+    seen = []
+
+    def watch(name):
+        run = getattr(runner, name)
+
+        def wrapped(*a, **kw):
+            sl = np.asarray(runner.state["seq_lens"])
+            seen.append(int(np.sum(-(-sl // bs))))
+            return run(*a, **kw)
+        setattr(runner, name, wrapped)
+    for name in ("megastep", "unified_step", "unified_step_chained",
+                 "decode"):
+        watch(name)
+    rng = np.random.default_rng(5)
+    for _ in range(6):
+        eng.add(list(rng.integers(1, 200, int(rng.integers(3, 40)))),
+                SamplingParams(max_tokens=int(rng.integers(4, 24))))
+    eng.run_until_done()
+    pages, total = [], 0
+    for s in eng.tracer.spans():
+        if s.name in FORWARD[:4]:
+            n = s.args["n_steps"] if s.name == "dispatch:megastep" else 1
+            pages.append(s.args["pages"] if s.args["rows"] else 0)
+            total += s.args["pages"] * n
+        elif s.name in FORWARD:
+            assert s.args["pages"] == 0, s
+    # chunk-only unified dispatches decode nothing: their tables are empty
+    assert pages == seen and max(pages) > 1
+    assert eng.obs.get("repro_decode_pages").get() == total
+    assert "repro_decode_pages" in eng.obs.to_prometheus()
+
+
 def test_compile_fires_once_per_executable(small):
     cfg, params = small
     eng = ServingEngine(cfg, params, max_slots=4, num_blocks=128,

@@ -162,6 +162,27 @@ def test_kernel_hlo_names_are_stable(chip, build, args, name):
     assert calls == [name]
 
 
+def test_decode_walk_at_cell_shapes(chip):
+    """The offline cell's decode kernel — 64 slots, 2 KV heads, 128-token
+    int8 pages of head dim 128, 48 table entries, a 28-layer pool of 1024
+    pages — takes the live-page walk with several pages per block, its
+    double-buffered page buffers fit the VMEM budget, and it compiles for
+    the chip (Mosaic refuses a kernel whose scratch overflows VMEM)."""
+    from repro.kernels.paged_attention import BUFFER_VMEM, pages_per_block
+    nl, nb, kv, bs, d, h, slots, mb = 28, 1024, 2, 128, 128, 12, 64, 48
+    P = pages_per_block(kv, bs, d, 1, mb)
+    assert 1 < P <= mb
+    assert 4 * P * kv * bs * d <= BUFFER_VMEM      # int8 tiles: no padding
+    pool = chip((nl, nb, kv, bs, d), jnp.int8)
+    sc = chip((nl, nb, kv), jnp.float32)
+    hlo = _hlo(lambda q, k, ks, v, vs, ly, bt, sl: paged_attention_quant(
+        q, k, ks, v, vs, ly, bt, sl, interpret=False),
+        chip((slots, h, d), jnp.bfloat16), pool, sc, pool, sc,
+        chip((), jnp.int32), chip((slots, mb), jnp.int32),
+        chip((slots,), jnp.int32))
+    assert "tpu_custom_call" in hlo
+
+
 @pytest.mark.parametrize("kv,kv_dtype,quant", [
     (16, "bf16", None), (2, "int8", "rtn-int4")],
     ids=["kv16-bf16", "kv2-int8-w4a16"])
