@@ -31,8 +31,8 @@ Write discipline (what keeps one scale per block sound):
   requantizes the whole block with the recomputed amax.  When the scale
   is unchanged this is exact (``round(q) == q``); when it grows, existing
   values pick up at most half a quantization step — bounded drift, and
-  bit-identical between the fused megastep and the legacy loop because
-  both run this same op;
+  bit-identical at every megastep horizon because each decode step runs
+  this same op;
 * copy-on-write (``copy_blocks_quant``) copies the scale row with the
   value block, so forks keep decoding correctly.
 

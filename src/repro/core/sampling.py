@@ -10,8 +10,9 @@ slot's step key is ``fold_in(base_key, num_generated_tokens)``, i.e. the
 stream is indexed by *position in the generation*, not by engine step.
 That single choice buys three properties at once:
 
-* the fused megastep (device ``fori_loop``) and the legacy host loop
-  compute byte-identical keys, so their sampled tokens match bitwise;
+* the fused megastep (device ``fori_loop``) at any horizon, the unified
+  step and the whole-prompt wave's standalone sample compute
+  byte-identical keys, so their sampled tokens match bitwise;
 * a request's tokens do not depend on batch composition (slots never
   share a key), so seeded requests reproduce across runs and schedules;
 * recompute-style preemption resumes the stream where it left off
@@ -114,8 +115,7 @@ def sample_from_logits(logits: jnp.ndarray, base_keys: jnp.ndarray,
         # one max-reduce instead of isfinite+all over [B, V]: NaN
         # propagates through max, +inf IS the max, and an all--inf row
         # maxes to -inf — while mask-legal -inf entries under a finite
-        # max still pass.  Keeps the guarded trace within noise of the
-        # unguarded one (the <2% acceptance gate in bench_serving).
+        # max still pass.  The guard costs one reduce over the logits.
         ok = jnp.isfinite(jnp.max(logits, axis=-1))
         tok = jnp.where(ok, tok, -1)
     return tok

@@ -73,19 +73,13 @@ def main() -> None:
                          "stop-the-world whole-prompt prefill (the "
                          "parity oracle; also the path non-full-"
                          "attention archs always use)")
-    ap.add_argument("--enable-unified-step",
-                    action=argparse.BooleanOptionalAction, default=True,
-                    help="--no-enable-unified-step restores the two-call "
-                         "mixed step (separate decode / prefill-chunk / "
-                         "sample dispatches) — the unified single-"
-                         "dispatch step's parity oracle")
     ap.add_argument("--enable-async-step",
                     action=argparse.BooleanOptionalAction, default=True,
                     help="--no-enable-async-step restores the read-back-"
                          "every-step loop — the async pipelined step "
                          "(plan/enqueue N+1 while N executes, tokens "
-                         "read back one step late) is on by default in "
-                         "unified mode")
+                         "read back one step late) is on by default "
+                         "with chunked prefill")
     ap.add_argument("--max-waiting", type=int, default=None,
                     help="bound the waiting queue; arrivals past the "
                          "bound are handled per --shed-policy")
@@ -143,7 +137,6 @@ def main() -> None:
                    num_blocks=args.blocks, max_blocks_per_seq=16,
                    max_num_batched_tokens=args.max_num_batched_tokens,
                    enable_chunked_prefill=args.enable_chunked_prefill,
-                   enable_unified_step=args.enable_unified_step,
                    enable_async_step=args.enable_async_step,
                    max_waiting=args.max_waiting,
                    shed_policy=args.shed_policy,

@@ -401,8 +401,8 @@ def decode_megastep(cfg: ModelConfig, params: Params,
             temps [B] f32 (0 => greedy), top_ks [B] i32 (0 => off),
             top_ps [B] f32 (1.0 => off).  Step ``t`` of the horizon
             samples slot ``b`` with ``fold_in(keys[b], counts[b] + t)`` —
-            exactly the key the legacy host loop derives, so fused and
-            legacy outputs are bitwise identical per slot.
+            the key one dispatch per token derives, so every horizon
+            gives bitwise identical outputs per slot.
     active: [B] bool; inactive slots are carried through untouched (their
             KV writes are dropped, their seq_lens stay 0).
     n_steps: scalar int32 *dynamic* trip count <= max_horizon — the host
@@ -786,8 +786,8 @@ def unified_step(cfg: ModelConfig, params: Params,
     sampling: padded per-row ``SamplingParams`` arrays of B + 1 rows —
         rows [0, B) are the decode slots, row B is the chunk's request
         (each row's key is ``fold_in(keys[r], counts[r])``, the same
-        stream position the two-call path derives, so sampled tokens are
-        bitwise identical to the megastep + batched-sample pair);
+        stream position the megastep and the whole-prompt wave's sample
+        derive, so sampled tokens are bitwise identical to theirs);
     active: [B] bool decode mask (row gating only — the host ignores
         inactive rows of the output);
     chunk_tokens / chunk_block_table / pos_offset / total_len: the

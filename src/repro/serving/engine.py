@@ -12,27 +12,21 @@ The engine is a thin conductor over two halves:
   ``max_num_batched_tokens``, with KV blocks allocated incrementally
   per chunk;
 * ``serving.model_runner.ModelRunner`` — the device: paged KV pools,
-  the fixed-shape ``[1, chunk_tokens]`` chunk-prefill executable
-  (compiled ONCE regardless of prompt length or wave composition),
-  jitted per-token decode / fused megastep, CoW block copies,
-  on-device per-slot sampling.
+  the fixed-shape unified step (compiled ONCE regardless of prompt
+  length or wave composition), the fused decode megastep, CoW block
+  copies, on-device per-slot sampling.
 
-``enable_chunked_prefill=False`` (or an arch whose prefill state cannot
-yet re-enter mid-prompt: SSM / recurrent / sliding-ring stacks)
-restores the stop-the-world whole-prompt wave — retained as the parity
-oracle: chunked greedy serving is token-exact against it on the
-reduced configs for both the bf16 and int8 KV pools.
-
-With ``enable_unified_step=True`` (default; needs chunked mode and
-``use_fused``) a mixed iteration — decodes interleaved with a prefill
-chunk — executes as ONE donated device dispatch: the decode step, the
-chunk (through the dynamic-offset chunk-flash path) and every row's
-sampling fused under one jit, one ``[max_slots + 1]`` token readback.
-``enable_unified_step=False`` keeps the two-call execute (decode
-dispatch, then chunk dispatch(es), then a first-token sample dispatch)
-as the unified path's token-exact / bitwise-sampling parity oracle;
-``report()['device_dispatches_per_step']`` shows the difference
-(1.0 unified vs ~2-3 two-call in the steady mixed state).
+One executable serves each situation the engine can observe.  A plan
+with prefill work runs as unified dispatches: the first fuses the
+step's decodes, a prefill chunk (through the dynamic-offset chunk-flash
+path) and every row's sampling under one donated jit, with one
+``[max_slots + 1]`` token readback; further chunks of an admission
+burst dispatch chunk-only.  A decode-only plan runs the megastep, one
+donated dispatch per multi-token horizon (``max_horizon=1`` gives one
+dispatch per token through the same decode step and sampling kernel).
+An arch whose prefill state cannot yet re-enter mid-prompt (SSM /
+recurrent / sliding-ring stacks), or ``enable_chunked_prefill=False``,
+takes the stop-the-world whole-prompt wave instead of chunks.
 
 ``enable_async_step=True`` (default; rides the unified executable)
 pipelines the loop one step deep: an iteration plans and ENQUEUES its
@@ -45,17 +39,14 @@ speculatively (``Sequence.speculated``: in-flight tokens counted into
 ``seq_len`` but not ``req.output``) and reconciles at readback;
 finish/abort/preemption during the flight discards the speculated
 token, which recompute replay regenerates token-exactly.  All donating
-dispatches (megastep, CoW, chunk bursts, the two-call oracle) flush
-the pipeline first.  ``enable_async_step=False`` keeps the
-read-back-every-step engine as the pipeline's parity oracle.
+dispatches (megastep, CoW, chunk bursts) flush the pipeline first.
+``enable_async_step=False`` keeps the read-back-every-step engine as
+the pipeline's parity oracle.
 
 Requests enter with a ``SamplingParams`` (temperature / top_k / top_p /
 seed / stop token ids / max_tokens) that is lowered to padded per-slot
 device arrays, so one batch freely mixes greedy, temperature and
-top-k/top-p requests — through *both* the legacy per-token loop
-(``use_fused=False``, the bitwise-equivalence oracle) and the fused
-decode megastep (default; one buffer-donated device call per multi-token
-horizon, one host↔device round trip per dispatch).
+top-k/top-p requests, with one host↔device round trip per dispatch.
 
 Results stream back as ``RequestOutput`` deltas: ``step()`` returns the
 events produced by that iteration and ``stream()`` yields them as
@@ -77,7 +68,6 @@ from dataclasses import dataclass, field
 from typing import Dict, Iterator, List, Optional, Sequence as SeqT
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
@@ -93,8 +83,8 @@ from repro.serving.model_runner import ModelRunner
 from repro.serving.params import (FINISH_ABORT, FINISH_ERROR, FINISH_LENGTH,
                                   FINISH_SHED, FINISH_STOP, RequestOutput,
                                   SamplingParams)
-from repro.serving.scheduler import (PrefillChunk, RequestState, Scheduler,
-                                     Sequence, StepPlan, UnifiedDispatch)
+from repro.serving.scheduler import (RequestState, Scheduler, Sequence,
+                                     StepPlan, UnifiedDispatch)
 
 
 class EngineOverloadedError(RuntimeError):
@@ -142,12 +132,10 @@ class ServingEngine:
     def __init__(self, cfg: ModelConfig, params, *, max_slots: int = 8,
                  num_blocks: int = 512, max_blocks_per_seq: int = 64,
                  prefill_bucket: int = 64, rt: Optional[dict] = None,
-                 seed: int = 0, use_fused: bool = True,
-                 max_horizon: int = 8, detokenizer=None,
+                 seed: int = 0, max_horizon: int = 8, detokenizer=None,
                  kv_cache_dtype: str = "bf16",
                  max_num_batched_tokens: int = 256,
                  enable_chunked_prefill: bool = True,
-                 enable_unified_step: bool = True,
                  enable_async_step: bool = True,
                  max_waiting: Optional[int] = None,
                  shed_policy: str = "reject",
@@ -166,7 +154,6 @@ class ServingEngine:
         self.max_slots = max_slots
         self.mb = max_blocks_per_seq
         self.prefill_bucket = prefill_bucket
-        self.use_fused = use_fused
         self.max_horizon = max(1, max_horizon)
         self.detokenizer = detokenizer
         self.seed = seed
@@ -188,11 +175,8 @@ class ServingEngine:
         self.metrics: Dict[str, float] = MetricsDict(self.obs, initial={
             "prompt_tokens": 0, "gen_tokens": 0, "preemptions": 0,
             "host_syncs": 0, "decode_dispatches": 0, "decode_steps": 0,
-            "decode_time_s": 0.0, "truncated_prompts": 0,
-            # dispatches after the first: excludes jit compile of the step
-            "decode_warm_steps": 0, "decode_warm_time_s": 0.0,
-            "timed_decode_dispatches": 0,
-            "prefill_chunks": 0, "plan_steps": 0, "budget_tokens_used": 0,
+            "truncated_prompts": 0, "prefill_chunks": 0, "plan_steps": 0,
+            "budget_tokens_used": 0,
             # device calls per engine iteration (the unified-dispatch
             # figure): work_steps counts iterations that dispatched at all
             "device_dispatches": 0, "work_steps": 0,
@@ -229,7 +213,9 @@ class ServingEngine:
         ring_only = bool(cfg.sliding_window) and not any(
             cfg.layer_kind(i) == "full" for i in range(cfg.num_layers))
         # chunked prefill needs every layer's prefill state to live in the
-        # paged pool; SSM / recurrent / ring archs keep the oracle path
+        # paged pool; SSM / recurrent / ring archs keep the whole-prompt
+        # wave.  A chunked engine runs every prefill through the unified
+        # step.
         self.chunked = bool(enable_chunked_prefill) \
             and T.supports_chunked_prefill(cfg)
         alloc = BlockAllocator(
@@ -250,13 +236,6 @@ class ServingEngine:
         chunk_tokens = min(self.max_num_batched_tokens,
                            self.scheduler.cap_tokens) if self.chunked \
             else None
-        # unified single-dispatch step: decode + the step's prefill chunk
-        # + sampling fused under one jit.  Needs the chunk executable
-        # (chunked mode) and the fused on-device sampling contract
-        # (use_fused) — the two-call path survives behind
-        # ``enable_unified_step=False`` as the parity oracle.
-        self.unified = bool(enable_unified_step) and self.chunked \
-            and use_fused
         # async pipelined step (default; needs the unified executable):
         # a mixed iteration ENQUEUES its unified dispatch chained on the
         # previous (still in-flight) one and reads tokens back exactly
@@ -264,7 +243,7 @@ class ServingEngine:
         # absorb, detokenize, bookkeeping — overlaps device execution.
         # ``enable_async_step=False`` keeps the read-back-every-step
         # engine as the pipeline's token-exactness oracle.
-        self.async_step = bool(enable_async_step) and self.unified
+        self.async_step = bool(enable_async_step) and self.chunked
         # the per-step non-finite logit guard is a *static* flag baked
         # into the jitted executables at trace time: guards-off builds
         # trace byte-identical programs to a build that never heard of
@@ -280,7 +259,6 @@ class ServingEngine:
                                   rt=rt, max_horizon=self.max_horizon,
                                   kv_cache_dtype=kv_cache_dtype,
                                   chunk_tokens=chunk_tokens,
-                                  unified=self.unified,
                                   tracer=self.tracer,
                                   metrics=self.metrics)
         self.kv_cache_dtype = self.runner.kv_cache_dtype
@@ -634,9 +612,9 @@ class ServingEngine:
 
     def _run_prefill_oracle(self, seqs: List[Sequence],
                             outs: List[RequestOutput]) -> None:
-        """Stop-the-world wave prefill — retained ONLY as the parity
-        oracle behind ``enable_chunked_prefill=False`` (and for archs the
-        chunk executable cannot serve): pads the whole wave to a
+        """Stop-the-world wave prefill — the only path for archs the
+        unified step cannot chunk, and the parity oracle behind
+        ``enable_chunked_prefill=False``: pads the whole wave to a
         ``prefill_bucket`` multiple, so it recompiles per (wave size,
         bucket) pair and stalls every running sequence for the duration
         of the longest prompt."""
@@ -664,53 +642,6 @@ class ServingEngine:
         # decode's sync.
         self._sync_tables(self.scheduler.running)
 
-    def _run_prefill_chunks(self, chunks: List[PrefillChunk],
-                            outs: List[RequestOutput]) -> None:
-        """Execute the plan's prefill chunks through the fixed-shape
-        executable.  Logits stay on device; prompts completing this step
-        have their first token sampled in ONE batched call (a single
-        host sync for any number of finishing prompts)."""
-        final: List[tuple] = []
-        try:
-            for c in chunks:
-                logits = self._protected(
-                    [c.seq.req.rid],
-                    lambda c=c: self.runner.prefill_chunk(c.seq, c.start,
-                                                          c.length))
-                with self.tracer.span("plan", cat="host"):
-                    self.scheduler.complete_chunk(c)
-                    self.metrics["prefill_chunks"] += 1
-                    self.metrics["prompt_tokens"] += c.length
-                if c.last:
-                    final.append((c.seq, logits))
-        except PoisonedDispatchError as e:
-            # prompts that completed prefill this step but whose
-            # first-token sample never ran cannot decode token-exactly:
-            # requeue them alongside the failing dispatch (recompute
-            # replays them; as innocents they clear probation fast)
-            raise PoisonedDispatchError(
-                set(e.rids) | {s.req.rid for s, _ in final}) from e
-        if not final:
-            return
-        # pad to max_slots rows so this sample executable compiles once
-        # regardless of how many prompts finish in a step (and shares its
-        # shape with the legacy decode path's per-slot sample)
-        pad = self.max_slots - len(final)
-        with self.tracer.span("inputs", cat="host"):
-            stacked = jnp.concatenate(
-                [lg for _, lg in final]
-                + ([jnp.zeros((pad,) + final[0][1].shape[1:],
-                              final[0][1].dtype)] if pad else []), axis=0)
-        nxt = self._protected(
-            [s.req.rid for s, _ in final],
-            lambda: self.runner.sample(stacked, self._sampling_rows(
-                [s.req for s, _ in final] + [None] * pad)))
-        self.metrics["host_syncs"] += 1
-        now = time.perf_counter()
-        with self.tracer.span("absorb", cat="host"):
-            for i, (s, _) in enumerate(final):
-                self._absorb(s, [int(nxt[i])], now, outs)
-
     # ------------------------------------------------------------ readback
     def _readback(self, out) -> np.ndarray:
         """The host<->device sync boundary: one bulk transfer of a
@@ -726,20 +657,9 @@ class ServingEngine:
             return np.asarray(out)
 
     # ------------------------------------------------------------ decode
-    def _record_decode_time(self, dt: float, steps: int) -> None:
-        self.metrics["decode_time_s"] += dt
-        # warm = past the megastep/decode compile call.  Gated on the
-        # count of *timed* decode dispatches, not decode_dispatches: an
-        # earlier unified mixed dispatch (never timed here) must not make
-        # the first pure-decode dispatch — the compile — read as warm.
-        self.metrics["timed_decode_dispatches"] += 1
-        if self.metrics["timed_decode_dispatches"] > 1:
-            self.metrics["decode_warm_time_s"] += dt
-            self.metrics["decode_warm_steps"] += steps
-
     def _prepare_dispatch(self, horizon: int) -> StepPlan:
-        """Oracle-mode planning: horizon + block growth for all running
-        (= all decodable) sequences, as one degenerate StepPlan."""
+        """Whole-prompt-mode planning: horizon + block growth for all
+        running (= all decodable) sequences, as one degenerate StepPlan."""
         h = self.scheduler.plan_horizon(horizon)
         cow = self.scheduler.grow_for_horizon(h) if h else []
         return StepPlan(decode_slots=sorted(self.scheduler.decodable())
@@ -748,16 +668,10 @@ class ServingEngine:
 
     def _dispatch_decode(self, plan: StepPlan,
                          outs: List[RequestOutput]) -> None:
-        """Execute a plan's decode half: fused megastep over the planned
-        horizon, or the legacy per-token loop (same planner, same
-        sampling kernel — the bitwise-equivalence oracle).  Only the
-        plan's decodable slots are active: mid-prefill slots get device
-        seq_len 0, so the decode KV scatter drops their writes."""
-        if not plan.decode_slots:
-            return
-        t0 = time.perf_counter()
-        if plan.cow_pairs:
-            self.runner.copy_cow(plan.cow_pairs)
+        """Execute a plan's decodes as one fused megastep over the
+        planned horizon.  Only the plan's decodable slots are active:
+        mid-prefill slots get device seq_len 0, so the decode KV scatter
+        drops their writes."""
         # device tables carry EXACTLY the planned slots: everything else
         # (mid-prefill, or decodables a degenerate budget left out) gets
         # seq_len 0, so the decode KV scatter drops their writes
@@ -770,16 +684,9 @@ class ServingEngine:
                     for sl in plan.decode_slots]
             active = np.zeros((self.max_slots,), bool)
             active[plan.decode_slots] = True
-        if self.use_fused:
-            out_np = self._protected(rids, lambda: self.runner.megastep(
-                toks, self._slot_sampling(live=set(rids)), active,
-                plan.horizon))
-        else:
-            def _decode_and_sample():
-                logits = self.runner.decode(toks, len(plan.decode_slots))
-                return self.runner.sample(
-                    logits, self._slot_sampling(live=set(rids)))
-            out_np = self._protected(rids, _decode_and_sample)[None]
+        out_np = self._protected(rids, lambda: self.runner.megastep(
+            toks, self._slot_sampling(live=set(rids)), active,
+            plan.horizon))
         with self.tracer.span("absorb", cat="host"):
             self.metrics["host_syncs"] += 1
             self.metrics["decode_dispatches"] += 1
@@ -788,21 +695,17 @@ class ServingEngine:
             for slot in plan.decode_slots:
                 self._absorb(self.scheduler.running[slot],
                              out_np[:, slot].tolist(), now, outs)
-        self._record_decode_time(time.perf_counter() - t0, plan.horizon)
 
     def _dispatch_unified(self, plan: StepPlan,
                           outs: List[RequestOutput]) -> None:
-        """Execute a mixed plan (decodes at horizon <= 1 interleaved with
-        prefill) as unified dispatches: the first fuses the decode step,
-        the step's first prefill chunk AND all sampling into ONE donated
-        device call with a single ``[max_slots + 1]`` token readback;
-        further chunks (fresh-admission bursts) each dispatch alone.  In
-        the steady mixed workload (one prompt chunking over a decoding
-        batch) that is exactly one device dispatch per engine iteration
-        — the two-call path pays a decode dispatch, a chunk dispatch and
-        a first-token sample dispatch for the same work."""
-        if plan.cow_pairs:
-            self.runner.copy_cow(plan.cow_pairs)
+        """Execute a plan's prefill chunks as unified dispatches (the
+        layout is ``StepPlan.unified_dispatches``): the first fuses the
+        decode step (at horizon 1), the step's first prefill chunk AND
+        all sampling into ONE donated device call with a single
+        ``[max_slots + 1]`` token readback; further chunks (fresh-
+        admission bursts) each dispatch alone.  In the steady mixed
+        workload (one prompt chunking over a decoding batch) that is
+        exactly one device dispatch per engine iteration."""
         done: List[tuple] = []
         try:
             for d in plan.unified_dispatches():
@@ -837,11 +740,6 @@ class ServingEngine:
                     self.metrics["prefill_chunks"] += 1
                     self.metrics["prompt_tokens"] += c.length
                     if d.decode_slots:
-                        # decode bookkeeping rides the unified dispatch;
-                        # its *timing* is not recorded —
-                        # decode_step_latency_us stays a pure-decode
-                        # figure (mixed dispatches include chunk compute
-                        # the two-call path never timed as decode)
                         self.metrics["decode_dispatches"] += 1
                         self.metrics["decode_steps"] += 1
         finally:
@@ -958,7 +856,7 @@ class ServingEngine:
         token-exactly via the counts-indexed sampling stream if the
         request ever runs again.  No-op when nothing is in flight, so it
         doubles as the pipeline flush every donating fallback dispatch
-        (megastep, CoW, chunk bursts, the two-call oracle) requires."""
+        (megastep, CoW, chunk bursts) requires."""
         fl = self._flight
         if fl is None:
             return
@@ -990,18 +888,16 @@ class ServingEngine:
     def _dispatch_fallback(self, plan: StepPlan,
                            outs: List[RequestOutput]) -> None:
         """The synchronous dispatch selection (also the async engine's
-        non-pipelined fallback, after a flush): unified one-dispatch
-        mixed steps, else megastep + chunk walk."""
-        if self.unified and plan.prefill and plan.horizon <= 1:
-            self._dispatch_unified(plan, outs)
-        else:
-            # pure-decode plans keep the fused megastep (already one
-            # dispatch per multi-token horizon); with
-            # enable_unified_step=False this two-phase execute is the
-            # unified path's parity oracle
+        non-pipelined fallback, after a flush): a plan's CoW copies,
+        then its decodes as a megastep unless they ride the first
+        unified dispatch (horizon 1 beside prefill), then its chunks as
+        unified dispatches."""
+        if plan.cow_pairs:
+            self.runner.copy_cow(plan.cow_pairs)
+        if plan.decode_slots and (plan.horizon > 1 or not plan.prefill):
             self._dispatch_decode(plan, outs)
-            if plan.prefill:
-                self._run_prefill_chunks(plan.prefill, outs)
+        if plan.prefill:
+            self._dispatch_unified(plan, outs)
 
     # ------------------------------------------------------------ drive
     def step(self) -> List[RequestOutput]:
@@ -1009,8 +905,9 @@ class ServingEngine:
         plans decodes first (fused horizon when no prefill is pending,
         one interleaved token otherwise), then packs prefill chunks into
         the remaining budget; the runner executes both halves.  With
-        ``enable_chunked_prefill=False`` the pre-budget stop-the-world
-        behaviour is preserved as the parity oracle.  Returns the
+        ``enable_chunked_prefill=False`` (or an arch the unified step
+        cannot chunk) the stop-the-world whole-prompt wave runs
+        instead.  Returns the
         ``RequestOutput`` deltas produced by this iteration.
 
         Robustness rides the same loop: deadlines expire before
@@ -1093,20 +990,20 @@ class ServingEngine:
                 if not self.scheduler.running:  # may be at the boundary
                     return outs
                 with self.tracer.span("plan", cat="host"):
-                    plan = self._prepare_dispatch(
-                        self.max_horizon if self.use_fused else 1)
-                self._dispatch_decode(plan, outs)
+                    plan = self._prepare_dispatch(self.max_horizon)
+                self._dispatch_fallback(plan, outs)
                 return outs
             with self.tracer.span("plan", cat="host"):
                 plan = self.scheduler.plan_step(
                     self.max_num_batched_tokens,
-                    max_horizon=self.max_horizon if self.use_fused else 1,
+                    max_horizon=self.max_horizon,
                     alloc_blocked=alloc_blocked)
                 self._mark_admitted([c.seq.req for c in plan.prefill],
                                     time.perf_counter())
                 ds = plan.unified_dispatches() if self.async_step else None
             if self.async_step:
-                if len(ds) == 1 and not plan.cow_pairs:
+                if len(ds) == 1 and plan.horizon <= 1 \
+                        and not plan.cow_pairs:
                     # the tentpole fast path (the steady mixed state):
                     # enqueue this step's single unified dispatch chained
                     # on the in-flight one, THEN read the previous step's
@@ -1118,8 +1015,9 @@ class ServingEngine:
                     self.metrics["async_steps"] += 1
                 else:
                     # leaving the pipelined regime (pure-decode megastep,
-                    # a multi-chunk admission burst, CoW copies, or no
-                    # schedulable work): every fallback dispatch donates
+                    # a multi-chunk admission burst, decodes at a
+                    # multi-token horizon beside prefill, CoW copies, or
+                    # no schedulable work): every fallback dispatch donates
                     # its inputs, so the in-flight dispatch is collected
                     # first — and absorbing its tokens may finish
                     # sequences the plan still references, so the plan is
@@ -1296,12 +1194,6 @@ class ServingEngine:
             if n else float("nan")
         total_toks = self.metrics["prompt_tokens"] + self.metrics["gen_tokens"]
         d_steps = max(self.metrics["decode_steps"], 1)
-        # prefer warm (post-compile) per-step latency when measurable
-        if self.metrics["decode_warm_steps"]:
-            step_lat = (self.metrics["decode_warm_time_s"]
-                        / self.metrics["decode_warm_steps"])
-        else:
-            step_lat = self.metrics["decode_time_s"] / d_steps
         # inter-token latency percentiles over per-event gaps: under
         # stop-the-world prefill the p99 carries the "one long prompt
         # stalls everyone" spikes the chunked planner bounds at O(chunk)
@@ -1321,7 +1213,7 @@ class ServingEngine:
             "prefill_chunks": self.metrics["prefill_chunks"],
             "prefill_compiles": self.runner.prefill_compiles(),
             # device calls per engine iteration (1.0 in the unified
-            # steady mixed state; ~2-3 on the two-call path)
+            # steady mixed state)
             "device_dispatches_per_step":
                 (self.metrics["device_dispatches"]
                  / self.metrics["work_steps"])
@@ -1344,9 +1236,9 @@ class ServingEngine:
             "host_syncs": self.metrics["host_syncs"],
             "decode_dispatches": self.metrics["decode_dispatches"],
             "decode_steps": self.metrics["decode_steps"],
-            "decode_step_latency_us": step_lat * 1e6,
             # decode-path syncs only (one per dispatch): prefill-wave syncs
-            # are excluded, so legacy reads exactly 1.0 and fused 1/horizon
+            # are excluded, so max_horizon=1 reads exactly 1.0 and a
+            # fused horizon 1/horizon
             "syncs_per_decode_step":
                 self.metrics["decode_dispatches"] / d_steps,
         }

@@ -82,9 +82,9 @@ class LLM:
         engine_kw:  forwarded to ``ServingEngine`` (max_slots,
                     num_blocks, max_blocks_per_seq,
                     max_num_batched_tokens, enable_chunked_prefill,
-                    enable_unified_step, enable_async_step,
-                    prefill_bucket [oracle path only], rt, use_fused,
-                    max_horizon, detokenizer via __init__; robustness:
+                    enable_async_step, prefill_bucket [whole-prompt
+                    path only], rt, max_horizon, detokenizer via
+                    __init__; robustness:
                     max_waiting, shed_policy, enable_guards,
                     fault_injector, max_dispatch_retries,
                     retry_backoff_s — see docs/API.md "Fault
@@ -95,10 +95,9 @@ class LLM:
                     engine step may batch (decodes first, then prefill
                     chunks); ``enable_chunked_prefill=False`` restores
                     the stop-the-world whole-prompt prefill (the parity
-                    oracle); ``enable_unified_step=False`` restores the
-                    two-call mixed step (separate decode / chunk /
-                    sample dispatches) instead of the default fused
-                    single-dispatch iteration;
+                    oracle); ``max_horizon`` caps the tokens one
+                    decode-only megastep dispatch yields (1 = one
+                    dispatch per token);
                     ``enable_async_step=False`` restores the
                     read-back-every-step loop instead of the default
                     one-step-deferred async pipeline (see docs/PERF.md

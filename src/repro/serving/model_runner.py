@@ -1,8 +1,9 @@
 """Device-side half of the serving engine: decode state + jitted calls.
 
-Owns the paged KV pools / SSM state pools, the jitted prefill /
-per-token decode / fused megastep executables, on-device sampling for the
-legacy loop, and the copy-on-write block copies.  It knows nothing about
+Owns the paged KV pools / SSM state pools, the jitted executables (the
+unified step and its chained variant, the fused decode megastep, the
+whole-prompt prefill and its first-token sample), and the copy-on-write
+block copies.  It knows nothing about
 queues, slots-as-policy, or request lifecycles — the ``Scheduler`` does;
 the engine facade wires the two together.
 
@@ -37,8 +38,7 @@ class ModelRunner:
                  num_blocks: int, max_blocks_per_seq: int,
                  rt: Optional[dict] = None, max_horizon: int = 8,
                  state_dtype=jnp.float32, kv_cache_dtype: str = "bf16",
-                 chunk_tokens: Optional[int] = None,
-                 unified: bool = False, tracer=None,
+                 chunk_tokens: Optional[int] = None, tracer=None,
                  metrics: Optional[MetricsDict] = None):
         self.cfg = cfg
         self.params = params
@@ -64,7 +64,6 @@ class ModelRunner:
         self.max_horizon = max(1, max_horizon)
         self.kv_cache_dtype = normalize_kv_cache_dtype(kv_cache_dtype)
         self.chunk_tokens = chunk_tokens
-        self.unified = bool(unified and chunk_tokens)
         # device dispatches issued so far (jitted calls + CoW copies) —
         # the engine diffs this around each step for
         # ``device_dispatches_per_step`` (host->device table uploads are
@@ -79,18 +78,15 @@ class ModelRunner:
 
         self._prefill = jax.jit(
             lambda p, s, b: T.prefill(cfg, p, s, b, None, self.rt))
-        # the serving chunk executable: [1, chunk_tokens] + scalar offsets
-        # regardless of prompt length or batch composition, so it compiles
-        # exactly once. Pools are donated: the chunk scatter updates the
-        # [L, NB, KV, BS, D] arrays (+ int8 scales) in place.
+        # the standalone chunk executable behind ``prefill_chunk``: the
+        # engine never dispatches it (its chunks ride the unified step);
+        # it stays for the benchmark's fault test, which wraps it by name
         self._prefill_chunk = None
         if chunk_tokens:
             self._prefill_chunk = jax.jit(
                 lambda p, c, t, bt, off, tl: T.prefill_chunk(
                     cfg, p, c, t, bt, off, tl, None, self.rt),
                 donate_argnums=(1,))
-        self._decode = jax.jit(
-            lambda p, s, t: T.decode_step(cfg, p, s, t, None, self.rt))
         # the fused megastep donates the whole decode state: the KV pools
         # are updated in place instead of copied every token.
         self._megastep = jax.jit(
@@ -101,10 +97,12 @@ class ModelRunner:
         # the unified step: ONE donated dispatch = one decode step for the
         # active slots + one prefill chunk + per-row sampling.  Shapes are
         # pinned to [max_slots] decode rows and the [1, chunk_tokens]
-        # chunk window, so it compiles exactly once.
+        # chunk window, so it compiles exactly once.  Pools are donated:
+        # the chunk scatter updates the [L, NB, KV, BS, D] arrays (+ int8
+        # scales) in place.
         self._unified = None
         self._unified_chained = None
-        if self.unified:
+        if chunk_tokens:
             self._unified = jax.jit(
                 lambda p, s, t, sp, a, c, cbt, off, tl: T.unified_step(
                     cfg, p, s, t, sp, a, c, cbt, off, tl, None, self.rt),
@@ -126,8 +124,8 @@ class ModelRunner:
         # (use_prev all False): allocated once so the chained executable
         # keeps a single (shape, dtype) signature either way
         self.zero_prev = jnp.zeros((max_slots + 1,), jnp.int32)
-        # legacy-loop sampling: the SAME per-slot kernel the megastep runs,
-        # jitted standalone so both paths are bitwise identical.  ``guard``
+        # the whole-prompt wave's first-token sample: the SAME per-slot
+        # kernel the fused executables run, jitted standalone.  ``guard``
         # is trace-static (a python bool branching on jnp.isfinite): with
         # guards off the traced program is identical to the pre-guard one.
         self._sample = jax.jit(sample_from_logits,
@@ -223,12 +221,12 @@ class ModelRunner:
         return logits
 
     def prefill_chunk(self, seq, start: int, length: int) -> jnp.ndarray:
-        """Run one prefill chunk of one sequence through the fixed-shape
-        executable: tokens [1, W] right-padded, scalar position offset.
-        Scatters the chunk K/V into the live pools (donated, in place)
-        and returns the last-live-token logits [1, V] as a *device*
-        array — the engine batches first-token sampling across the
-        step's final chunks, so no host sync happens here."""
+        """Run one prefill chunk of one sequence through the standalone
+        fixed-shape chunk executable: tokens [1, W] right-padded, scalar
+        position offset.  Scatters the chunk K/V into the live pools
+        (donated, in place) and returns the last-live-token logits
+        [1, V] as a *device* array.  The engine does not call it: its
+        chunks run through ``unified_step``."""
         toks, bt = self._chunk_inputs(seq.req.prompt, seq.block_ids, start,
                                       length)
         cache = cache_from_state(self.state)
@@ -332,46 +330,27 @@ class ModelRunner:
         return float(fn._cache_size())
 
     def prefill_compiles(self) -> float:
-        """Compile count of the executable that actually runs prefill
-        work: the unified step (which embeds the chunk path) in unified
-        mode, else the fixed-shape chunk executable — 1 forever for
-        either fixed-shape path; one per distinct (wave size, bucket)
-        shape for the whole-prompt oracle (the recompile explosion the
-        chunked path removes)."""
-        if self.unified and self._unified is not None:
+        """Compile count of the executable that runs prefill work: the
+        unified step (which embeds the chunk path) when chunked — 1
+        forever — else one per distinct (wave size, bucket) shape of the
+        whole-prompt wave (the recompile explosion chunking removes)."""
+        if self.chunk_tokens:
             return self.unified_compiles()
-        fn = self._prefill_chunk if self._prefill_chunk is not None \
-            else self._prefill
-        return self._cache_size(fn)
+        return self._cache_size(self._prefill)
 
     def unified_compiles(self) -> float:
         """Max compile count across the unified step executables (NaN
-        when unified dispatch is off or the private jax cache API
-        drifted).  The async engine runs mixed steps through the chained
-        variant and the flush fallbacks through the donated one — each
+        without chunking or when the private jax cache API drifted).
+        The async engine runs mixed steps through the chained variant
+        and the flush fallbacks through the donated one — each
         fixed-shape executable must compile exactly once, so a healthy
         run reads 1.0 whichever subset actually dispatched."""
         if self._unified is None:
             return float("nan")
-        counts = [self._cache_size(self._unified)]
-        if self._unified_chained is not None:
-            counts.append(self._cache_size(self._unified_chained))
-        return float(max(counts))
+        return max(self._cache_size(self._unified),
+                   self._cache_size(self._unified_chained))
 
     # ------------------------------------------------------------ decode
-    def decode(self, tokens: np.ndarray, rows: int) -> jnp.ndarray:
-        """One per-token decode step for all slots; tokens: [max_slots],
-        ``rows`` of them live (the slots ``sync_tables`` gave a length)."""
-        self.dispatches += 1
-        with self.tracer.span("dispatch:decode", cat="device",
-                              args={"rows": rows, "pages": self._pages(rows)},
-                              label="decode"):
-            logits, self.state = self._decode(self.params, self.state,
-                                              jnp.asarray(tokens))
-        self._count_pass(rows)
-        self._note_compile("decode", self._decode)
-        return logits
-
     def megastep(self, tokens: np.ndarray, sampling: Dict[str, np.ndarray],
                  active: np.ndarray, n_steps: int) -> np.ndarray:
         """Dispatch one fused horizon; returns the [n_steps, max_slots]
@@ -393,10 +372,10 @@ class ModelRunner:
         return out_np
 
     def sample(self, logits, sampling: Dict[str, np.ndarray]) -> np.ndarray:
-        """Per-slot sampling for the legacy loop / prefill first token.
-        An optional "poison" row-bias (fault injection) and the
-        non-finite guard flag ride through so the two-call oracle path
-        gets the exact same protection as the fused executables."""
+        """First-token sampling of the whole-prompt wave.  An optional
+        "poison" row-bias (fault injection) and the non-finite guard
+        flag ride through so the wave gets the exact same protection as
+        the fused executables."""
         self.dispatches += 1
         kw = {}
         if "poison" in sampling:

@@ -1,7 +1,7 @@
 """Public request surface of the serving API (the vLLM-shaped half).
 
-``SamplingParams`` travels with a request through admission, the legacy
-per-token loop and the fused decode megastep — the engine lowers it to
+``SamplingParams`` travels with a request through admission, the unified
+step and the fused decode megastep — the engine lowers it to
 padded per-slot device arrays (see ``core.sampling.sample_from_logits``).
 ``RequestOutput`` is what the engine emits back: one event per request per
 engine step that produced tokens for it, carrying both the delta and the

@@ -1,9 +1,10 @@
 """Token sampling: greedy / temperature / top-k / top-p.
 
 ``sample_from_logits`` (re-exported from ``repro.core.sampling``) is the
-jit-friendly per-slot core used by both the fused decode megastep and the
-legacy loop (via ``ModelRunner.sample``); ``sample`` is a host-facing
-convenience wrapper over the legacy single-key batch sampler.
+jit-friendly per-slot core used by the fused executables and the
+whole-prompt wave's first-token sample (``ModelRunner.sample``);
+``sample`` is a host-facing convenience wrapper over the single-key
+batch sampler.
 """
 from __future__ import annotations
 

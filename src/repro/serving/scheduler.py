@@ -136,14 +136,16 @@ class StepPlan:
         decodes are interleaving); any further chunks — bursts of fresh
         admissions — each get their own chunk-only dispatch, in plan
         order, with no decode rows.  Empty when the plan has no prefill
-        (a pure-decode plan dispatches the fused megastep instead) or
-        when the horizon exceeds 1 (never the case when prefill is
-        pending — the planner pins it).
+        (a pure-decode plan dispatches the fused megastep instead).
+
+        Decodes at a horizon above 1 run as the plan's megastep, so
+        every chunk dispatches alone.  The planner emits such a plan
+        when ``plan_horizon`` preempts a sequence after the horizon was
+        chosen and the freed blocks or slot let prefill in.
         """
-        if not self.prefill or self.horizon > 1:
-            return []
+        ride = list(self.decode_slots) if self.horizon <= 1 else []
         return [UnifiedDispatch(
-            decode_slots=list(self.decode_slots) if i == 0 else [],
+            decode_slots=ride if i == 0 else [],
             chunk=c, sample_chunk=c.last)
             for i, c in enumerate(self.prefill)]
 
@@ -530,7 +532,10 @@ class Scheduler:
         While prefill work is pending the decode horizon is pinned to 1
         (one decode token per sequence per iteration interleaved with
         chunks); with no prefill in flight the full fused horizon is
-        planned, recovering the megastep steady state.
+        planned, recovering the megastep steady state.  The pin is
+        decided before ``plan_horizon`` runs, so a preemption there can
+        free room for chunks beside a longer horizon (see
+        ``StepPlan.unified_dispatches``).
 
         ``alloc_blocked`` (fault injection: the allocator reports
         exhaustion) suppresses everything that would *take new blocks

@@ -23,8 +23,7 @@ class RecompileSentinel:
     regression — matching ``ModelRunner._cache_size``.
     """
 
-    _EXECUTABLES = ("_prefill_chunk", "_unified", "_unified_chained",
-                    "_megastep", "_decode", "_sample")
+    _EXECUTABLES = ("_unified", "_unified_chained", "_megastep", "_sample")
 
     def __init__(self):
         self._armed = []

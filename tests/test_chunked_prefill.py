@@ -1,8 +1,8 @@
 """Token-budget chunked prefill vs the stop-the-world whole-prompt
-oracle: greedy token-exactness (bf16 AND int8 KV pools), fused/legacy
-bitwise parity within chunked mode, preemption mid-prefill, the
-single-compile guarantee of the fixed-shape chunk executable, and the
-direct transformer-level chunk-vs-prefill check."""
+oracle: greedy token-exactness (bf16 AND int8 KV pools), multi-token
+horizon vs per-token bitwise parity within chunked mode, preemption
+mid-prefill, the single-compile guarantee of the fixed-shape chunk
+executable, and the direct transformer-level chunk-vs-prefill check."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -118,21 +118,48 @@ def test_chunked_serving_token_exact_vs_oracle(small, kv_cache_dtype):
     assert eng.runner.prefill_compiles() == 1
 
 
-def test_chunked_fused_matches_chunked_legacy_bitwise(small):
-    """Within chunked mode the fused megastep and the legacy loop stay
-    bitwise-identical across mixed sampling modes (the decode halves are
-    untouched by the prefill refactor)."""
+def test_chunked_horizon_matches_per_token_bitwise(small):
+    """Within chunked mode a multi-token megastep horizon and one decode
+    dispatch per token (``max_horizon=1``) stay bitwise-identical across
+    mixed sampling modes."""
     cfg, params = small
     prompts = _prompts(4, seed=31, lo=20, hi=40)
     sps = [SamplingParams(max_tokens=8),
            SamplingParams(temperature=0.9, max_tokens=8),
            SamplingParams(temperature=0.8, top_k=5, max_tokens=8),
            SamplingParams(temperature=0.7, top_p=0.9, seed=7, max_tokens=8)]
-    o_leg, _ = _drain(_engine(cfg, params, use_fused=False,
+    o_one, _ = _drain(_engine(cfg, params, max_horizon=1,
                               max_num_batched_tokens=16), prompts, sps)
-    o_fus, _ = _drain(_engine(cfg, params, use_fused=True,
-                              max_num_batched_tokens=16), prompts, sps)
-    assert o_leg == o_fus
+    o_fus, _ = _drain(_engine(cfg, params, max_num_batched_tokens=16),
+                      prompts, sps)
+    assert o_one == o_fus
+
+
+def test_runner_prefill_chunk_matches_unified_chunk(small):
+    """``ModelRunner.prefill_chunk``, the standalone chunk executable the
+    engine no longer dispatches, writes the same pool as a chunk-only
+    unified dispatch of the same chunk, and its logits' argmax is that
+    dispatch's greedy first token."""
+    cfg, params = small
+    prompt = _prompts(1, seed=81, lo=12, hi=13)[0]
+    engs, chunks = [], []
+    for _ in range(2):
+        eng = _engine(cfg, params, max_num_batched_tokens=16)
+        eng.add(prompt, SamplingParams(max_tokens=1))
+        (c,) = eng.scheduler.plan_step(16).prefill
+        engs.append(eng)
+        chunks.append(c)
+    (a, b), (ca, cb) = engs, chunks
+    logits = a.runner.prefill_chunk(ca.seq, ca.start, ca.length)
+    n = b.max_slots
+    out = b.runner.unified_step(
+        np.zeros(n, np.int32), b._sampling_rows([None] * n + [cb.seq.req]),
+        np.zeros(n, bool), cb.seq.req.prompt, cb.seq.block_ids, cb.start,
+        cb.length)
+    assert int(np.asarray(out)[n]) == int(jnp.argmax(logits[0]))
+    for k in ("k_pool", "v_pool"):
+        np.testing.assert_array_equal(np.asarray(a.runner.state[k]),
+                                      np.asarray(b.runner.state[k]))
 
 
 def test_chunked_interleaves_decode_with_long_prefill(

@@ -1,5 +1,6 @@
-"""Fused decode megastep vs legacy per-token loop, CoW device copy,
-preemption-requeue determinism, gather_kv partial-tail."""
+"""Fused multi-token decode horizon vs one dispatch per token
+(``max_horizon=1``), CoW device copy, preemption-requeue determinism,
+gather_kv partial-tail."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -22,9 +23,9 @@ def small():
     return cfg, params
 
 
-def _run(cfg, params, prompts, *, use_fused, temperature=0.0,
-         max_new_tokens=10, **kw):
-    eng = ServingEngine(cfg, params, use_fused=use_fused, **kw)
+def _run(cfg, params, prompts, *, temperature=0.0, max_new_tokens=10,
+         **kw):
+    eng = ServingEngine(cfg, params, **kw)
     for i, p in enumerate(prompts):
         eng.add_request(Request(rid=i, prompt=p, temperature=temperature,
                                 max_new_tokens=max_new_tokens))
@@ -38,40 +39,44 @@ def _prompts(n, seed=0, lo=4, hi=20):
             for _ in range(n)]
 
 
-# ------------------------------------------------------------ fused == legacy
+# ------------------------------------------------- horizon == per-token
 
-def test_fused_matches_legacy_greedy(small):
-    """Acceptance: fused-path outputs bitwise-identical (greedy) to the
-    step-by-step loop on the reduced qwen1.5-0.5b config."""
+def test_horizon_matches_per_token_greedy(small):
+    """Acceptance: multi-token-horizon outputs bitwise-identical (greedy)
+    to one dispatch per token (``max_horizon=1``) on the reduced
+    qwen1.5-0.5b config."""
     cfg, params = small
     kw = dict(max_slots=3, num_blocks=64, max_blocks_per_seq=8,
               prefill_bucket=16)
-    o_leg, _, _ = _run(cfg, params, _prompts(6), use_fused=False, **kw)
-    o_fus, rep, _ = _run(cfg, params, _prompts(6), use_fused=True, **kw)
-    assert len(o_leg) == len(o_fus) == 6
-    assert o_leg == o_fus
-    # the fast path actually fused: fewer dispatches than decode steps
+    o_one, rep1, _ = _run(cfg, params, _prompts(6), max_horizon=1, **kw)
+    o_fus, rep, _ = _run(cfg, params, _prompts(6), **kw)
+    assert len(o_one) == len(o_fus) == 6
+    assert o_one == o_fus
+    # the horizon actually fused: fewer dispatches than decode steps,
+    # while max_horizon=1 dispatches once per step
     assert rep["decode_dispatches"] < rep["decode_steps"]
+    assert rep1["decode_dispatches"] == rep1["decode_steps"]
 
 
-def test_fused_matches_legacy_temperature(small):
-    """The megastep splits the PRNG key once per step exactly like the host
-    loop, so even temperature sampling matches token for token."""
+def test_horizon_matches_per_token_temperature(small):
+    """The megastep splits the PRNG key once per step, so even
+    temperature sampling matches one dispatch per token, token for
+    token."""
     cfg, params = small
     kw = dict(max_slots=2, num_blocks=64, max_blocks_per_seq=8,
               prefill_bucket=16)
-    o_leg, _, _ = _run(cfg, params, _prompts(3, seed=7), use_fused=False,
+    o_one, _, _ = _run(cfg, params, _prompts(3, seed=7), max_horizon=1,
                        temperature=0.9, **kw)
-    o_fus, _, _ = _run(cfg, params, _prompts(3, seed=7), use_fused=True,
+    o_fus, _, _ = _run(cfg, params, _prompts(3, seed=7),
                        temperature=0.9, **kw)
-    assert o_leg == o_fus
+    assert o_one == o_fus
 
 
 def test_fused_single_sync_per_horizon(small):
     """Acceptance: steady-state decode performs at most one host<->device
     round trip per dispatched horizon."""
     cfg, params = small
-    _, rep, _ = _run(cfg, params, _prompts(3, seed=3), use_fused=True,
+    _, rep, _ = _run(cfg, params, _prompts(3, seed=3),
                      max_slots=4, num_blocks=64, max_blocks_per_seq=8,
                      prefill_bucket=16)
     # all admitted in one wave: total syncs = 1 prefill + 1 per dispatch
@@ -83,7 +88,7 @@ def test_fused_greedy_matches_direct_forward(small):
     """Fused engine greedy decode == teacher-forced model argmax."""
     cfg, params = small
     prompt = [5, 9, 13, 2, 7, 11]
-    outs, _, _ = _run(cfg, params, [prompt], use_fused=True,
+    outs, _, _ = _run(cfg, params, [prompt],
                       max_new_tokens=6, max_slots=2, num_blocks=64,
                       max_blocks_per_seq=8, prefill_bucket=8)
     toks = list(prompt)
@@ -176,35 +181,35 @@ def test_preemption_requeue_identical_outputs(small):
     request."""
     cfg, params = small
     prompts = _prompts(4, seed=11, lo=17, hi=30)
-    roomy, _, _ = _run(cfg, params, prompts, use_fused=True,
+    roomy, _, _ = _run(cfg, params, prompts,
                        max_new_tokens=32, max_slots=3, num_blocks=256,
                        max_blocks_per_seq=8, prefill_bucket=16)
-    tight, rep, eng = _run(cfg, params, prompts, use_fused=True,
+    tight, rep, eng = _run(cfg, params, prompts,
                            max_new_tokens=32, max_slots=3, num_blocks=9,
                            max_blocks_per_seq=8, prefill_bucket=16)
     assert eng.metrics["preemptions"] > 0, "scenario must exercise preemption"
     assert tight == roomy
 
 
-def test_preemption_identical_legacy_vs_fused(small):
+def test_preemption_identical_per_token_vs_horizon(small):
     cfg, params = small
     prompts = _prompts(4, seed=11, lo=17, hi=30)
     kw = dict(max_new_tokens=32, max_slots=3, num_blocks=9,
               max_blocks_per_seq=8, prefill_bucket=16)
-    o_leg, _, eng_l = _run(cfg, params, prompts, use_fused=False, **kw)
-    o_fus, _, eng_f = _run(cfg, params, prompts, use_fused=True, **kw)
-    assert eng_l.metrics["preemptions"] > 0
+    o_one, _, eng_1 = _run(cfg, params, prompts, max_horizon=1, **kw)
+    o_fus, _, eng_f = _run(cfg, params, prompts, **kw)
+    assert eng_1.metrics["preemptions"] > 0
     assert eng_f.metrics["preemptions"] > 0
-    assert o_leg == o_fus
+    assert o_one == o_fus
 
 
-@pytest.mark.parametrize("use_fused", [False, True])
-def test_sequence_truncated_at_block_table_capacity(small, use_fused):
+@pytest.mark.parametrize("max_horizon", [1, 8])
+def test_sequence_truncated_at_block_table_capacity(small, max_horizon):
     """A generation that would overflow the mb-wide block table is
     truncated (force-finished), not crashed in _sync_tables."""
     cfg, params = small
     prompt = list(range(1, 18))                     # 17 tokens, cap 2*16=32
-    outs, _, eng = _run(cfg, params, [prompt], use_fused=use_fused,
+    outs, _, eng = _run(cfg, params, [prompt], max_horizon=max_horizon,
                         max_new_tokens=48, max_slots=2, num_blocks=8,
                         max_blocks_per_seq=2, prefill_bucket=32)
     assert len(eng.finished) == 1
@@ -218,7 +223,7 @@ def test_overlong_prompt_clamped_at_admission(small):
     (leaving room to generate) instead of crashing the prefill scatter."""
     cfg, params = small
     prompt = list(range(1, 40))                     # 39 tokens > cap 2*16=32
-    outs, _, eng = _run(cfg, params, [prompt], use_fused=True,
+    outs, _, eng = _run(cfg, params, [prompt],
                         max_new_tokens=4, max_slots=2, num_blocks=8,
                         max_blocks_per_seq=2, prefill_bucket=32)
     assert eng.metrics["truncated_prompts"] == 1

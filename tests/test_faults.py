@@ -22,11 +22,12 @@ from repro.serving import (EngineOverloadedError, FaultInjector, FaultSpec,
 
 KEY = jax.random.PRNGKey(0)
 
-# (engine kwargs, id) — the unified single-dispatch path and the two-call
-# oracle path must give fault handling identical semantics
+# (engine kwargs, id) — the async pipelined engine and the synchronous
+# read-back-every-step engine (its own recovery path) must give fault
+# handling identical semantics
 MODES = [
     pytest.param({}, id="unified"),
-    pytest.param({"enable_unified_step": False}, id="two-call"),
+    pytest.param({"enable_async_step": False}, id="sync"),
 ]
 POOLS = ["bf16", "int8"]
 
@@ -299,7 +300,7 @@ def test_chaos_schedule_drains_token_exact(small, kw, pool):
     """Seeded random fault soup (transient dispatches + NaN rows + alloc
     exhaustion): the engine drains every request, quarantined ones get
     "error", unaffected greedy requests are token-exact vs the fault-free
-    run — in unified AND two-call modes, bf16 AND int8 pools."""
+    run — in the async AND sync engines, bf16 AND int8 pools."""
     prompts = _prompts(4, seed=7)
     base = _drain(_mk(small, kv_cache_dtype=pool, **kw), prompts)
     fi = FaultInjector(random_schedule(11, 25, p_dispatch=0.25,

@@ -346,10 +346,10 @@ def test_paged_attention_quant_kernel_matches_ref(use_alibi, walk):
 
 # ------------------------------------------------------------ end to end
 
-def _generate(kv_cache_dtype, prompts, *, use_fused=True, temperature=0.0,
+def _generate(kv_cache_dtype, prompts, *, max_horizon=8, temperature=0.0,
               max_tokens=12, num_blocks=64):
     llm = LLM.load("qwen1.5-0.5b", reduced=True,
-                   kv_cache_dtype=kv_cache_dtype, use_fused=use_fused,
+                   kv_cache_dtype=kv_cache_dtype, max_horizon=max_horizon,
                    max_slots=3, num_blocks=num_blocks, max_blocks_per_seq=8,
                    prefill_bucket=16, overrides={"num_layers": 2})
     res = llm.generate(prompts, SamplingParams(temperature=temperature,
@@ -377,16 +377,16 @@ def test_int8_greedy_parity_with_bf16():
     assert ratio >= 1.8, ratio
 
 
-def test_int8_fused_matches_legacy_bitwise():
-    """Within int8 mode the fused megastep and the legacy loop remain
-    bitwise-identical (same quantize-on-write ops, same sampling streams),
-    including under temperature sampling."""
+def test_int8_horizon_matches_per_token_bitwise():
+    """Within int8 mode a multi-token megastep horizon and one decode
+    dispatch per token (``max_horizon=1``) remain bitwise-identical
+    (same quantize-on-write ops, same sampling streams), including under
+    temperature sampling."""
     prompts = _prompts(4, seed=7)
     for temp in (0.0, 0.9):
-        leg, _ = _generate("int8", prompts, use_fused=False,
-                           temperature=temp)
-        fus, _ = _generate("int8", prompts, use_fused=True, temperature=temp)
-        assert leg == fus, f"temperature={temp}"
+        one, _ = _generate("int8", prompts, max_horizon=1, temperature=temp)
+        fus, _ = _generate("int8", prompts, temperature=temp)
+        assert one == fus, f"temperature={temp}"
 
 
 def test_int8_preemption_recompute_parity():

@@ -398,14 +398,13 @@ def test_telemetry_off_engine_still_serves(small):
 
 # the runner's forward-pass dispatch spans and how many passes each makes
 FORWARD = ("dispatch:megastep", "dispatch:unified",
-           "dispatch:unified_chained", "dispatch:decode", "dispatch:chunk",
-           "dispatch:prefill")
+           "dispatch:unified_chained", "dispatch:prefill")
 
 
 @pytest.mark.parametrize("mode", [
-    {}, {"enable_async_step": False}, {"enable_unified_step": False},
-    {"use_fused": False}, {"enable_chunked_prefill": False}],
-    ids=["async", "unified", "two-call", "legacy-decode", "oracle-prefill"])
+    {}, {"enable_async_step": False}, {"max_horizon": 1},
+    {"enable_chunked_prefill": False}],
+    ids=["async", "unified", "per-token", "oracle-prefill"])
 def test_forward_pass_spans_carry_rows(small, mode):
     cfg, params = small
     eng = ServingEngine(cfg, params, max_slots=4, num_blocks=128,
@@ -433,8 +432,8 @@ def test_forward_pass_spans_carry_rows(small, mode):
 
 
 @pytest.mark.parametrize("mode", [
-    {}, {"enable_async_step": False}, {"use_fused": False}],
-    ids=["async", "unified", "legacy-decode"])
+    {}, {"enable_async_step": False}, {"max_horizon": 1}],
+    ids=["async", "unified", "per-token"])
 def test_forward_pass_spans_carry_pages(small, mode):
     """Each forward-pass span's ``pages`` arg, and ``repro_decode_pages``,
     is the live KV pages its decode rows read per layer: the sum of
@@ -454,8 +453,7 @@ def test_forward_pass_spans_carry_pages(small, mode):
             seen.append(int(np.sum(-(-sl // bs))))
             return run(*a, **kw)
         setattr(runner, name, wrapped)
-    for name in ("megastep", "unified_step", "unified_step_chained",
-                 "decode"):
+    for name in ("megastep", "unified_step", "unified_step_chained"):
         watch(name)
     rng = np.random.default_rng(5)
     for _ in range(6):
@@ -464,7 +462,7 @@ def test_forward_pass_spans_carry_pages(small, mode):
     eng.run_until_done()
     pages, total = [], 0
     for s in eng.tracer.spans():
-        if s.name in FORWARD[:4]:
+        if s.name in FORWARD[:3]:
             n = s.args["n_steps"] if s.name == "dispatch:megastep" else 1
             pages.append(s.args["pages"] if s.args["rows"] else 0)
             total += s.args["pages"] * n

@@ -1,6 +1,8 @@
 """Host-side Scheduler unit tests: admission, watermark, clamping,
 horizon planning, preemption, capacity and the token-budget step planner
 (``plan_step``) — no model, no device arrays."""
+import random
+
 import pytest
 
 from repro.core.paged_cache import BlockAllocator
@@ -481,6 +483,46 @@ def test_unified_dispatch_layout():
         _execute_plan(s, s.plan_step(32, max_horizon=4))
     plan = s.plan_step(32, max_horizon=4)
     assert not plan.prefill and plan.unified_dispatches() == []
+
+
+def test_unified_dispatch_layout_under_random_arrivals():
+    """Over seeded random arrivals into tight pools, every plan's decodes
+    run exactly once.  Beside prefill at horizon 1 they ride the first
+    unified dispatch.  At a longer horizon they run as the plan's
+    megastep and every chunk dispatches alone: the planner emits such a
+    plan when ``plan_horizon`` preempts after the horizon pin was
+    decided and the freed blocks or slot let prefill in."""
+    rng = random.Random(0)
+    longer = 0
+    for _ in range(200):
+        slots = rng.randint(1, 4)
+        alloc = BlockAllocator(rng.randint(6, 24), BS,
+                               watermark_frac=rng.choice([0.0, 0.25]))
+        s = Scheduler(alloc, max_slots=slots,
+                      max_blocks_per_seq=rng.randint(2, 8))
+        budget, horizon = rng.randint(slots + 1, 40), rng.randint(2, 8)
+        pending = [_req(i, rng.randint(1, 40), max_tokens=rng.randint(1, 30))
+                   for i in range(rng.randint(1, 10))]
+        for i, r in enumerate(pending):
+            r.prompt = [1000 * (i + 1) + t for t in range(len(r.prompt))]
+        for _step in range(400):
+            if not (pending or s.waiting or s.running):
+                break
+            if pending and rng.random() < 0.4:
+                s.add(pending.pop(0))
+            for _q in s.finish_at_capacity():
+                pass
+            plan = s.plan_step(budget, max_horizon=horizon)
+            ds = plan.unified_dispatches()
+            assert [d.chunk for d in ds] == plan.prefill
+            assert all(d.decode_slots == [] for d in ds[1:])
+            if ds and plan.horizon <= 1:
+                assert ds[0].decode_slots == plan.decode_slots
+            elif ds:
+                assert ds[0].decode_slots == []
+                longer += bool(plan.decode_slots)
+            _execute_plan(s, plan)
+    assert longer > 0, "the sweep must reach prefill beside a longer horizon"
 
 
 # ---------------------------------------------- register-on-write hashing

@@ -1,6 +1,6 @@
-"""vLLM-style serving API: per-request SamplingParams through both decode
-paths (bitwise), stop-token semantics, streaming, the LLM facade and the
-deprecation shim."""
+"""vLLM-style serving API: per-request SamplingParams at a multi-token
+horizon and one token per dispatch (bitwise), stop-token semantics,
+streaming, the LLM facade and the deprecation shim."""
 import jax
 import numpy as np
 import pytest
@@ -44,10 +44,10 @@ def _drain(eng, prompts, sps):
 
 # ---------------------------------------------------- heterogeneous parity
 
-def test_heterogeneous_params_fused_matches_legacy(small):
+def test_heterogeneous_params_horizon_matches_per_token(small):
     """Acceptance: one batch mixing greedy / temperature / top-k / top-p /
-    seeded requests decodes bitwise-identically through the fused megastep
-    and the legacy per-token loop."""
+    seeded requests decodes bitwise-identically at a multi-token megastep
+    horizon and at one dispatch per token (``max_horizon=1``)."""
     cfg, params = small
     prompts = _prompts(6, seed=5)
     sps = [SamplingParams(max_tokens=10),
@@ -57,10 +57,10 @@ def test_heterogeneous_params_fused_matches_legacy(small):
            SamplingParams(temperature=0.7, top_k=12, top_p=0.95, seed=42,
                           max_tokens=10),
            SamplingParams(max_tokens=10)]
-    o_leg, _ = _drain(_engine(cfg, params, use_fused=False), prompts, sps)
-    o_fus, fr = _drain(_engine(cfg, params, use_fused=True), prompts, sps)
-    assert len(o_leg) == len(o_fus) == 6
-    assert o_leg == o_fus
+    o_one, _ = _drain(_engine(cfg, params, max_horizon=1), prompts, sps)
+    o_fus, fr = _drain(_engine(cfg, params), prompts, sps)
+    assert len(o_one) == len(o_fus) == 6
+    assert o_one == o_fus
     assert all(r == "length" for r in fr.values())
 
 
@@ -110,18 +110,18 @@ def test_stop_token_finishes_and_releases_blocks_immediately(small):
     assert eng.finished[-1].finish_reason in ("length", "capacity")
 
 
-def test_stop_midhorizon_parity_fused_vs_legacy(small):
+def test_stop_midhorizon_parity_horizon_vs_per_token(small):
     cfg, params = small
     probe, _ = _drain(_engine(cfg, params), _prompts(2, seed=4),
                       [SamplingParams(max_tokens=12)] * 2)
     stop = [probe[0][3], probe[1][5]]
     sps = [SamplingParams(max_tokens=12, stop=[stop[0]]),
            SamplingParams(max_tokens=12, stop=[stop[1]])]
-    o_leg, f_leg = _drain(_engine(cfg, params, use_fused=False),
+    o_one, f_one = _drain(_engine(cfg, params, max_horizon=1),
                           _prompts(2, seed=4), sps)
-    o_fus, f_fus = _drain(_engine(cfg, params, use_fused=True, max_horizon=8),
+    o_fus, f_fus = _drain(_engine(cfg, params, max_horizon=8),
                           _prompts(2, seed=4), sps)
-    assert o_leg == o_fus and f_leg == f_fus
+    assert o_one == o_fus and f_one == f_fus
     assert set(f_fus.values()) == {"stop"}
 
 

@@ -1,7 +1,8 @@
-"""Unified single-dispatch serving step vs the two-call oracle
-(``enable_unified_step=False``): greedy token-exactness on both KV pool
-formats, bitwise-identical fused sampling, preemption mid-prefill, the
-single-compile guarantee, and the dispatch-count accounting."""
+"""Unified single-dispatch serving step: greedy tokens against the
+teacher-forced ``T.forward`` reference on both KV pool formats, fused
+sampling bitwise-identical to the whole-prompt path's own sample
+dispatch, preemption mid-prefill, the single-compile guarantee, and the
+dispatch-count accounting."""
 import re
 
 import jax
@@ -46,39 +47,73 @@ def _drain(eng, prompts, sps):
         {r.rid: r.finish_reason for r in eng.finished}
 
 
+# How far below the reference's best logit an int8-pool greedy token's
+# reference logit may sit.  The int8 pool stores every K/V element to
+# within half a quantization step (amax / 254 of its block and head), an
+# error the dense pool does not make, so where the reference's top logits
+# nearly tie the int8 engine may take the runner-up.  On this two-layer
+# model the logits are bf16 of magnitude below 1, where one rounding step
+# is 2**-8; the measured gap is at most one such step.  0.02 allows five,
+# and stays below the median top-two margin (about 0.04), so a token that
+# is not a near-tie still fails.  A chunk whose K/V lands one position
+# off moves the logits far more than that.
+INT8_LOGIT_TOL = 0.02
+
+
+def _reference_gaps(cfg, params, eng):
+    """For every served token: the teacher-forced reference's best logit
+    minus its logit of the served token, given the served prefix (one
+    ``T.forward`` per request over prompt + output)."""
+    fwd = jax.jit(lambda t: T.forward(cfg, params, {"tokens": t}))
+    gaps = []
+    for r in eng.finished:
+        prompt, out = list(r.prompt_token_ids), list(r.output)
+        logits = np.asarray(fwd(jnp.asarray([prompt + out[:-1]])),
+                            np.float32)[0, len(prompt) - 1:]
+        gaps += [float(row.max() - row[t]) for row, t in zip(logits, out)]
+    return gaps
+
+
 @pytest.mark.parametrize("kv_cache_dtype", ["bf16", "int8"])
-def test_unified_token_exact_vs_two_call(small, kv_cache_dtype):
+def test_unified_token_exact_vs_reference(small, kv_cache_dtype):
     """Acceptance: multi-chunk greedy serving through the unified
-    single-dispatch step is token-exact against the two-call oracle on
-    the dense AND int8 pools, from exactly one unified-step compile."""
+    single-dispatch step serves the teacher-forced reference's argmax at
+    every position on the dense pool, and a token within
+    ``INT8_LOGIT_TOL`` of it on the int8 pool, from exactly one
+    unified-step compile."""
     cfg, params = small
     prompts = _prompts(5, seed=21, lo=24, hi=60)
     sps = [SamplingParams(max_tokens=10)] * 5
-    o_ref, f_ref = _drain(
-        _engine(cfg, params, enable_unified_step=False,
-                kv_cache_dtype=kv_cache_dtype), prompts, sps)
     eng = _engine(cfg, params, kv_cache_dtype=kv_cache_dtype)
-    o_chk, f_chk = _drain(eng, prompts, sps)
+    _, fin = _drain(eng, prompts, sps)
     assert eng.metrics["prefill_chunks"] > len(prompts)   # really chunked
-    assert o_ref == o_chk and f_ref == f_chk
+    assert set(fin.values()) == {"length"}
+    gaps = _reference_gaps(cfg, params, eng)
+    assert len(gaps) == 5 * 10
+    tol = 0.0 if kv_cache_dtype == "bf16" else INT8_LOGIT_TOL
+    assert max(gaps) <= tol, sorted(gaps)[-5:]
     assert eng.runner.unified_compiles() == 1
     assert eng.runner.prefill_compiles() == 1
 
 
-def test_unified_sampling_bitwise_vs_two_call(small):
+def test_unified_sampling_bitwise_vs_whole_prompt(small):
     """Fused sampling inside the unified dispatch (decode rows + the
     chunk's first token, one sample kernel over max_slots + 1 rows) is
-    bitwise-identical to the two-call path's megastep + batched-sample
-    pair across mixed sampling modes, including seeded requests."""
+    bitwise-identical to the whole-prompt path, whose first token comes
+    from its own sample dispatch, across mixed sampling modes, including
+    seeded requests."""
     cfg, params = small
     prompts = _prompts(4, seed=31, lo=20, hi=40)
     sps = [SamplingParams(max_tokens=8),
            SamplingParams(temperature=0.9, max_tokens=8),
            SamplingParams(temperature=0.8, top_k=5, max_tokens=8),
            SamplingParams(temperature=0.7, top_p=0.9, seed=7, max_tokens=8)]
-    o_ref, _ = _drain(_engine(cfg, params, enable_unified_step=False),
-                      prompts, sps)
-    o_chk, _ = _drain(_engine(cfg, params), prompts, sps)
+    ref = _engine(cfg, params, enable_chunked_prefill=False,
+                  prefill_bucket=16)
+    o_ref, _ = _drain(ref, prompts, sps)
+    eng = _engine(cfg, params)
+    o_chk, _ = _drain(eng, prompts, sps)
+    assert ref.runner._unified is None and eng.metrics["prefill_chunks"]
     assert o_ref == o_chk
 
 
@@ -99,6 +134,35 @@ def test_unified_preemption_mid_prefill_parity(small, kv_cache_dtype):
     assert eng.metrics["preemptions_mid_prefill"] > 0, \
         "scenario must preempt a sequence mid-prefill"
     assert roomy == tight
+
+
+def test_prefill_beside_multi_token_horizon_parity(small):
+    """A preemption inside ``plan_horizon`` can free room for prefill
+    after the horizon was chosen.  That plan's decodes run as its
+    megastep and its chunks as chunk-only unified dispatches, and the
+    block-starved run stays token-exact against a roomy one."""
+    cfg, params = small
+    prompts = _prompts(4, seed=11, lo=17, hi=30)
+    sps = [SamplingParams(max_tokens=32)] * 4
+    roomy, _ = _drain(_engine(cfg, params, num_blocks=256), prompts, sps)
+    eng = _engine(cfg, params, num_blocks=8)
+    plans, megasteps = [], []
+    plan_step, dispatch_decode = eng.scheduler.plan_step, eng._dispatch_decode
+
+    def spy(*a, **kw):
+        plans.append(plan_step(*a, **kw))
+        return plans[-1]
+
+    def spy_decode(plan, outs):
+        megasteps.append(plan)
+        return dispatch_decode(plan, outs)
+    eng.scheduler.plan_step, eng._dispatch_decode = spy, spy_decode
+    tight, _ = _drain(eng, prompts, sps)
+    mixed = [p for p in plans if p.prefill and p.decode_slots
+             and p.horizon > 1]
+    assert mixed, "scenario must plan prefill beside h > 1"
+    assert all(any(p is m for m in megasteps) for p in mixed)
+    assert tight == roomy
 
 
 def test_unified_one_compile_across_heterogeneous_prompts(
@@ -143,19 +207,33 @@ def test_unified_single_dispatch_in_steady_mixed_state(
     eng.run_until_done()
 
 
-def test_unified_requires_chunked_and_fused(small):
-    """enable_unified_step quietly degrades to the two-call paths when
-    its prerequisites (chunked prefill + fused decode) are off."""
+def _compiled(eng):
+    return {s.args["executable"] for s in eng.tracer.spans()
+            if s.name == "compile"}
+
+
+def test_unified_follows_chunked_prefill(small):
+    """The unified step serves exactly the chunked engines: a chunked
+    engine runs prefill through it (plus the megastep for decode-only
+    steps) and never compiles the whole-prompt prefill or its sample; an
+    engine without chunked prefill builds no unified executable and
+    serves through the whole-prompt wave and the megastep."""
     cfg, params = small
-    eng = _engine(cfg, params, enable_chunked_prefill=False)
-    assert not eng.unified
-    eng = _engine(cfg, params, use_fused=False)
-    assert not eng.unified
     prompts = _prompts(2, seed=71)
-    a, _ = _drain(eng, prompts, [SamplingParams(max_tokens=4)] * 2)
-    b, _ = _drain(_engine(cfg, params, use_fused=False,
-                          enable_unified_step=False),
-                  prompts, [SamplingParams(max_tokens=4)] * 2)
+    sps = [SamplingParams(max_tokens=4)] * 2
+    eng = _engine(cfg, params)
+    a, _ = _drain(eng, prompts, sps)
+    assert eng.chunked and eng.async_step
+    assert _compiled(eng) <= {"unified_step", "unified_step_chained",
+                              "megastep", "copy_cow"}
+    assert eng.runner.unified_compiles() == 1
+    eng = _engine(cfg, params, enable_chunked_prefill=False,
+                  prefill_bucket=16)
+    b, _ = _drain(eng, prompts, sps)
+    assert not eng.chunked and not eng.async_step
+    assert eng.runner._unified is None and eng.runner._unified_chained is None
+    assert _compiled(eng) == {"prefill", "sample", "megastep"}
+    assert np.isnan(eng.runner.unified_compiles())
     assert a == b
 
 
